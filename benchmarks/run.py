@@ -84,12 +84,22 @@ def emit_gauge(name: str, value: float) -> None:
 
 def _gemm_eff(m: int, k: int, n: int, us: float,
               dtype: str = "float32") -> str:
-    """``eff=`` column: achieved GEMM FLOP/s over the analytic device
-    peak (repro.obs.efficiency) — the paper's %-of-peak figure.  On the
-    CPU-interpret backend this is honestly minuscule; the perf gate
-    tracks it as a run-over-run ratio per backend."""
-    from repro.obs.efficiency import gemm_efficiency
+    """``eff=`` column: achieved GEMM FLOP/s over the published peak of
+    the chip the bench ran on (repro.obs.efficiency) — the paper's
+    %-of-peak figure.  ``eff=not_measured`` off an accelerator: a CPU
+    timing has no device peak to be a share of."""
+    from repro.obs.efficiency import device_kind, gemm_efficiency
+    if device_kind() is None:
+        return "eff=not_measured"
     return f"eff={gemm_efficiency(m, k, n, us, dtype):.2e}"
+
+
+def _serve_eff(cfg, tok_s: float) -> str:
+    """``eff=`` column of a serve row (see :func:`_gemm_eff`)."""
+    from repro.obs.efficiency import device_kind, serve_efficiency
+    if device_kind() is None:
+        return "eff=not_measured"
+    return f"eff={serve_efficiency(cfg, tok_s):.2e}"
 
 
 # ---------------------------------------------------------------------------
@@ -268,8 +278,10 @@ def bench_tuning_dispatch() -> None:
 
 
 def _pack_mesh(data: int, model: int):
-    from repro.launch.mesh import compat_make_mesh
-    return compat_make_mesh((data, model), ("data", "model"))
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # Reduce schedules selectable with --reduce; "all" runs them side by
@@ -433,7 +445,6 @@ def bench_serve_trace() -> None:
     try:
         run_trace(engine, trace, log=None)          # compile warmup
         rep = run_trace(engine, trace, log=None)
-        from repro.obs.efficiency import serve_efficiency
         kv_kib = engine.kv_bytes_reserved() / 1024
         # Step-time attribution for the warm replay: bubble is the share
         # of step wall time outside the device-synced section probes;
@@ -441,17 +452,19 @@ def bench_serve_trace() -> None:
         # deterministic (analytic shapes vs hw peaks); the fraction is
         # timing-derived, so its --metrics gate gets a wide tolerance.
         ktab = engine.profiler.kernel_table()
-        stall = f"{ktab[0].name}:{ktab[0].stall_class}" if ktab else "n/a"
+        stall = (f"{ktab[0].name}:{ktab[0].stall_class or 'not_measured'}"
+                 if ktab else "n/a")
         emit("serve.continuous.s4", rep["wall_s"] * 1e6 / rep["tokens"],
              f"tok_s={rep['tok_s']:.1f} p50={rep['p50_ms']:.2f}ms "
              f"p99={rep['p99_ms']:.2f}ms shared_steps={rep['shared_steps']} "
              f"decode_steps={rep['decode_steps']} kv_kib={kv_kib:.0f} "
              f"bubble={rep['bubble_fraction']:.2f} stall={stall} "
-             f"eff={serve_efficiency(cfg, rep['tok_s']):.2e}")
+             f"{_serve_eff(cfg, rep['tok_s'])}")
         emit_gauge("serve.bubble_fraction", rep["bubble_fraction"])
-        emit_gauge("serve.stall.memory_bound",
-                   1.0 if ktab and ktab[0].stall_class == "memory"
-                   else 0.0)
+        if ktab and ktab[0].stall_class is not None:
+            # No gauge where no device peak places the kernel (the CPU).
+            emit_gauge("serve.stall.memory_bound",
+                       1.0 if ktab[0].stall_class == "memory" else 0.0)
         # Serialized baseline: same engine, same requests, grouped into
         # uniform one-shot batches (arrivals ignored — the baseline gets
         # every benefit of the doubt); each batch decodes to its longest
@@ -481,7 +494,6 @@ def bench_serve_trace() -> None:
                 toks, prep["results"][tid],
                 err_msg=f"paged diverged from dense (trace id {tid})")
         hwm_kib = prep["kv_bytes_hwm"] / 1024
-        from repro.obs.efficiency import serve_efficiency
         emit("serve.paged.s4", prep["wall_s"] * 1e6 / prep["tokens"],
              f"tok_s={prep['tok_s']:.1f} p50={prep['p50_ms']:.2f}ms "
              f"p99={prep['p99_ms']:.2f}ms page=16 "
@@ -489,7 +501,7 @@ def bench_serve_trace() -> None:
              f"reclaimed={prep['pages_reclaimed']} "
              f"kv_hwm_kib={hwm_kib:.0f} "
              f"dense_kib={kv_kib:.0f} "
-             f"eff={serve_efficiency(cfg, prep['tok_s']):.2e}")
+             f"{_serve_eff(cfg, prep['tok_s'])}")
     finally:
         paged.close()
     # int8 KV pages on the same trace: per-page-row scales shrink each
@@ -522,7 +534,7 @@ def bench_serve_trace() -> None:
              f"kv_hwm_kib={q_hwm_kib:.0f} "
              f"f32_hwm_kib={hwm_kib:.0f} "
              f"identical_streams={same}/{len(trace)} "
-             f"eff={serve_efficiency(cfg, qrep['tok_s']):.2e}")
+             f"{_serve_eff(cfg, qrep['tok_s'])}")
     finally:
         qpaged.close()
     # Chunked prefill on the same trace (page-aligned 16-token chunks
@@ -546,7 +558,7 @@ def bench_serve_trace() -> None:
              f"budget={slots + 16} chunks={crep['prefill_chunks']} "
              f"mono_p99={prep['p99_ms']:.2f}ms "
              f"bubble={crep['bubble_fraction']:.2f} "
-             f"eff={serve_efficiency(cfg, crep['tok_s']):.2e}")
+             f"{_serve_eff(cfg, crep['tok_s'])}")
     finally:
         chunked.close()
     # Prefix caching on the committed shared-system-prompt trace
@@ -594,7 +606,7 @@ def bench_serve_trace() -> None:
          f"uncached_hwm={f32u['pages_hwm']} "
          f"cow={f32c['cow_copies']} "
          f"int8_identical=yes "
-         f"eff={serve_efficiency(cfg, f32c['tok_s']):.2e}")
+         f"{_serve_eff(cfg, f32c['tok_s'])}")
     emit_gauge("serve.prefix.miss_rate", 1.0 - f32c["prefix_hit_rate"])
     emit_gauge("serve.prefix.pages_hwm_ratio",
                f32c["pages_hwm"] / f32u["pages_hwm"])
@@ -731,6 +743,8 @@ def main() -> None:
             os.environ["XLA_FLAGS"] = (
                 flags + " --xla_force_host_platform_device_count=8"
             ).strip()
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}")
     print("name,us_per_call,derived")
     for name, fn in LEVELS[args.level]:
         if args.filter and args.filter not in name:

@@ -1,25 +1,27 @@
 """Cascade parallelism demo — the paper's pack as TPU collectives.
 
-Runs on 8 host devices (re-execs itself with the device flag): a
+Runs on 8 host-CPU devices (the device-count flag is set in this
+process before jax initializes; no child process is started): a
 K-sharded GEMM whose partial sums combine via subgroup reduce-scatter
 (the cascade), swept over pack sizes G like the paper's Fig. 6, plus the
 planner's cost-model view of the same sweep for the production mesh.
 
-    PYTHONPATH=src python examples/cascade_parallelism.py
+    PYTHONPATH=src JAX_PLATFORMS=cpu python examples/cascade_parallelism.py
 """
 
 import os
-import subprocess
-import sys
 
-if "XLA_FLAGS" not in os.environ:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    sys.exit(subprocess.call([sys.executable] + sys.argv, env=env))
+# Must precede jax initialization; an explicit device-count flag from
+# the caller wins.
+_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _flags:
+    os.environ["XLA_FLAGS"] = (
+        _flags + " --xla_force_host_platform_device_count=8").strip()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 from repro.core import planner  # noqa: E402
 from repro.distributed.cascade import (cascade_ffn,  # noqa: E402
@@ -27,7 +29,8 @@ from repro.distributed.cascade import (cascade_ffn,  # noqa: E402
 
 
 def main() -> None:
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
     t, d, f = 32, 64, 256
     x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)

@@ -1,6 +1,10 @@
-"""SmolLM-360M — llama-arch small [hf:HuggingFaceTB/SmolLM-135M; hf].
+"""SmolLM-360M — llama-architecture small model
+[hf:HuggingFaceTB/SmolLM-360M, config.json].
 
-Assigned: 32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152.
+Published widths: 32 layers, hidden 960, 15 query heads and 5 KV heads
+of 64, intermediate 2560, vocab 49152, tied embeddings, RoPE theta
+10000.  FULL keeps them all; SMOKE is a 2-layer, d_model 96 f32
+stand-in for CPU tests.
 """
 
 from repro.models.config import ModelConfig

@@ -5,9 +5,9 @@ Two targets live side by side:
 * :class:`AIE2Device` — the paper's AMD Versal VE2802 (AIE-ML) device.  Used
   by the *faithful* reproduction path (tile search, buffer placement, pack
   and array models) that validates against the paper's Tables II-VI.
-* :class:`TpuChip` — the deployment target for the JAX/Pallas framework.
-  Constants follow the assignment brief: 197 TFLOP/s bf16 per chip,
-  819 GB/s HBM, ~50 GB/s/link ICI.
+* :class:`TpuChip` — the deployment target for the JAX/Pallas framework,
+  with the published peaks of each supported chip in
+  :data:`DEVICE_PEAKS`, keyed by jax's ``device_kind``.
 
 Both expose the quantities the shared analytical model in
 :mod:`repro.core.gemm_model` needs: peak MAC throughput per precision, the
@@ -46,6 +46,10 @@ INT8_INT32 = Precision("int8-int32", in_bytes=1, out_bytes=4, acc_bytes=4)
 INT8_INT16 = Precision("int8-int16", in_bytes=1, out_bytes=2, acc_bytes=4)
 INT8_INT8 = Precision("int8-int8", in_bytes=1, out_bytes=1, acc_bytes=4)
 BF16_BF16 = Precision("bf16-bf16", in_bytes=2, out_bytes=2, acc_bytes=4)
+# Not one of the paper's precisions: f32 operands on the TPU, so the tile
+# planner counts their real VMEM bytes (the MXU still runs them at the
+# bf16 peak).
+F32_F32 = Precision("f32-f32", in_bytes=4, out_bytes=4, acc_bytes=4)
 
 PRECISIONS: Dict[str, Precision] = {
     p.name: p for p in (INT8_INT32, INT8_INT16, INT8_INT8, BF16_BF16)
@@ -115,28 +119,35 @@ VE2802 = AIE2Device()
 
 
 # ---------------------------------------------------------------------------
-# TPU v5e-class chip — the deployment target
+# TPU chips — the deployment target, keyed by jax's ``device_kind``
 # ---------------------------------------------------------------------------
 
 
 @dataclasses.dataclass(frozen=True)
 class TpuChip:
-    """TPU chip model (v5e-class constants per the assignment brief)."""
+    """One TPU chip's published peaks plus the Pallas tiling constants
+    the planner and kernels share."""
 
     name: str = "tpu-v5e"
     # Peak compute.
     peak_bf16_flops: float = 197e12
-    peak_int8_ops: float = 394e12  # 2x bf16, standard for the generation
+    peak_int8_ops: float = 393e12
     # Memory system.
-    hbm_bytes: int = 16 * 2**30
+    hbm_bytes: int = 16 * 10**9
     hbm_bw: float = 819e9
-    # VMEM: capacity is generous on the ML-optimized generations; we budget
-    # conservatively and keep it configurable (tile search treats this as the
-    # analogue of the AIE's 64 KB local memory).
-    vmem_bytes: int = 64 * 2**20
+    # VMEM: v5e has 128 MiB per core.  Kernels compile with a scoped
+    # limit of ``vmem_limit`` (the compiler's own default is 16 MiB),
+    # and the planner only admits tiles whose modelled working set fits
+    # ``vmem_budget`` — the gap covers what the model leaves out (the
+    # pipeline's second output buffer, the dot result before it is
+    # accumulated), so every admitted plan also compiles.  The budget is
+    # the analogue of the AIE's 64 KB local memory in the tile search.
+    vmem_bytes: int = 128 * 2**20
+    vmem_limit: int = 64 * 2**20
     vmem_budget: int = 48 * 2**20
-    # ICI per-link bandwidth (assignment: ~50 GB/s/link).
-    ici_bw: float = 50e9
+    # Chip-to-chip interconnect per link: 1,600 Gbit/s per chip over
+    # four links.
+    ici_bw: float = 1600e9 / 8 / 4
     # MXU geometry: 128x128 systolic, (sublane, lane) native tile (8, 128).
     mxu_dim: int = 128
     sublanes: int = 8
@@ -156,7 +167,34 @@ class TpuChip:
         return (self.sublanes * packing, self.lanes)
 
 
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# ICI per chip.
 TPU_V5E = TpuChip()
+
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+#: A device that is not here has no peak: asking for one is an error.
+DEVICE_PEAKS: Dict[str, TpuChip] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def chip_for_kind(device_kind: str) -> TpuChip:
+    """The published peaks of the chip jax reports as ``device_kind``.
+
+    >>> chip_for_kind("TPU v5 lite").peak_bf16_flops
+    197000000000000.0
+    >>> chip_for_kind("cpu")  # doctest: +ELLIPSIS
+    Traceback (most recent call last):
+    ...
+    ValueError: no published peaks for device kind 'cpu' ...
+    """
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(DEVICE_PEAKS)})") from None
 
 
 # ---------------------------------------------------------------------------

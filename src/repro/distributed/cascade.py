@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed._compat import shard_map
 
 
 def cascade_groups(w: int, g: int):
@@ -84,7 +83,7 @@ def cascade_matmul(
             out, model_axis, axis=0, tiled=True,
             axis_index_groups=groups)                         # (T, N/X)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda xs, ws: local(xs[0], ws[0])[None],
         mesh=mesh,
         in_specs=(P(model_axis, None, None), P(model_axis, None, None)),
@@ -139,7 +138,7 @@ def cascade_ffn(
             out, model_axis, axis=0, tiled=True,
             axis_index_groups=groups)                         # (T, d)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None), P(None, model_axis), P(None, model_axis),
                   P(model_axis, None)),

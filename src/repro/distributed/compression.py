@@ -25,7 +25,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed._compat import shard_map
 
 BLOCK = 256
 
@@ -102,7 +101,7 @@ def compressed_grad_mean(grads: Any, err: Any, mesh: Mesh,
             nerrs.append(ne.reshape(g.shape))
         return tuple(outs) + tuple(nerrs)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=tuple(P() for _ in range(2 * len(flat))),
         out_specs=tuple(P() for _ in range(2 * len(flat))),

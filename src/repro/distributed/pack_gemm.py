@@ -55,6 +55,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -62,7 +63,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed._compat import shard_map
 from repro.kernels import ref
 
 __all__ = [
@@ -323,7 +323,6 @@ def pack_gemm(a: jax.Array, b: jax.Array, mesh: Mesh, *,
     np_ = _round_up(max(n, 1), q)
     kb = kp // (p * cyc)
     nq = np_ // q
-    md = mp // d
 
     ap = jnp.pad(a, ((0, mp - m), (0, kp - k)))
     bp = jnp.pad(b, ((0, kp - k), (0, np_ - n)))
@@ -331,8 +330,31 @@ def pack_gemm(a: jax.Array, b: jax.Array, mesh: Mesh, *,
     bg = stage_b_blocks(bp, p, q, cyc, kb)    # (q, p, cyc*kb, nq)
 
     sub = split_pack_mesh(mesh, model_axis, p, q)
-    perm = [(j, (j + 1) % p) for j in range(p)]
     da = data_axis if data_axis else None
+    # (d, q, p, Md, nq)
+    out = _pack_shard_map(ag, bg, sub=sub, da=da, p=p, cyc=cyc, kb=kb,
+                          stagger=stagger, reduce=reduce, overlap=overlap,
+                          acc_dtype=acc_dtype, mode=mode)
+    # Every member of a column holds the full reduction; keep j == 0.
+    out = out[:, :, 0]                                 # (d, q, Md, nq)
+    out = out.transpose(0, 2, 1, 3).reshape(mp, np_)[:m, :n]
+    # Requantize exactly once, after the full cross-device reduction.
+    return ref.requantize(out, out_dtype, scale)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sub", "da", "p", "cyc", "kb", "stagger", "reduce", "overlap",
+    "acc_dtype", "mode"))
+def _pack_shard_map(ag: jax.Array, bg: jax.Array, *, sub: Mesh,
+                    da: Optional[str], p: int, cyc: int, kb: int,
+                    stagger: int, reduce: str, overlap: bool, acc_dtype,
+                    mode: str) -> jax.Array:
+    """The per-device pack dataflow as one compiled program per static
+    configuration.  Jitting matters off the traced path too: an eager
+    ``shard_map`` dispatches the ring's slices, permutes and updates one
+    primitive at a time, which costs seconds per call on host devices;
+    compiled, a repeat call is a cache hit."""
+    perm = [(j, (j + 1) % p) for j in range(p)]
 
     def local(a_l, b_l):
         al, bl = a_l[0, 0], b_l[0, 0]      # (Md, cyc*kb), (cyc*kb, nq)
@@ -383,17 +405,12 @@ def pack_gemm(a: jax.Array, b: jax.Array, mesh: Mesh, *,
                                             stagger, col_axis=_Q_AXIS)
         return red[None, None, None]
 
-    fn = shard_map(local, mesh=sub,
-                   in_specs=(P(da, _P_AXIS, None, None),
-                             P(_Q_AXIS, _P_AXIS, None, None)),
-                   out_specs=P(da, _Q_AXIS, _P_AXIS, None, None),
-                   check_vma=False)
-    out = fn(ag, bg)                                   # (d, q, p, Md, nq)
-    # Every member of a column holds the full reduction; keep j == 0.
-    out = out[:, :, 0]                                 # (d, q, Md, nq)
-    out = out.transpose(0, 2, 1, 3).reshape(mp, np_)[:m, :n]
-    # Requantize exactly once, after the full cross-device reduction.
-    return ref.requantize(out, out_dtype, scale)
+    fn = jax.shard_map(local, mesh=sub,
+                       in_specs=(P(da, _P_AXIS, None, None),
+                                 P(_Q_AXIS, _P_AXIS, None, None)),
+                       out_specs=P(da, _Q_AXIS, _P_AXIS, None, None),
+                       check_vma=False)
+    return fn(ag, bg)
 
 
 def array_gemm(a: jax.Array, b: jax.Array, mesh: Mesh, *,

@@ -19,7 +19,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed._compat import shard_map
 
 Params = Any
 
@@ -71,7 +70,7 @@ def pipeline_apply(
         mask = (sid == n_stages - 1).astype(outs.dtype)
         return jax.lax.psum(outs * mask, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         # Every param leaf is sharded on its leading (stage) axis; the
         # microbatched input is replicated along the pipe axis.

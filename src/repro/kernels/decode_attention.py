@@ -7,9 +7,11 @@ are stacked into the sublane dimension so the (group, bk) logits block
 feeds the MXU/VPU efficiently — this is the TPU analogue of the paper's
 "pack" reading one stream and producing one combined result.
 
-Grid: (B, Hkv, Sk/bk), KV innermost ("arbitrary"); length masking uses a
-(B, 1) int32 length tensor (production would use scalar prefetch; a VMEM
-(1, 1) block keeps the kernel interpret-validatable).
+Grid: (B, Hkv, Sk/bk), KV innermost ("arbitrary"); length masking reads
+the (B,) int32 length vector as a scalar-prefetch operand (SMEM): a
+per-slot VMEM block of a (B, 1) array would be a (1, 1) block, which the
+TPU compiler refuses for B > 1 (a block's last two dims must be
+multiples of (8, 128) or span the array).
 
 The length is **per slot**: in a ragged continuous batch every row of
 the cache belongs to a different request at a different position, and
@@ -39,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 _LANES = 128
 _NEG_INF = -1e30
 
@@ -56,7 +56,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
     k_block_start = ki * bk
 
     @pl.when(k_block_start < length)
@@ -116,7 +116,7 @@ def flash_decode(
         scale = d ** -0.5
     if length is None:
         length = jnp.full((b,), sk, jnp.int32)
-    len2d = length.reshape(b, 1).astype(jnp.int32)
+    length = length.reshape(b).astype(jnp.int32)
     # Stack each KV head's q group into the sublane dim.
     qg = q.reshape(b, hkv, group, d)
     k_steps = sk // bk
@@ -126,41 +126,54 @@ def flash_decode(
                                gp=group, scale=scale)
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bb, h, ki: (bb, 0)),
-            pl.BlockSpec((1, 1, group, d), lambda bb, h, ki: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bb, h, ki: (bb, h, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bb, h, ki: (bb, h, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, d),
-                               lambda bb, h, ki: (bb, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, group, d),
+                             lambda bb, h, ki, ln: (bb, h, 0, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda bb, h, ki, ln: (bb, h, ki, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda bb, h, ki, ln: (bb, h, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, group, d),
+                                   lambda bb, h, ki, ln: (bb, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((group, _LANES), jnp.float32),
+                pltpu.VMEM((group, _LANES), jnp.float32),
+                pltpu.VMEM((group, d), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, _LANES), jnp.float32),
-            pltpu.VMEM((group, _LANES), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="gama_flash_decode",
-    )(len2d, qg, k, v)
+    )(length, qg, k, v)
     return out.reshape(b, hq, d)
 
 
 def _paged_page_step(q_ref, k, v, m_ref, l_ref, acc_ref, *,
                      k_block_start, length, gp: int, page_size: int,
-                     scale: float):
+                     scale: float, k_rows=None, v_rows=None):
     """The online-softmax update for one dereferenced page.  ``k``/``v``
-    are the page's f32 values — already dequantized when the pool is
-    int8 — so every buffering/precision variant of the paged kernel
-    shares one arithmetic body and they stay bit-identical to each
-    other (asserted by the fuzz suite)."""
+    are the page's values cast to f32, so every buffering/precision
+    variant of the paged kernel shares one arithmetic body and they
+    stay bit-identical to each other (asserted by the fuzz suite).
+
+    int8 pages pass their per-row scales as ``(1, page_size)`` rows:
+    the K dequant is folded into the logits (``q.(s_t k_t) =
+    s_t (q.k_t)``) and the V dequant into the probabilities
+    (``sum_t p_t s_t v_t``), so a scale row broadcasts along lanes and
+    is never relaid out into a (page_size, 1) column."""
     q = q_ref[0, 0].astype(jnp.float32)              # (gp, d)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (gp, page_size)
+        preferred_element_type=jnp.float32)          # (gp, page_size)
+    if k_rows is not None:
+        s = s * k_rows
+    s = s * scale
     k_pos = k_block_start + jax.lax.broadcasted_iota(
         jnp.int32, (gp, page_size), 1)
     valid = k_pos < length                           # partial-page mask
@@ -172,18 +185,12 @@ def _paged_page_step(q_ref, k, v, m_ref, l_ref, acc_ref, *,
     p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    pv = p if v_rows is None else p * v_rows
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        pv, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-
-def _dequant_page(kq, sq):
-    """Fused dequant epilogue of the int8 page load: (ps, d) int8 page x
-    (ps,) scale row -> f32 values, in-register (the page streamed from
-    HBM at int8 width — this is what makes int8 KV bandwidth-neutral)."""
-    return kq.astype(jnp.float32) * sq.reshape(-1, 1)
 
 
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
@@ -207,7 +214,8 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0, 0]
+    h = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
     k_block_start = pi * page_size
 
     # Pages at or past the length are either the partial tail (handled
@@ -215,15 +223,18 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     # the pool's null sink — the guard skips the sink pages entirely.
     @pl.when(k_block_start < length)
     def _body():
+        k = k_ref[0, 0].astype(jnp.float32)          # (page_size, d)
+        v = v_ref[0, 0].astype(jnp.float32)
+        k_rows = v_rows = None
         if quantized:
-            k = _dequant_page(k_ref[0, 0], ks_ref[0, 0])
-            v = _dequant_page(v_ref[0, 0], vs_ref[0, 0])
-        else:
-            k = k_ref[0, 0].astype(jnp.float32)      # (page_size, d)
-            v = v_ref[0, 0].astype(jnp.float32)
+            # The scale block holds every KV head's rows of this page;
+            # pick this head's (1, page_size) row.
+            k_rows = ks_ref[0, pl.ds(h, 1), :]
+            v_rows = vs_ref[0, pl.ds(h, 1), :]
         _paged_page_step(q_ref, k, v, m_ref, l_ref, acc_ref,
                          k_block_start=k_block_start, length=length,
-                         gp=gp, page_size=page_size, scale=scale)
+                         gp=gp, page_size=page_size, scale=scale,
+                         k_rows=k_rows, v_rows=v_rows)
 
     @pl.when(pi == n_pages - 1)
     def _done():
@@ -240,11 +251,12 @@ def _paged_decode_dbuf_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     step dereferences the block table itself and issues explicit async
     copies into two VMEM page slots, starting page ``pi+1``'s copy
     *before* waiting on page ``pi`` — the next page's KV loads overlap
-    this page's softmax/matmul.  The arithmetic body is shared with the
-    single-buffer kernel, so outputs are bit-identical."""
+    this page's softmax/matmul.  int8 scale rows are small and ride the
+    BlockSpec pipeline, as in the single-buffer kernel.  The arithmetic
+    body is shared with that kernel, so outputs are bit-identical."""
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, m_ref, l_ref, acc_ref,
-         k_buf, v_buf, ks_buf, vs_buf, sem) = rest
+        (ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref,
+         k_buf, v_buf, sem) = rest
     else:
         o_ref, m_ref, l_ref, acc_ref, k_buf, v_buf, sem = rest
     bb = pl.program_id(0)
@@ -253,23 +265,15 @@ def _paged_decode_dbuf_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
 
     def page_copies(slot, page_idx):
         """The (src, dst, sem) copy descriptors of one page gather.
-        ``.start()`` on all of them issues the slot's DMAs; ``.wait()``
-        blocks until the slot holds the page."""
+        ``.start()`` on both issues the slot's DMAs; ``.wait()`` blocks
+        until the slot holds the page."""
         page = bt_ref[bb, page_idx]
-        copies = [
+        return [
             pltpu.make_async_copy(k_hbm.at[page, h], k_buf.at[slot],
                                   sem.at[0, slot]),
             pltpu.make_async_copy(v_hbm.at[page, h], v_buf.at[slot],
                                   sem.at[1, slot]),
         ]
-        if quantized:
-            copies += [
-                pltpu.make_async_copy(ks_hbm.at[page, h], ks_buf.at[slot],
-                                      sem.at[2, slot]),
-                pltpu.make_async_copy(vs_hbm.at[page, h], vs_buf.at[slot],
-                                      sem.at[3, slot]),
-            ]
-        return copies
 
     @pl.when(pi == 0)
     def _init():
@@ -292,26 +296,41 @@ def _paged_decode_dbuf_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest,
     for c in page_copies(slot, pi):
         c.wait()
 
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
     k_block_start = pi * page_size
 
     @pl.when(k_block_start < length)
     def _body():
+        k = k_buf[slot].astype(jnp.float32)
+        v = v_buf[slot].astype(jnp.float32)
+        k_rows = v_rows = None
         if quantized:
-            k = _dequant_page(k_buf[slot], ks_buf[slot])
-            v = _dequant_page(v_buf[slot], vs_buf[slot])
-        else:
-            k = k_buf[slot].astype(jnp.float32)
-            v = v_buf[slot].astype(jnp.float32)
+            k_rows = ks_ref[0, pl.ds(h, 1), :]
+            v_rows = vs_ref[0, pl.ds(h, 1), :]
         _paged_page_step(q_ref, k, v, m_ref, l_ref, acc_ref,
                          k_block_start=k_block_start, length=length,
-                         gp=gp, page_size=page_size, scale=scale)
+                         gp=gp, page_size=page_size, scale=scale,
+                         k_rows=k_rows, v_rows=v_rows)
 
     @pl.when(pi == n_pages - 1)
     def _done():
         l = l_ref[...][:, :1]
         safe_l = jnp.where(l > 0.0, l, 1.0)
         o_ref[0, 0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+
+
+def page_row_dim(d_head: int) -> int:
+    """Width of one stored KV page row: the head dim rounded up to a
+    multiple of 128 lanes, the pad lanes holding zeros.  The explicit-
+    DMA page gather (``buffers=2``) can only slice an HBM array whose
+    minor dim is lane-aligned, and the TPU's tiled HBM layout pads a
+    narrower row to 128 lanes anyway, so the padding costs no device
+    memory.
+
+    >>> page_row_dim(64), page_row_dim(128), page_row_dim(160)
+    (128, 128, 256)
+    """
+    return -(-d_head // _LANES) * _LANES
 
 
 def flash_paged_decode(
@@ -328,7 +347,9 @@ def flash_paged_decode(
     interpret: bool = False,
 ) -> jax.Array:
     """Paged flash decode.  q: (B, Hq, D); k_pages/v_pages:
-    (P, Hkv, page_size, D) pool arrays (P includes the null sink page);
+    (P, Hkv, page_size, R) pool arrays (P includes the null sink page)
+    whose rows hold the head dim in their first D lanes, R >= D (the
+    serving pools use R = :func:`page_row_dim` (D), zero-padded);
     block_tables: (B, max_pages) int32 page ids; length: (B,) int32
     valid rows per slot.  Returns (B, Hq, D).
 
@@ -337,8 +358,9 @@ def flash_paged_decode(
     gathered page by page inside the loop.  ``buffers`` picks the
     gather pipeline: 1 = BlockSpec index maps (the scalar-prefetched
     table dereferenced per step), 2 = explicit two-slot DMA ping-pong
-    (page ``i+1``'s copy issued before page ``i``'s compute).  Both are
-    bit-identical — they share the arithmetic body.
+    (page ``i+1``'s copy issued before page ``i``'s compute), which the
+    TPU compiler accepts only for lane-aligned rows (R % 128 == 0).
+    Both are bit-identical — they share the arithmetic body.
 
     int8 pools pass per-row scale rows ``k_scale``/``v_scale``
     ((P, Hkv, page_size) f32); dequant is fused into the split-K loop,
@@ -349,12 +371,22 @@ def flash_paged_decode(
     kernel).
     """
     b, hq, d = q.shape
-    _, hkv, page_size, _ = k_pages.shape
+    _, hkv, page_size, rd = k_pages.shape
     _, n_pages = block_tables.shape
     assert hq % hkv == 0
     group = hq // hkv
     if buffers not in (1, 2):
         raise ValueError(f"buffers must be 1 or 2, got {buffers}")
+    if rd < d:
+        raise ValueError(f"page rows of {rd} lanes cannot hold head "
+                         f"dim {d}")
+    if buffers == 2 and not interpret and rd % _LANES:
+        raise ValueError(
+            f"buffers=2 gathers pages with explicit DMAs, and the TPU "
+            f"compiler only slices an HBM array whose minor dim is a "
+            f"multiple of 128 lanes (page rows of {rd}).  Store rows of "
+            f"page_row_dim(d) lanes, as init_paged_kv_cache does, or "
+            f"use buffers=1")
     quantized = k_pages.dtype == jnp.int8
     if quantized and (k_scale is None or v_scale is None):
         raise ValueError("int8 k_pages/v_pages need k_scale and v_scale "
@@ -363,20 +395,31 @@ def flash_paged_decode(
         raise ValueError("k_scale/v_scale are only valid for int8 pools")
     if scale is None:
         scale = d ** -0.5
-    len2d = length.reshape(b, 1).astype(jnp.int32)
-    qg = q.reshape(b, hkv, group, d)
+    if rd != d:
+        # Zero query lanes meet the pool's zero pad lanes: q.k and the
+        # output's first d lanes are unchanged.
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, rd - d)))
+    qg = q.reshape(b, hkv, group, rd)
     grid = (b, hkv, n_pages)
 
     head_specs = [
-        pl.BlockSpec((1, 1), lambda bb, h, pi, bt: (bb, 0)),
-        pl.BlockSpec((1, 1, group, d), lambda bb, h, pi, bt: (bb, h, 0, 0)),
+        pl.BlockSpec((1, 1, group, rd),
+                     lambda bb, h, pi, bt, ln: (bb, h, 0, 0)),
     ]
     state_scratch = [
         pltpu.VMEM((group, _LANES), jnp.float32),
         pltpu.VMEM((group, _LANES), jnp.float32),
-        pltpu.VMEM((group, d), jnp.float32),
+        pltpu.VMEM((group, rd), jnp.float32),
     ]
-    operands = [block_tables.astype(jnp.int32), len2d, qg, k_pages, v_pages]
+    operands = [block_tables.astype(jnp.int32),
+                length.reshape(b).astype(jnp.int32), qg, k_pages, v_pages]
+    # The whole (Hkv, page_size) scale block of the page: a one-head
+    # (1, page_size) block would break the TPU rule that a block's last
+    # two dims are (8, 128)-aligned or span the array, and a DMA of a
+    # scale row is not lane-aligned unless page_size % 128 == 0.
+    srow_spec = pl.BlockSpec((1, hkv, page_size),
+                             lambda bb, h, pi, bt, ln: (bt[bb, pi], 0, 0))
+    scale_specs = [srow_spec, srow_spec] if quantized else []
     if quantized:
         operands += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 
@@ -384,48 +427,37 @@ def flash_paged_decode(
         kernel = functools.partial(
             _paged_decode_kernel, n_pages=n_pages, page_size=page_size,
             gp=group, scale=scale, quantized=quantized)
-        page_spec = pl.BlockSpec((1, 1, page_size, d),
-                                 lambda bb, h, pi, bt: (bt[bb, pi], h, 0, 0))
-        in_specs = head_specs + [page_spec, page_spec]
-        if quantized:
-            srow_spec = pl.BlockSpec((1, 1, page_size),
-                                     lambda bb, h, pi, bt: (bt[bb, pi], h, 0))
-            in_specs += [srow_spec, srow_spec]
+        page_spec = pl.BlockSpec(
+            (1, 1, page_size, rd),
+            lambda bb, h, pi, bt, ln: (bt[bb, pi], h, 0, 0))
+        in_specs = head_specs + [page_spec, page_spec] + scale_specs
         scratch = list(state_scratch)
     else:
         kernel = functools.partial(
             _paged_decode_dbuf_kernel, n_pages=n_pages, page_size=page_size,
             gp=group, scale=scale, quantized=quantized)
-        any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        in_specs = head_specs + [any_spec, any_spec]
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
+        in_specs = head_specs + [any_spec, any_spec] + scale_specs
         scratch = list(state_scratch) + [
-            pltpu.VMEM((2, page_size, d), k_pages.dtype),
-            pltpu.VMEM((2, page_size, d), v_pages.dtype),
+            pltpu.VMEM((2, page_size, rd), k_pages.dtype),
+            pltpu.VMEM((2, page_size, rd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ]
-        n_copies = 2
-        if quantized:
-            in_specs += [any_spec, any_spec]
-            scratch += [
-                pltpu.VMEM((2, page_size), jnp.float32),
-                pltpu.VMEM((2, page_size), jnp.float32),
-            ]
-            n_copies = 4
-        scratch.append(pltpu.SemaphoreType.DMA((n_copies, 2)))
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, group, d),
-                                   lambda bb, h, pi, bt: (bb, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, group, rd),
+                                   lambda bb, h, pi, bt, ln: (bb, h, 0, 0)),
             scratch_shapes=scratch,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        compiler_params=_compat.CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, rd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=f"gama_flash_paged_decode_b{buffers}",
     )(*operands)
-    return out.reshape(b, hq, d)
+    return out.reshape(b, hq, rd)[..., :d]

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.core import hw
 
 _LANES = 128
 _NEG_INF = -1e30
@@ -49,11 +49,12 @@ def attention_vmem_bytes(bq: int, bk: int, d: int, in_bytes: int) -> int:
     return 2 * (q + kv) + state + out
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                  k_steps: int, bq: int, bk: int, scale: float,
-                  causal: bool, q_offset: int, kv_len: int):
+def _flash_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                  acc_ref, *, k_steps: int, bq: int, bk: int, scale: float,
+                  causal: bool, kv_len: int):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
+    q_offset = off_ref[0]
 
     @pl.when(ki == 0)
     def _init():
@@ -117,14 +118,16 @@ def flash_attention(
     bk: int = DEFAULT_BK,
     scale: Optional[float] = None,
     causal: bool = True,
-    q_offset: int = 0,
+    q_offset=0,
     kv_len: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D); Sq % bq == Sk % bk == 0.
 
     GQA mapping is done by the kv index_map (q head h reads kv head
-    h // (Hq // Hkv)) — no KV replication in HBM.
+    h // (Hq // Hkv)) — no KV replication in HBM.  ``q_offset`` may be
+    traced (a chunked prefill's cursor): it reaches the kernel as a
+    scalar-prefetch operand, so one compiled kernel serves every cursor.
     """
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape
@@ -140,29 +143,37 @@ def flash_attention(
 
     kernel = functools.partial(
         _flash_kernel, k_steps=k_steps, bq=bq, bk=bk, scale=scale,
-        causal=causal, q_offset=q_offset, kv_len=kv_len)
+        causal=causal, kv_len=kv_len)
+    offset = jnp.asarray(q_offset, jnp.int32).reshape(1)
 
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bb, h, qi, ki: (bb, h, qi, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bb, h, qi, ki, g=group: (bb, h // g, ki, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bb, h, qi, ki, g=group: (bb, h // g, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda bb, h, qi, ki: (bb, h, qi, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, bq, d),
+                             lambda bb, h, qi, ki, off: (bb, h, qi, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda bb, h, qi, ki, off, g=group:
+                             (bb, h // g, ki, 0)),
+                pl.BlockSpec((1, 1, bk, d),
+                             lambda bb, h, qi, ki, off, g=group:
+                             (bb, h // g, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, bq, d),
+                                   lambda bb, h, qi, ki, off: (bb, h, qi, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((bq, _LANES), jnp.float32),   # running max
+                pltpu.VMEM((bq, _LANES), jnp.float32),   # running denom
+                pltpu.VMEM((bq, d), jnp.float32),        # output acc
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running denominator
-            pltpu.VMEM((bq, d), jnp.float32),        # output accumulator
-        ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=hw.TPU_V5E.vmem_limit),
         interpret=interpret,
         name="gama_flash_attention",
-    )(q, k, v)
+    )(offset, q, k, v)

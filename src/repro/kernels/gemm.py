@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.core import hw
 
 # Integer output ranges for the saturating epilogue.
 _INT_RANGE = {
@@ -120,8 +120,9 @@ def gama_gemm(
         out_specs=pl.BlockSpec((tm, tn), o_map),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), acc_dtype)],
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=hw.TPU_V5E.vmem_limit),
         interpret=interpret,
         name="gama_gemm",
     )(a, b)

@@ -9,6 +9,12 @@ alignment, GQA group padding, and backend dispatch:
 * mode="kernel": force the Pallas kernel (interpret=True off-TPU);
 * mode="ref": force the jnp oracle.
 
+:func:`force_mode` re-resolves every ``mode="auto"`` call traced inside
+it — how a whole model runs through the ``kernels/ref.py`` oracles on
+the chip, as the reference its kernel path is checked against.  Each
+call counts its route (``ops.<op>.kernel`` / ``.ref`` / ``.pack``) in
+``repro.obs`` at trace time.
+
 Above the pack threshold, :func:`matmul` additionally dispatches to the
 pack-level sharded GEMM (``repro.distributed.pack_gemm``) when a pack
 context is installed — the paper's three-level scaling: single kernel
@@ -18,11 +24,13 @@ then selects the backend of each *local* per-device GEMM.
 
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Optional
+from typing import Callable, Iterator, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import flash_decode, flash_paged_decode
@@ -33,12 +41,31 @@ from repro.obs import count as _obs_count
 
 Mode = str  # "auto" | "kernel" | "ref"
 
+_FORCED: Optional[Mode] = None   # what "auto" resolves to in force_mode
+
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+@contextlib.contextmanager
+def force_mode(mode: Mode) -> Iterator[None]:
+    """Resolve ``mode="auto"`` to ``mode`` ("ref" or "kernel") for every
+    op traced inside the block.  Jitted functions must be traced inside
+    it (a cached trace keeps the routes it was traced with)."""
+    global _FORCED
+    if mode not in ("ref", "kernel"):
+        raise ValueError(f"force_mode takes 'ref' or 'kernel', got {mode!r}")
+    prev, _FORCED = _FORCED, mode
+    try:
+        yield
+    finally:
+        _FORCED = prev
+
+
 def _use_kernel(mode: Mode) -> bool:
+    if mode == "auto" and _FORCED is not None:
+        mode = _FORCED
     if mode == "kernel":
         return True
     if mode == "ref":
@@ -48,6 +75,23 @@ def _use_kernel(mode: Mode) -> bool:
 
 def _interpret() -> bool:
     return not on_tpu()
+
+
+def _on_each_device(fn: Callable) -> Callable:
+    """XLA cannot partition a Pallas (Mosaic) kernel.  In a program that
+    spans an installed multi-device pack mesh — the GEMMs above the
+    pack threshold run shard_mapped over it — every other kernel call
+    therefore runs whole on each device, replicated in and out, through
+    a shard_map of its own.  Inside a shard_map already (the pack's own
+    per-device GEMMs), or with no multi-device pack mesh, ``fn`` is
+    returned as is."""
+    import repro.distributed.pack_gemm as pg
+    ctx = pg.get_pack_context()
+    if (ctx is None or ctx.mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return fn
+    return jax.shard_map(fn, mesh=ctx.mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -123,8 +167,9 @@ def matmul(a: jax.Array, b: jax.Array, *, out_dtype=None, scale: float = 1.0,
     mp, kp, np_ = _round_up(m, tm), _round_up(k, tk), _round_up(n, tn)
     ap = jnp.pad(a, ((0, mp - m), (0, kp - k)))
     bp = jnp.pad(b, ((0, kp - k), (0, np_ - n)))
-    out = gama_gemm(ap, bp, tm=tm, tk=tk, tn=tn, out_dtype=out_dtype,
-                    scale=scale, order=order, interpret=_interpret())
+    out = _on_each_device(functools.partial(
+        gama_gemm, tm=tm, tk=tk, tn=tn, out_dtype=out_dtype, scale=scale,
+        order=order, interpret=_interpret()))(ap, bp)
     return out[:m, :n]
 
 
@@ -160,9 +205,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qp = jnp.pad(q, ((0, 0), (0, 0), (0, sqp - sq), (0, 0)))
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, skp - sk), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, skp - sk), (0, 0)))
-    out = flash_attention(qp, kp, vp, bq=bq, bk=bk, scale=scale,
-                          causal=causal, q_offset=q_offset, kv_len=sk,
-                          interpret=_interpret())
+    out = _on_each_device(
+        lambda q_, k_, v_, off: flash_attention(
+            q_, k_, v_, bq=bq, bk=bk, scale=scale, causal=causal,
+            q_offset=off, kv_len=sk, interpret=_interpret()))(
+        qp, kp, vp, jnp.asarray(q_offset, jnp.int32))
     return out[:, :, :sq]
 
 
@@ -213,11 +260,23 @@ def decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
         qq = qg.reshape(b, hkv * gp, d)
     else:
         qq = q
-    out = flash_decode(qq, kp, vp, length=length, bk=bk, scale=scale,
-                       interpret=_interpret())
+    out = _on_each_device(
+        lambda q_, k_, v_, ln: flash_decode(
+            q_, k_, v_, length=ln, bk=bk, scale=scale,
+            interpret=_interpret()))(qq, kp, vp, length)
     if gp != group:
         out = out.reshape(b, hkv, gp, d)[:, :, :group].reshape(b, hq, d)
     return out
+
+
+def to_page_rows(x: jax.Array, width: int) -> jax.Array:
+    """Zero-pad KV rows (..., D) to a page pool's row width
+    (``decode_attention.page_row_dim``), as they are written into the
+    pool."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array, *,
@@ -229,18 +288,21 @@ def decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array, *,
                  mode: Mode = "auto") -> jax.Array:
     """Single-token decode attention over a **paged** KV cache
     (``repro.serving.kvpool``).  q: (B,Hq,D); k_pages/v_pages:
-    (P,Hkv,page_size,D) pool arrays; block_tables: (B,max_pages) int32
-    page ids; length: (B,) int32 per-slot valid rows.
+    (P,Hkv,page_size,R) pool arrays, the head dim in the first D lanes
+    of each row (R = ``page_row_dim(D)`` for the serving pools);
+    block_tables: (B,max_pages) int32 page ids; length: (B,) int32
+    per-slot valid rows.
 
     The kernel path gathers each slot's pages via the block table
     inside the split-K loop (one page per step, the last partial page
     masked by ``length``); ``buffers=2`` (default) double-buffers that
-    gather with explicit DMA copy slots so page i+1's loads overlap
-    page i's softmax/matmul, and ``buffers=1`` keeps the serial
-    BlockSpec gather — both bit-identical.  int8 pools pass per-row
-    ``k_scale``/``v_scale`` rows ((P,Hkv,page_size) f32); dequant fuses
-    into the split-K loop.  The ref path dequantizes + materializes the
-    gather and runs the dense decode oracle — mathematically identical.
+    gather with explicit DMA copy slots so page i+1's loads overlap page
+    i's softmax/matmul, and ``buffers=1`` keeps the serial BlockSpec
+    gather — both bit-identical.
+    int8 pools pass per-row ``k_scale``/``v_scale`` rows
+    ((P,Hkv,page_size) f32); dequant fuses into the split-K loop.  The
+    ref path dequantizes + materializes the gather and runs the dense
+    decode oracle — mathematically identical.
     """
     _check_gqa(q.shape[1], k_pages.shape[1])
     b, hq, d = q.shape
@@ -279,10 +341,11 @@ def decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array, *,
         qq = qg.reshape(b, hkv * gp, d)
     else:
         qq = q
-    out = flash_paged_decode(qq, k_pages, v_pages, block_tables,
-                             length=length, scale=scale,
-                             k_scale=k_scale, v_scale=v_scale,
-                             buffers=buffers, interpret=_interpret())
+    out = _on_each_device(
+        lambda q_, kp_, vp_, bt, ln, ks, vs: flash_paged_decode(
+            q_, kp_, vp_, bt, length=ln, scale=scale, k_scale=ks,
+            v_scale=vs, buffers=buffers, interpret=_interpret()))(
+        qq, k_pages, v_pages, block_tables, length, k_scale, v_scale)
     if gp != group:
         out = out.reshape(b, hkv, gp, d)[:, :, :group].reshape(b, hq, d)
     return out
@@ -311,7 +374,8 @@ def wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         w2 = jnp.pad(w, zp, constant_values=1.0)
     else:
         r2, k2, v2, w2 = r, k, v, w
-    out = wkv6(r2, k2, v2, w2, u, chunk=chunk, interpret=_interpret())
+    out = _on_each_device(functools.partial(
+        wkv6, chunk=chunk, interpret=_interpret()))(r2, k2, v2, w2, u)
     return out[:, :, :t]
 
 

@@ -211,9 +211,13 @@ def ref_paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     """Oracle for flash_paged_decode: dequantize the pools (int8 pages
     carry per-row scale rows), gather pages contiguous, then the dense
     decode oracle.  Unallocated table entries point at the null sink
-    page; ``length`` masks them (and the partial tail page) out."""
+    page; ``length`` masks them (and the partial tail page) out.  Pool
+    rows may be wider than the head dim (lane padding); only the first
+    ``D`` lanes are read."""
+    d = q.shape[-1]
     kc = gather_pages(dequantize_pool(k_pages, k_scale), block_tables)
     vc = gather_pages(dequantize_pool(v_pages, v_scale), block_tables)
+    kc, vc = kc[..., :d], vc[..., :d]
     return ref_decode_attention(q, kc, vc, length=length, scale=scale)
 
 

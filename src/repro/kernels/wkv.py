@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_ref, *,
                 chunk: int):
@@ -75,7 +73,7 @@ def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((b, h, t, n), r.dtype),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="gama_wkv6",

@@ -251,7 +251,7 @@ def run_cell(arch_id: str, shape_id: str, *, multi_pod: bool = False,
              train_dtype: Optional[str] = None,
              keep_hlo: bool = False) -> Dict[str, Any]:
     """Lower + compile one cell on the production mesh; return the record."""
-    from repro.launch.mesh import make_production_mesh, mesh_context
+    from repro.launch.mesh import make_production_mesh
     mesh = make_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
     cell = build_cell(arch_id, shape_id, mesh, schedule=schedule,
@@ -260,7 +260,7 @@ def run_cell(arch_id: str, shape_id: str, *, multi_pod: bool = False,
                       serve_dtype=serve_dtype, train_dtype=train_dtype)
 
     t0 = time.monotonic()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.fn, in_shardings=cell.in_shardings)
         lowered = jitted.lower(*cell.args)
         t_lower = time.monotonic() - t0

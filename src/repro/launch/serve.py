@@ -30,10 +30,11 @@ Trace file (``--trace``, JSON lines; see docs/SERVING.md)::
 synthesize that many random tokens.  ``cancel_after: N`` cancels the
 request after its Nth streamed token (``engine.cancel`` frees its slot
 and pages the same step).  ``--verify`` re-runs every completed request
-through a one-slot one-shot *dense* ``generate()`` and checks the
-continuous outputs are identical (for ``--kv paged`` this is the
-paged-vs-dense bit-identity check; with ``--prefill-chunk`` it is the
-chunked-vs-monolithic check too).  ``--kv paged`` serves through the
+through a one-slot one-shot ``generate()`` with the same KV layout and
+holds the continuous run to it (with ``--prefill-chunk`` this is the
+chunked-vs-monolithic check too): every logits row both runs computed
+from the same inputs must agree within ``VERIFY_TOL`` row standard
+deviations, so greedy tokens may part only at a near-tie.  ``--kv paged`` serves through the
 ``repro.serving.kvpool`` page pool (``--page_size``/``--pool_pages``)
 and logs page-reclaim/preemption events plus the pool high-water mark;
 ``--kv-dtype int8`` stores the pages quantized (per-row scales,
@@ -51,7 +52,12 @@ N-token chunks interleaved with in-flight decode (0 = monolithic,
 unified step loop's budget and admission policy.
 ``--mesh D,M`` installs a pack mesh so the large GEMMs run as
 pack-level collective matmuls (simulate devices with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  The arch's
+smoke preset is served by default; ``--no-smoke`` serves its published
+widths, and ``--platform tpu`` refuses to run anywhere but on the chip:
+
+    python -m repro.launch.serve --arch smollm_360m --no-smoke \
+        --platform tpu --kv paged --page_size 16 --prefill-chunk 128
 """
 
 from __future__ import annotations
@@ -62,6 +68,14 @@ import time
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
+
+# --verify: max |continuous - one-shot| over each logits row computed
+# from the same inputs, in units of the one-shot row's standard
+# deviation.  A wrong slot, page, position or mask moves logits by O(1)
+# standard deviations; reordered bf16 sums (a 4-slot program against a
+# 1-slot one on a TPU) move them by a few hundredths.  On the CPU the
+# rows are bit-identical.
+VERIFY_TOL = 0.1
 
 
 def shared_prefix_tokens(group: int, prefix_len: int, vocab_size: int,
@@ -390,6 +404,7 @@ def run_trace(engine, trace: List[dict],
     tokens = sum(len(v) for v in results.values())
     rep = {
         "results": results,
+        "rids": {tid: rid for rid, tid in rid_to_tid.items()},
         "cancelled_ids": sorted(cancelled_tids),
         "wall_s": wall,
         "tokens": tokens,
@@ -428,10 +443,19 @@ def run_trace(engine, trace: List[dict],
     return rep
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", type=str, default="qwen3_8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--arch", type=str, default="smollm_360m",
+                    help="config id (repro.configs); the default fits one "
+                         "16 GB chip at its published widths")
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the arch's 2-layer smoke preset; "
+                         "--no-smoke serves its FULL published widths")
+    ap.add_argument("--platform", type=str, default=None,
+                    choices=("tpu", "cpu"),
+                    help="fail unless jax runs on this platform (e.g. "
+                         "--platform tpu never carries on on the CPU)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--batch_slots", type=int, default=4,
                     help="KV slots (0 = resolve from the tuner)")
@@ -552,7 +576,11 @@ def main() -> None:
     ap.add_argument("--verify", action="store_true",
                     help="check each completed request against a "
                          "one-shot single-slot generate() (greedy only)")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv: Optional[List[str]] = None) -> None:
+    args = build_parser().parse_args(argv)
     if args.verify and args.temperature > 0.0:
         raise SystemExit(
             "--verify requires greedy decoding (temperature=0): the "
@@ -563,8 +591,17 @@ def main() -> None:
     import jax
 
     from repro import configs as C, obs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_params
     from repro.serving.engine import ServeConfig, ServeEngine
+
+    dev = jax.devices()[0]
+    print(f"[serve] device platform={dev.platform} "
+          f"kind={dev.device_kind} count={len(jax.devices())}")
+    if args.platform is not None and dev.platform != args.platform:
+        raise SystemExit(f"[serve] asked for --platform {args.platform} "
+                         f"but jax runs on {dev.platform}")
+    print(f"[serve] compile cache: {enable_compile_cache()}")
 
     # Fresh metrics for this run; span recording only when a trace is
     # actually being written (spans cost a clock read each).
@@ -594,9 +631,10 @@ def main() -> None:
     max_len = max(len(t["prompt"]) + t["max_new"] for t in trace) + 8
     mesh = None
     if args.mesh:
-        from repro.launch.mesh import compat_make_mesh
+        from jax.sharding import AxisType
         d, m = (int(x) for x in args.mesh.split(","))
-        mesh = compat_make_mesh((d, m), ("data", "model"))
+        mesh = jax.make_mesh((d, m), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     engine = ServeEngine(cfg, params, ServeConfig(
         batch_slots=args.batch_slots, max_len=max_len,
         temperature=args.temperature, seed=args.seed,
@@ -620,12 +658,16 @@ def main() -> None:
         def stream_cb(tid, tok, done):
             print(f"[stream] id={tid} token={tok}"
                   f"{' (done)' if done else ''}")
+    served_rows: Dict[int, Dict[int, "jax.Array"]] = {}
     try:
         if args.warmup:
             run_trace(engine, trace, log=None)
             engine.drain()
             bundle.registry.reset_values()
             engine.profiler.reset_totals()
+        if args.verify:
+            engine.logits_tap = lambda rid, pos, row: served_rows.setdefault(
+                rid, {}).__setitem__(pos, row)
         rep = run_trace(engine, trace, stream=stream_cb,
                         speed=args.speed)
         expected = len(trace) - len(rep["cancelled_ids"])
@@ -650,17 +692,22 @@ def main() -> None:
             print(f"[serve] cancelled ids={rep['cancelled_ids']} "
                   f"(slots/pages reclaimed same-step)")
         # The paper's %-of-peak analogue: achieved decode throughput
-        # over the analytic device peak (VE2802 reference off-TPU).
-        eff = obs.efficiency.serve_efficiency(cfg, rep["tok_s"])
-        bundle.registry.gauge(
-            "serve.efficiency",
-            "achieved decode throughput / analytic peak").set(eff)
-        print(f"[serve] efficiency={eff:.3e} of analytic peak "
-              f"(backend={jax.default_backend()})")
+        # over the published peak of the chip (none on the CPU).
+        if obs.efficiency.device_kind() is None:
+            print("[serve] efficiency=not measured (no device peak on "
+                  "the cpu)")
+        else:
+            eff = obs.efficiency.serve_efficiency(cfg, rep["tok_s"])
+            bundle.registry.gauge(
+                "serve.efficiency",
+                "achieved decode throughput / published peak").set(eff)
+            print(f"[serve] efficiency={eff:.3e} of the published peak "
+                  f"({obs.efficiency.device_kind()}, incl. compile)")
         # Step-time attribution: the run's device/bubble split and the
         # per-kernel roofline stall table (worst bound_ratio first).
         stall = " ".join(
             f"{k.name}:{k.stall_class}({k.bound_ratio:.1e})"
+            if k.stall_class is not None else f"{k.name}:not-measured"
             for k in engine.profiler.kernel_table()) or "n/a"
         print(f"[serve] attribution: bubble={rep['bubble_fraction']:.3f} "
               f"(bubble_ms={rep['bubble_ms_total']:.1f}) stall={stall}")
@@ -694,14 +741,17 @@ def main() -> None:
                   f"/{engine.pool.num_pages} pages")
         if args.verify:
             done_trace = [t for t in trace if t["id"] in rep["results"]]
-            _verify(cfg, params, done_trace, rep["results"], engine.scfg)
+            _verify(cfg, params, done_trace, rep["results"], engine.scfg,
+                    {t["id"]: served_rows[rep["rids"][t["id"]]]
+                     for t in done_trace})
         if args.trace_out:
             n = bundle.tracer.write(args.trace_out)
             obs.validate_chrome_trace(bundle.tracer.chrome_trace())
             print(f"[serve] wrote {n} trace events -> {args.trace_out} "
                   f"(open in chrome://tracing or ui.perfetto.dev)")
         if args.metrics_out:
-            run_section = {k: v for k, v in rep.items() if k != "results"}
+            run_section = {k: v for k, v in rep.items()
+                           if k not in ("results", "rids")}
             run_section["arch"] = cfg.name
             run_section["kv_mode"] = engine.kv_mode
             run_section["prefill_chunk"] = engine.prefill_chunk
@@ -710,8 +760,7 @@ def main() -> None:
                 extra={"run": run_section},
                 required_histograms=("serve.ttft_ms",
                                      "serve.inter_token_ms"),
-                required_gauges=("kvpool.pages_in_use",
-                                 "serve.efficiency", "serve.kv_tokens",
+                required_gauges=("kvpool.pages_in_use", "serve.kv_tokens",
                                  "serve.bubble_fraction"))
             print(f"[serve] wrote metrics snapshot -> {args.metrics_out}")
         if args.prom_out:
@@ -728,47 +777,74 @@ def main() -> None:
         engine.close()
 
 
-def _verify(cfg, params, trace, results, scfg) -> None:
-    """Re-run every request one-shot (one slot, same kernels/pack
-    context) and compare with the continuous-batching outputs.  The
-    one-shot engine always prefills monolithically, so with
-    ``prefill_chunk`` set this is the chunked-vs-whole-prompt
-    bit-identity check.  For a full-precision paged run the one-shot
-    engine is *dense*, so it is also the paged-vs-dense check.  With a
-    quantized ``kv_dtype`` the one-shot reference keeps the same paged
-    quantized layout (dense has no page pool to retype and would add
-    quantization noise to the diff): the check then isolates the
-    continuous-batching machinery — admission, chunking, paging,
-    batched decode — which must be bit-identical run to run; the
-    quantization *error* itself is bounded separately
-    (tests/test_quant.py)."""
+def _verify(cfg, params, trace, results, scfg, served_rows) -> None:
+    """Re-run every request one-shot (one slot, same kernels, pack
+    context, KV layout and page dtype; the whole prompt prefilled at
+    once) and hold the continuous-batching run to it.  The check
+    isolates the batching machinery — admission, multi-slot decode,
+    paging, chunking.  ``served_rows[trace id][pos]`` are the continuous
+    run's logits rows.
+
+    Every row both runs computed from the same inputs (the prompt's last
+    position, then each decode step up to and including the first step
+    where the greedy tokens part) must satisfy max |continuous -
+    one-shot| <= VERIFY_TOL x the one-shot row's standard deviation.
+    The tokens may part only where the one-shot row's gap between the
+    two tokens is within twice that bound: a near-tie, which on a TPU a
+    4-slot and a 1-slot program (whose bf16 sums run in different
+    orders) may break either way."""
     import dataclasses
 
-    from repro.serving.engine import ServeConfig, ServeEngine
+    from repro.serving.engine import ServeEngine
     # The reference never shares pages: with ``prefix_cache`` set this
-    # is also the shared-vs-private-pages bit-identity check.
-    if scfg.kv_dtype is None:
-        one_scfg = dataclasses.replace(scfg, batch_slots=1, kv="dense",
-                                       prefill_chunk=0,
-                                       prefix_cache=False)
-        ref_name = "one-shot dense generate()"
-    else:
-        one_scfg = dataclasses.replace(scfg, batch_slots=1,
-                                       prefill_chunk=0,
-                                       prefix_cache=False)
-        ref_name = f"one-shot paged/{scfg.kv_dtype} generate()"
+    # is also the shared-vs-private-pages check.
+    one_scfg = dataclasses.replace(scfg, batch_slots=1, prefill_chunk=0,
+                                   prefix_cache=False)
+    ref_name = (f"one-shot {scfg.kv}/{scfg.kv_dtype or 'cache-dtype'} "
+                f"generate()")
     one = ServeEngine(cfg, params, one_scfg)
+    ref_rows: Dict[int, "jax.Array"] = {}
+    one.logits_tap = lambda rid, pos, row: ref_rows.__setitem__(pos, row)
     try:
-        bad = []
+        bad, parted, worst = [], [], 0.0
         for t in trace:
+            ref_rows.clear()
             want = one.generate(t["prompt"][None, :], t["max_new"])[0]
             got = results[t["id"]]
-            if not np.array_equal(want, got):
-                bad.append(t["id"])
+            n = min(len(want), len(got))
+            split = next((i for i in range(n) if want[i] != got[i]), None)
+            last = (n - 1) if split is None else split
+            base = len(t["prompt"]) - 1
+            dev = 0.0
+            for i in range(last + 1):
+                r = np.asarray(ref_rows[base + i], np.float32)
+                c = np.asarray(served_rows[t["id"]][base + i], np.float32)
+                dev = max(dev, float(np.max(np.abs(c - r)) / np.std(r)))
+            worst = max(worst, dev)
+            if dev > VERIFY_TOL:
+                bad.append(f"id={t['id']} max|d|={dev:.6f} sigma")
+            elif split is None and len(want) != len(got):
+                bad.append(f"id={t['id']} {len(got)} tokens, one-shot "
+                           f"{len(want)}")
+            elif split is not None:
+                r = np.asarray(ref_rows[base + split], np.float32)
+                gap = float((r[want[split]] - r[got[split]]) / np.std(r))
+                if gap > 2 * VERIFY_TOL:
+                    bad.append(f"id={t['id']} parted at step {split} with "
+                               f"one-shot gap {gap:.6f} sigma")
+                else:
+                    parted.append((t["id"], split, gap))
+        for tid, step, gap in parted:
+            print(f"[serve] verify: id={tid} parted at step {step} on a "
+                  f"near-tie (one-shot gap {gap:.6f} sigma)")
         if bad:
-            raise SystemExit(f"[serve] VERIFY FAILED for ids {bad}")
-        print(f"[serve] verify OK: {len(trace)} requests bit-identical "
-              f"to {ref_name}")
+            raise SystemExit(
+                f"[serve] VERIFY FAILED against {ref_name}: "
+                + ", ".join(bad) + f" (tolerance {VERIFY_TOL} sigma)")
+        print(f"[serve] verify OK: {len(trace)} requests within "
+              f"{VERIFY_TOL} sigma of {ref_name} (max|d|={worst:.6f} "
+              f"sigma; tokens identical for "
+              f"{len(trace) - len(parted)}/{len(trace)})")
     finally:
         one.close()
 

@@ -24,7 +24,7 @@ import numpy as np
 from repro import configs as C
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed.sharding import ShardingPolicy
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.models import init_params, layers as L, param_count
 from repro.optim import adamw
 from repro.training.trainer import TrainConfig, Trainer, make_train_step
@@ -74,7 +74,7 @@ def main() -> None:
         # Commit the state to its shardings (jit requires matching layouts).
         params = jax.device_put(params, p_sh)
         opt_state = jax.device_put(opt_state, o_sh)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             step_fn = jax.jit(step, in_shardings=(p_sh, o_sh, None),
                               out_shardings=(p_sh, o_sh, None))
         shardings = {"params": p_sh, "opt": o_sh}

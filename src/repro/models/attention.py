@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+from repro.kernels.decode_attention import page_row_dim
 from repro.models import layers as L
 
 Params = Dict[str, Any]
@@ -62,13 +63,14 @@ def init_paged_kv_cache(num_pages: int, n_kv_heads: int, page_size: int,
     a float name just retypes the pools; ``"int8"`` adds per-row f32
     scale-row arrays (``k_scale``/``v_scale``, one symmetric scale per
     token row per KV head) — the quantized-page layout the fused-dequant
-    decode kernel consumes."""
+    decode kernel consumes.  Page rows are ``page_row_dim(d_head)``
+    lanes wide, the head dim zero-padded to a lane multiple, so the
+    kernel's explicit-DMA page gather compiles for the chip."""
     page_dtype = jnp.dtype(kv_dtype) if kv_dtype else jnp.dtype(dtype)
+    shape = (num_pages, n_kv_heads, page_size, page_row_dim(d_head))
     cache = {
-        "k_pages": jnp.zeros((num_pages, n_kv_heads, page_size, d_head),
-                             page_dtype),
-        "v_pages": jnp.zeros((num_pages, n_kv_heads, page_size, d_head),
-                             page_dtype),
+        "k_pages": jnp.zeros(shape, page_dtype),
+        "v_pages": jnp.zeros(shape, page_dtype),
     }
     if page_dtype == jnp.int8:
         cache["k_scale"] = jnp.zeros((num_pages, n_kv_heads, page_size),
@@ -161,7 +163,7 @@ def attention(
             raise NotImplementedError(
                 "paged KV attention needs per-slot cache_pos and "
                 "block_tables (the continuous-batching decode shape)")
-        page_size = cache["k_pages"].shape[2]
+        page_size, row_dim = cache["k_pages"].shape[2:]
         pos = jnp.asarray(cache_pos, jnp.int32)
         page_ids = block_tables[jnp.arange(b), pos // page_size]
         rows = pos % page_size
@@ -172,8 +174,8 @@ def attention(
             # write its scale into the pool's scale rows.  The decode
             # kernel dequantizes inside its split-K page loop.
             from repro.serving.quant import quantize_kv_row
-            kq, ksc = quantize_kv_row(k[:, :, 0])
-            vq, vsc = quantize_kv_row(v[:, :, 0])
+            kq, ksc = quantize_kv_row(kops.to_page_rows(k[:, :, 0], row_dim))
+            vq, vsc = quantize_kv_row(kops.to_page_rows(v[:, :, 0], row_dim))
             ck = cache["k_pages"].at[page_ids, :, rows, :].set(kq)
             cv = cache["v_pages"].at[page_ids, :, rows, :].set(vq)
             cks = cache["k_scale"].at[page_ids, :, rows].set(ksc)
@@ -186,9 +188,11 @@ def attention(
                                     v_scale=cvs, mode=attn_mode)
         else:
             ck = cache["k_pages"].at[page_ids, :, rows, :].set(
-                k[:, :, 0].astype(cache["k_pages"].dtype))
+                kops.to_page_rows(k[:, :, 0], row_dim).astype(
+                    cache["k_pages"].dtype))
             cv = cache["v_pages"].at[page_ids, :, rows, :].set(
-                v[:, :, 0].astype(cache["v_pages"].dtype))
+                kops.to_page_rows(v[:, :, 0], row_dim).astype(
+                    cache["v_pages"].dtype))
             new_cache = {"k_pages": ck, "v_pages": cv}
             out = kops.decode_paged(q[:, :, 0], ck.astype(x.dtype),
                                     cv.astype(x.dtype),

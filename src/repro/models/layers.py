@@ -1,15 +1,14 @@
 """Shared layers: norms, rotary embeddings (incl. M-RoPE), MLPs, embeddings.
 
 All layers are pure functions over param dicts (pytrees).  Every matmul
-routes through :func:`gemm` so the GAMA Pallas kernel can be swapped in on
-TPU (models default to jnp for CPU smoke tests and the dry-run, which is
-mathematically identical — see kernels/ops.py).
+routes through :func:`gemm`, i.e. ``kernels.ops.matmul``: the GAMA Pallas
+kernel on TPU, the mathematically identical jnp oracle elsewhere (CPU
+smoke tests and the dry-run) — see kernels/ops.py.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -23,15 +22,6 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 # GEMM indirection — the GAMA integration point
 # ---------------------------------------------------------------------------
-
-_GEMM_MODE = "ref"   # "ref" (jnp) | "kernel" (Pallas) — set by set_gemm_mode
-
-
-def set_gemm_mode(mode: str) -> None:
-    global _GEMM_MODE
-    assert mode in ("ref", "kernel", "auto")
-    _GEMM_MODE = mode
-
 
 # Activation-sharding hook: the launcher installs a policy callback
 # (ShardingPolicy.act) and model code marks tensors with semantic kinds
@@ -54,27 +44,20 @@ def shard_hint(x: jax.Array, kind: str) -> jax.Array:
 
 
 def gemm(x: jax.Array, w: jax.Array) -> jax.Array:
-    """x: (..., K) @ w: (K, N) -> (..., N), via the GAMA kernel when on.
+    """x: (..., K) @ w: (K, N) -> (..., N) through ``kernels.ops.matmul``
+    (mode="auto": the GAMA kernel on TPU, the jnp oracle elsewhere, or
+    whatever ``ops.force_mode`` says).
 
     With a pack context installed (``repro.distributed.pack_gemm``),
     GEMMs above its FLOP threshold — in practice the lm head and the
-    ffn projections — route through the pack-level collective matmul
-    even when the Pallas kernel is off (the local per-device GEMMs then
-    use the jnp reference, mode="auto").  The pack context therefore
-    outranks ``set_gemm_mode("ref")`` here: to isolate the pure
-    single-process oracle for numerics debugging, clear the context
-    (``pack_gemm.clear_pack_context()`` / ``engine.close()``) or call
-    ``kernels.ops.matmul(..., mode="ref")`` directly.
+    ffn projections — route through the pack-level collective matmul,
+    whose per-device GEMMs resolve "auto" the same way.  To isolate the
+    pure single-process oracle, clear the context
+    (``pack_gemm.clear_pack_context()`` / ``engine.close()``) and trace
+    under ``ops.force_mode("ref")``.
     """
-    rows = math.prod(x.shape[:-1])
-    use_kernel = _GEMM_MODE == "kernel" or (
-        _GEMM_MODE == "auto" and kops.on_tpu())
-    if not use_kernel and not kops.pack_eligible(rows, x.shape[-1],
-                                                 w.shape[-1]):
-        return x @ w
     lead = x.shape[:-1]
-    x2 = x.reshape(-1, x.shape[-1])
-    out = kops.matmul(x2, w, mode="kernel" if use_kernel else "auto")
+    out = kops.matmul(x.reshape(-1, x.shape[-1]), w)
     return out.reshape(*lead, w.shape[-1])
 
 

@@ -3,21 +3,19 @@
 
 GAMA's headline results are efficiency numbers: 85% of the chip's int8
 peak and 86% of its bf16 peak, i.e. *achieved throughput divided by the
-Eq. 1/Eq. 6 analytic peak*.  This module computes the same ratio for
-every bench level of the repro:
+Eq. 1/Eq. 6 analytic peak*.  This module computes the same ratio:
 
 * ``gemm_efficiency`` — one (M, K, N) GEMM's achieved FLOP/s over the
   device peak (single / pack / array levels);
 * ``serve_efficiency`` — the serving level: achieved decode tokens/s
   times the model's GEMM FLOPs per token, over the device peak.
 
-The peak comes from the hardware models in :mod:`repro.core.hw`: the
-TPU chip model when jax is running on TPU, otherwise the paper's VE2802
-AIE device — the *reference* peak, so CPU interpret-mode runs report
-honestly minuscule efficiencies instead of pretending the host is an
-accelerator.  The ratio is meaningful as a **trend per backend** (the
-perf gate compares it run-over-run on the same backend), and approaches
-the paper's figures only on real accelerator hardware.
+The peak is the published one of the chip jax runs on, looked up by its
+``device_kind`` in :data:`repro.core.hw.DEVICE_PEAKS`.  A device that is
+not in that table — the CPU among them — has no peak, and asking for an
+efficiency there raises: callers print "not measured" instead
+(:func:`device_kind` returns ``None`` off an accelerator).  A CPU
+timing is never divided by an accelerator's peak.
 
 FLOP accounting is GEMM-only (the projections + lm head — the terms
 Eq. 1 models); attention score/value FLOPs and normalizations are
@@ -48,33 +46,52 @@ def precision_for_dtype(dtype_name: str) -> hw.Precision:
     return hw.BF16_BF16
 
 
+def device_kind() -> Optional[str]:
+    """``device_kind`` of the accelerator jax runs on, or ``None`` on
+    the CPU, which has no published peak to measure against."""
+    import jax
+    if jax.default_backend() == "cpu":
+        return None
+    return jax.devices()[0].device_kind
+
+
+def resolve_device_kind(kind: Optional[str]) -> str:
+    """``kind``, or the device jax runs on; raises on the CPU."""
+    if kind is None:
+        kind = device_kind()
+    if kind is None:
+        raise ValueError("no device peak on the CPU: efficiency is not "
+                         "measured there")
+    return kind
+
+
 def peak_flops(dtype_name: str = "bfloat16",
-               backend: Optional[str] = None) -> float:
-    """Analytic peak ops/s for the backend jax is actually running on:
-    the TPU chip model on TPU, else the paper's VE2802 reference chip."""
-    p = precision_for_dtype(dtype_name)
-    if backend is None:
-        import jax
-        backend = jax.default_backend()
-    if backend == "tpu":
-        return hw.TPU_V5E.peak_ops(p)
-    return hw.VE2802.peak_ops(p)
+               device_kind: Optional[str] = None) -> float:
+    """Published peak ops/s of ``device_kind`` (default: the device jax
+    runs on) at the dtype's precision.  Unknown kinds raise.
+
+    >>> peak_flops("bfloat16", device_kind="TPU v5 lite")
+    197000000000000.0
+    """
+    return hw.chip_for_kind(resolve_device_kind(device_kind)).peak_ops(
+        precision_for_dtype(dtype_name))
 
 
 def gemm_efficiency(m: int, k: int, n: int, us_per_call: float,
                     dtype_name: str = "float32",
-                    backend: Optional[str] = None) -> float:
-    """Achieved FLOP/s of one timed GEMM over the analytic peak.
+                    device_kind: Optional[str] = None) -> float:
+    """Achieved FLOP/s of one timed GEMM over the device's peak.
 
-    >>> peak = peak_flops("bf16", backend="cpu")
+    >>> peak = peak_flops("bf16", device_kind="TPU v5 lite")
     >>> us_at_peak = 2 * 64**3 / peak * 1e6
-    >>> round(gemm_efficiency(64, 64, 64, us_at_peak, backend="cpu"), 6)
+    >>> round(gemm_efficiency(64, 64, 64, us_at_peak,
+    ...                       device_kind="TPU v5 lite"), 6)
     1.0
     """
     if us_per_call <= 0:
         raise ValueError(f"us_per_call must be > 0, got {us_per_call}")
     achieved = 2.0 * m * k * n / (us_per_call / 1e6)
-    return achieved / peak_flops(dtype_name, backend)
+    return achieved / peak_flops(dtype_name, device_kind)
 
 
 def model_flops_per_token(cfg) -> float:
@@ -93,12 +110,12 @@ def model_flops_per_token(cfg) -> float:
 
 
 def serve_efficiency(cfg, tok_s: float,
-                     backend: Optional[str] = None) -> float:
+                     device_kind: Optional[str] = None) -> float:
     """The serving level's %-of-peak: achieved decode throughput
-    (tokens/s) x GEMM FLOPs per token, over the analytic peak for the
-    model's compute dtype."""
+    (tokens/s) x GEMM FLOPs per token, over the chip's published peak
+    for the model's compute dtype."""
     if tok_s <= 0:
         raise ValueError(f"tok_s must be > 0, got {tok_s}")
     achieved = tok_s * model_flops_per_token(cfg)
     dtype = getattr(cfg, "compute_dtype", "bfloat16")
-    return achieved / peak_flops(dtype, backend)
+    return achieved / peak_flops(dtype, device_kind)

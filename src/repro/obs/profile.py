@@ -20,10 +20,13 @@ per-kernel "why was it slow" questions for the serving stack:
   flash_paged_decode, prefill chunk scatter) is classified
   compute-bound vs memory-bound from its FLOPs and bytes (taken from
   jax's compiled ``cost_analysis()`` when available) against the
-  :mod:`repro.core.hw` roofline: arithmetic intensity above the ridge
+  published peaks of the chip (:data:`repro.core.hw.DEVICE_PEAKS`,
+  keyed by ``device_kind``): arithmetic intensity above the ridge
   point → compute-bound, below → memory-bound.  The roofline time bound
   ``max(flops/peak, bytes/bw)`` over the measured time gives the
-  achieved-vs-bound ratio (1.0 = at the roofline).
+  achieved-vs-bound ratio (1.0 = at the roofline).  On the CPU there
+  is no peak: the kernel row keeps its costs and timing, and its
+  roofline fields are ``None`` (not measured).
 
 Everything is host-side and cheap; the profiler only does arithmetic on
 timings the engine already takes.
@@ -35,7 +38,7 @@ import dataclasses
 from typing import Dict, List, Mapping, Optional, Tuple
 
 from repro.core import hw
-from repro.obs.efficiency import peak_flops, precision_for_dtype
+from repro.obs.efficiency import peak_flops, resolve_device_kind
 from repro.obs.metrics import Registry
 
 COMPUTE_BOUND = "compute"
@@ -45,25 +48,22 @@ MEMORY_BOUND = "memory"
 HOT_OPS = ("matmul", "flash_decode", "flash_paged_decode", "prefill_chunk")
 
 
-def peak_bandwidth(backend: Optional[str] = None) -> float:
-    """Analytic memory-system bandwidth (bytes/s) for the roofline's
-    slanted roof: HBM on the TPU chip model, the aggregate input PLIO
-    bandwidth on the paper's VE2802 (its kernels stream operands over
-    PLIO, so that is the memory-movement bound Eq. 2-4 model)."""
-    if backend is None:
-        import jax
-        backend = jax.default_backend()
-    if backend == "tpu":
-        return hw.TPU_V5E.hbm_bw
-    dev = hw.VE2802
-    return dev.plio_in * dev.plio_bytes_per_pl_cycle * dev.pl_hz
+def peak_bandwidth(device_kind: Optional[str] = None) -> float:
+    """Published HBM bandwidth (bytes/s) of ``device_kind`` (default:
+    the device jax runs on) — the roofline's slanted roof.  Unknown
+    kinds raise.
+
+    >>> peak_bandwidth("TPU v5 lite")
+    819000000000.0
+    """
+    return hw.chip_for_kind(resolve_device_kind(device_kind)).hbm_bw
 
 
 def ridge_intensity(dtype_name: str = "bfloat16",
-                    backend: Optional[str] = None) -> float:
+                    device_kind: Optional[str] = None) -> float:
     """The roofline ridge point (FLOPs/byte) where the compute roof
-    meets the bandwidth roof for this dtype + backend."""
-    return peak_flops(dtype_name, backend) / peak_bandwidth(backend)
+    meets the bandwidth roof for this dtype and chip."""
+    return peak_flops(dtype_name, device_kind) / peak_bandwidth(device_kind)
 
 
 def extract_costs(compiled) -> Optional[Tuple[float, float]]:
@@ -97,10 +97,12 @@ class KernelProfile:
     bytes: float
     measured_us: float
     intensity: float        # flops / bytes
-    ridge: float            # ridge point for its dtype + backend
-    stall_class: str        # COMPUTE_BOUND | MEMORY_BOUND
-    bound_us: float         # roofline lower bound on time
-    bound_ratio: float      # bound_us / measured_us  (1.0 = at roofline)
+    # Roofline placement against the chip's published peaks; all None
+    # where the device has none (the CPU): not measured.
+    ridge: Optional[float]          # ridge point for its dtype + chip
+    stall_class: Optional[str]      # COMPUTE_BOUND | MEMORY_BOUND
+    bound_us: Optional[float]       # roofline lower bound on time
+    bound_ratio: Optional[float]    # bound_us / measured_us (1.0 = roof)
 
     def as_dict(self) -> Dict[str, object]:
         return dataclasses.asdict(self)
@@ -109,15 +111,19 @@ class KernelProfile:
 def classify_kernel(name: str, flops: float, nbytes: float,
                     measured_us: float,
                     dtype_name: str = "bfloat16",
-                    backend: Optional[str] = None) -> KernelProfile:
-    """Place one timed kernel on the roofline.
+                    device_kind: Optional[str] = None) -> KernelProfile:
+    """Place one timed kernel on the roofline of ``device_kind``'s
+    published peaks (unknown kinds raise).  With no ``device_kind`` (the
+    CPU has no published peak) the row is recorded unplaced: its
+    roofline fields are None.
 
-    >>> p = classify_kernel("gemm", flops=2 * 512**3, nbytes=3 * 512 * 512 * 4,
-    ...                     measured_us=100.0, backend="cpu")
+    >>> p = classify_kernel("gemm", flops=2 * 4096**3,
+    ...                     nbytes=3 * 4096 * 4096 * 2, measured_us=1e3,
+    ...                     device_kind="TPU v5 lite")
     >>> p.stall_class
     'compute'
     >>> p = classify_kernel("scatter", flops=1e3, nbytes=1e9,
-    ...                     measured_us=100.0, backend="cpu")
+    ...                     measured_us=100.0, device_kind="TPU v5 lite")
     >>> p.stall_class
     'memory'
     """
@@ -125,9 +131,14 @@ def classify_kernel(name: str, flops: float, nbytes: float,
         raise ValueError(f"kernel {name}: negative flops/bytes")
     if measured_us <= 0:
         raise ValueError(f"kernel {name}: measured_us must be > 0")
-    peak = peak_flops(dtype_name, backend)
-    bw = peak_bandwidth(backend)
     intensity = flops / nbytes if nbytes > 0 else float("inf")
+    if device_kind is None:
+        return KernelProfile(
+            name=name, flops=flops, bytes=nbytes, measured_us=measured_us,
+            intensity=intensity, ridge=None, stall_class=None,
+            bound_us=None, bound_ratio=None)
+    peak = peak_flops(dtype_name, device_kind)
+    bw = peak_bandwidth(device_kind)
     ridge = peak / bw
     stall = COMPUTE_BOUND if intensity >= ridge else MEMORY_BOUND
     bound_s = max(flops / peak, nbytes / bw)
@@ -147,9 +158,11 @@ class StepProfiler:
     step's wall time and its device-synced section probes; it calls
     :meth:`record_kernel` once per hot op once costs and a steady-state
     timing are known (re-recording a kernel overwrites its row —
-    last-wins, so the table reflects warm timings).
+    last-wins, so the table reflects warm timings).  ``device_kind``
+    names the chip whose published peaks place kernels on the roofline;
+    ``None`` (the CPU) records rows with the roofline not measured.
 
-    >>> prof = StepProfiler(Registry(), backend="cpu")
+    >>> prof = StepProfiler(Registry(), device_kind="TPU v5 lite")
     >>> rec = prof.record_step(10.0, {"decode": 6.0, "prefill": 2.0})
     >>> rec["bubble_ms"]
     2.0
@@ -157,10 +170,13 @@ class StepProfiler:
     0.2
     """
 
-    def __init__(self, registry: Registry, backend: Optional[str] = None,
+    def __init__(self, registry: Registry,
+                 device_kind: Optional[str] = None,
                  dtype_name: str = "bfloat16"):
+        if device_kind is not None:
+            hw.chip_for_kind(device_kind)   # an unknown chip fails here
         self.registry = registry
-        self.backend = backend
+        self.device_kind = device_kind
         self.dtype_name = dtype_name
         self._wall_ms_total = 0.0
         self._bubble_ms_total = 0.0
@@ -225,8 +241,10 @@ class StepProfiler:
         prof = classify_kernel(
             name, flops, nbytes, measured_us,
             dtype_name=dtype_name or self.dtype_name,
-            backend=self.backend)
+            device_kind=self.device_kind)
         self._kernels[name] = prof
+        if prof.stall_class is None:
+            return prof
         self.registry.gauge(
             f"profile.{name}.bound_ratio",
             "roofline bound / measured time").set(prof.bound_ratio)
@@ -237,8 +255,11 @@ class StepProfiler:
         return prof
 
     def kernel_table(self) -> List[KernelProfile]:
-        """Stall table, worst (lowest bound_ratio) first."""
-        return sorted(self._kernels.values(), key=lambda p: p.bound_ratio)
+        """Stall table, worst (lowest bound_ratio) first; rows with the
+        roofline not measured keep their recording order."""
+        return sorted(self._kernels.values(),
+                      key=lambda p: (p.bound_ratio is not None,
+                                     p.bound_ratio or 0.0))
 
     def summary(self) -> Dict[str, object]:
         return {

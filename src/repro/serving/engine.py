@@ -66,10 +66,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops as kops
 from repro.models import (decode_step, forward, init_cache,
                           init_paged_cache, paged_eligible, prefill)
 from repro.models.config import ModelConfig
 from repro.obs import FlightRecorder, SLOMonitor, StepProfiler, get_obs
+from repro.obs.efficiency import device_kind
 from repro.serving.kvpool import (BlockTables, PagePool, PrefixCache,
                                   pages_for)
 from repro.serving.scheduler import (DECODE, PREFILLING, Request,
@@ -447,8 +449,11 @@ class ServeEngine:
         except TypeError:
             dtype_name = "bfloat16"
         self._dtype_bytes = float(jnp.dtype(dtype_name).itemsize)
+        # Roofline rows are placed against the published peaks of the
+        # chip serving (an unknown chip fails here, not mid-serve); on
+        # the CPU they are recorded with the roofline not measured.
         self.profiler = StepProfiler(obs.registry,
-                                     backend=jax.default_backend(),
+                                     device_kind=device_kind(),
                                      dtype_name=dtype_name)
         # Rolling-window tail-latency monitor.  Targets default to off;
         # the launcher arms them (--slo-ttft-ms / --slo-itl-ms).
@@ -484,6 +489,13 @@ class ServeEngine:
         self._scratch: Dict[int, Any] = {}        # slot idx -> chunk scratch
         self._on_token: Dict[int, Callable] = {}  # rid -> stream callback
         self._cancel_log: List[int] = []          # cancels since last step
+        # Optional observer of every logits row the engine samples from:
+        # ``logits_tap(rid, pos, logits)`` with the (V,) f32 device row
+        # computed from the token at position ``pos`` (the prompt's last
+        # token at prefill, then each decode input).  For checking the
+        # served numerics against a reference; None costs nothing.
+        self.logits_tap: Optional[Callable[[int, int, jax.Array], None]] \
+            = None
         self._kv_tokens_hwm = 0       # live-token high-water (dense + paged)
         self.stats = {"admitted": 0, "finished": 0, "prefills": 0,
                       "prefill_chunks": 0, "decode_steps": 0,
@@ -553,7 +565,10 @@ class ServeEngine:
         override are counted at the page dtype; int8 pages additionally
         carry one f32 scale per token row per KV head (the per-row
         scale-row layout), so the int8 figure is D + 4 bytes per head
-        row, not D — roughly half of f32's 4*D for D >= 8."""
+        row, not D — roughly half of f32's 4*D for D >= 8.  These are
+        the model's bytes: the pools store each row in
+        ``page_row_dim(D)`` lanes, as the TPU's tiled HBM layout
+        allocates a row narrower than 128 lanes anyway."""
         cfg = self.cfg
         n_attn = sum(1 for spec in cfg.pattern if spec.mixer == "attn")
         kv_dtype = (self.scfg.kv_dtype if self.kv_mode == "paged"
@@ -611,20 +626,23 @@ class ServeEngine:
         its scale into the pool's scale rows."""
         mp, ps = self._max_pages, self.pool.page_size
 
-        def chunk(dense):
-            # dense: (G, 1, Hkv, mp*ps, D) -> (G, mp, Hkv, ps, D)
+        def chunk(dense, width):
+            # dense: (G, 1, Hkv, mp*ps, D) -> (G, mp, Hkv, ps, width),
+            # the rows zero-padded to the pool's row width.
             g, _, hkv, _, d = dense.shape
-            return dense[:, 0].reshape(g, hkv, mp, ps, d) \
+            pages = dense[:, 0].reshape(g, hkv, mp, ps, d) \
                 .transpose(0, 2, 1, 3, 4)
+            return kops.to_page_rows(pages, width)
 
         def scat(pool, dense):
-            return pool.at[:, bt_row].set(chunk(dense).astype(pool.dtype))
+            return pool.at[:, bt_row].set(
+                chunk(dense, pool.shape[-1]).astype(pool.dtype))
 
         if self.scfg.kv_dtype == "int8":
             from repro.serving.quant import quantize_kv_row
 
             def scat_q(pool, spool, dense):
-                qrows, srows = quantize_kv_row(chunk(dense))
+                qrows, srows = quantize_kv_row(chunk(dense, pool.shape[-1]))
                 return (pool.at[:, bt_row].set(qrows),
                         spool.at[:, bt_row].set(srows))
 
@@ -654,19 +672,20 @@ class ServeEngine:
         instead of O(max_len)."""
         mp, ps = self._max_pages, self.pool.page_size
 
-        def pick(dense):
+        def pick(dense, width):
             # dense: (G, 1, Hkv, mp*ps, D) -> chunk pages
-            # (G, cpp, Hkv, ps, D)
+            # (G, cpp, Hkv, ps, width), the rows zero-padded to the
+            # pool's row width.
             g, _, hkv, _, d = dense.shape
             pages = dense[:, 0].reshape(g, hkv, mp, ps, d) \
                 .transpose(0, 2, 1, 3, 4)
-            return pages[:, src_idx]
+            return kops.to_page_rows(pages[:, src_idx], width)
 
         if self.scfg.kv_dtype == "int8":
             from repro.serving.quant import quantize_kv_row
 
             def scat_q(pool, spool, dense):
-                qrows, srows = quantize_kv_row(pick(dense))
+                qrows, srows = quantize_kv_row(pick(dense, pool.shape[-1]))
                 return (pool.at[:, page_ids].set(qrows),
                         spool.at[:, page_ids].set(srows))
 
@@ -681,7 +700,8 @@ class ServeEngine:
             return out
 
         def scat(pool, dense):
-            return pool.at[:, page_ids].set(pick(dense).astype(pool.dtype))
+            return pool.at[:, page_ids].set(
+                pick(dense, pool.shape[-1]).astype(pool.dtype))
 
         return [{"attn": {
             "k_pages": scat(fc["attn"]["k_pages"], oc["attn"]["k"]),
@@ -922,6 +942,8 @@ class ServeEngine:
                 for s in active:
                     if s.state != DECODE:
                         continue    # cancelled mid-step by a callback
+                    if self.logits_tap is not None:
+                        self.logits_tap(s.rid, s.length, logits[s.index])
                     s.length += 1
                     self._tok[s.index] = toks[s.index]
                     events["decoded"].append(s.rid)
@@ -1033,19 +1055,18 @@ class ServeEngine:
         costs = self._kernel_costs.get(op)
         if costs is None:
             from repro.obs.profile import extract_costs
-            try:
-                if self.kv_mode == "paged":
-                    lowered = self._decode.lower(
-                        self.params, jnp.asarray(self._tok),
-                        jnp.asarray(pos),
-                        jnp.asarray(self._decode_table()), self.caches)
-                else:
-                    lowered = self._decode.lower(
-                        self.params, jnp.asarray(self._tok),
-                        jnp.asarray(pos), self.caches)
-                costs = extract_costs(lowered.compile())
-            except Exception:
-                costs = None
+            # The executable is the one the step just ran (jit's compile
+            # cache hit); a failure here is a real compile failure and
+            # propagates.  Only missing cost figures fall back below.
+            if self.kv_mode == "paged":
+                lowered = self._decode.lower(
+                    self.params, jnp.asarray(self._tok), jnp.asarray(pos),
+                    jnp.asarray(self._decode_table()), self.caches)
+            else:
+                lowered = self._decode.lower(
+                    self.params, jnp.asarray(self._tok), jnp.asarray(pos),
+                    self.caches)
+            costs = extract_costs(lowered.compile())
             if costs is None:
                 costs = self._analytic_decode_costs(op)
             self._kernel_costs[op] = costs
@@ -1294,6 +1315,8 @@ class ServeEngine:
             # while the scratch still holds their full-precision rows.
             self._cache_prefix(slot, req)
         self._scratch.pop(slot.index, None)
+        if self.logits_tap is not None:
+            self.logits_tap(req.rid, plen - 1, logits[0, take - 1])
         tok0 = int(np.asarray(jnp.argmax(logits[0, take - 1])))
         slot.state = DECODE
         slot.length = plen
@@ -1530,6 +1553,8 @@ class ServeEngine:
                     self.caches, one, jnp.asarray(slot.index, jnp.int32))
             self.stats["prefills"] += 1
             slot.length = plen
+            if self.logits_tap is not None:
+                self.logits_tap(req.rid, plen - 1, logits[0, plen - 1])
             return jnp.argmax(logits[0, plen - 1])
 
     # -- legacy one-shot API (reimplemented on the continuous loop) ---------

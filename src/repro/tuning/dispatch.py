@@ -444,8 +444,8 @@ def tune_pack(m: int, k: int, n: int, dtype, *, data_axis: int = 1,
     only a hit while the host still cannot measure: on a host with
     enough devices it counts as a miss and is re-measured."""
     import jax
+    from jax.sharding import AxisType
 
-    from repro.launch.mesh import compat_make_mesh
     dt = canonical_dtype(dtype)
     backend, kind = backend_fingerprint()
     key = cache_key("pack", m, n, k, dt, backend, kind,
@@ -475,7 +475,8 @@ def tune_pack(m: int, k: int, n: int, dtype, *, data_axis: int = 1,
     from repro.tuning import runner
     survivors = prior.prune_pack(space, m, k, n, data_axis, model_axis,
                                  keep=keep)
-    mesh = compat_make_mesh((data_axis, model_axis), ("data", "model"))
+    mesh = jax.make_mesh((data_axis, model_axis), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     da = "data" if data_axis > 1 else None
     return _measure_and_store(
         key, tc, survivors,

@@ -21,9 +21,14 @@ from repro.tuning.space import (AttentionCandidate, DecodeCandidate,
 
 
 def precision_for(dtype_name: str) -> hw.Precision:
-    """Map a jnp dtype name onto the paper's precision descriptors."""
+    """Map a jnp dtype name onto a precision descriptor: the paper's
+    int8 and bf16 pairs, and f32 with its real 4-byte operands — the
+    VMEM budget is in bytes, and counting f32 blocks as bf16 admitted
+    tiles twice the size the kernel's scoped VMEM holds."""
     if dtype_name in ("int8", "int16", "int32", "uint8"):
         return hw.INT8_INT8
+    if dtype_name == "float32":
+        return hw.F32_F32
     return hw.BF16_BF16
 
 

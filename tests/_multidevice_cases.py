@@ -9,20 +9,22 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+from jax.sharding import AxisType, NamedSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.distributed.cascade import (cascade_ffn,  # noqa: E402
                                        cascade_ffn_reference, cascade_matmul)
-from repro.distributed._compat import shard_map  # noqa: E402
 from repro.distributed.compression import compressed_mean_flat  # noqa: E402
 from repro.distributed.pipeline import pipeline_apply  # noqa: E402
 from repro.distributed.sharding import ShardingPolicy  # noqa: E402
-from repro.launch.mesh import (compat_make_mesh,  # noqa: E402
-                               make_host_mesh, mesh_context)
+
+
+def _mesh(shape, axes):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def check_cascade():
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = _mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(16, 32)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(32, 24)), jnp.float32)
@@ -41,7 +43,7 @@ def check_cascade():
 
 
 def check_pipeline():
-    mesh = compat_make_mesh((4, 2), ("pod", "data"))
+    mesh = _mesh((4, 2), ("pod", "data"))
     rng = np.random.default_rng(1)
     ws = jnp.asarray(rng.normal(size=(4, 8, 8)) * 0.5, jnp.float32)
     x = jnp.asarray(rng.normal(size=(6, 3, 8)), jnp.float32)
@@ -55,7 +57,7 @@ def check_pipeline():
 
 
 def check_compression():
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = _mesh((8,), ("data",))
     rng = np.random.default_rng(2)
     gs = jnp.asarray(rng.normal(size=(8, 1024)), jnp.float32)
 
@@ -64,9 +66,9 @@ def check_compression():
         mean, err = compressed_mean_flat(g, jnp.zeros_like(g), "data", 8)
         return mean[None], err[None]
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P("data", None),),
-                   out_specs=(P("data", None), P("data", None)),
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P("data", None),),
+                       out_specs=(P("data", None), P("data", None)),
+                       check_vma=False)
     mean, err = fn(gs)
     true = jnp.mean(gs, axis=0)
     rel = float(jnp.max(jnp.abs(mean[0] - true)) / jnp.max(jnp.abs(true)))
@@ -88,7 +90,7 @@ def check_sharded_train_step():
     cfg = ModelConfig(name="t", n_layers=2, d_model=64, n_heads=4,
                       n_kv_heads=2, d_head=16, d_ff=128, vocab_size=128,
                       compute_dtype="float32", cache_dtype="float32")
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = _mesh((2, 4), ("data", "model"))
     policy = ShardingPolicy(mesh=mesh, data_axes=("data",), fsdp=True)
     params = init_params(jax.random.PRNGKey(0), cfg)
     opt_cfg = adamw.AdamWConfig(lr=1e-3)
@@ -104,7 +106,7 @@ def check_sharded_train_step():
 
     L.set_shard_hook(policy.act)
     try:
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=(
                 policy.param_sharding(params), policy.param_sharding(opt),
                 policy.batch_sharding(batch)))

@@ -17,10 +17,15 @@ os.environ["REPRO_TUNING_CACHE"] = os.path.join(
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 import repro.distributed.pack_gemm as pg  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
-from repro.launch.mesh import compat_make_mesh  # noqa: E402
+
+
+def _mesh(shape):
+    return jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def check_pack_numerics():
@@ -28,7 +33,7 @@ def check_pack_numerics():
     reduce orders and the K-streamed overlap schedule, on divisible and
     deliberately awkward shapes."""
     rng = np.random.default_rng(0)
-    mesh = compat_make_mesh((1, 8), ("data", "model"))
+    mesh = _mesh((1, 8))
     shapes = [(16, 32, 24),     # divisible everywhere
               (13, 100, 27)]    # M/K/N all non-divisible by any grid
     configs = [(1, 8, 0, "psum", False), (2, 4, 0, "psum", False),
@@ -65,7 +70,7 @@ def check_pack_int8():
     both the barrier ring and the K-streamed overlap (int32 partial
     sums are associative, so the chunk order cannot matter)."""
     rng = np.random.default_rng(1)
-    mesh = compat_make_mesh((1, 8), ("data", "model"))
+    mesh = _mesh((1, 8))
     ai = jnp.asarray(rng.integers(-128, 128, size=(16, 96)), jnp.int8)
     bi = jnp.asarray(rng.integers(-128, 128, size=(96, 24)), jnp.int8)
     want = np.asarray(ref.ref_gemm(ai, bi, out_dtype=jnp.int8,
@@ -85,7 +90,7 @@ def check_overlap_invariance():
     tight tolerance.  Also: the staged A entering shard_map is the
     q-free (d, p, Md, cyc*kb) tensor, never a Q-fold replica."""
     rng = np.random.default_rng(5)
-    mesh = compat_make_mesh((1, 8), ("data", "model"))
+    mesh = _mesh((1, 8))
 
     # int8: every (stagger, overlap) schedule is bit-identical.
     ai = jnp.asarray(rng.integers(-128, 128, size=(13, 100)), jnp.int8)
@@ -119,7 +124,7 @@ def check_overlap_invariance():
 def check_array_level():
     """array_gemm: M sharded over data, packs over model; edge shapes."""
     rng = np.random.default_rng(2)
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = _mesh((2, 4))
     for (m, k, n) in [(16, 32, 24), (13, 100, 27)]:
         a = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
         b = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
@@ -139,7 +144,7 @@ def check_ops_dispatch():
     """ops.matmul routes through the pack above the context threshold
     and stays single-kernel below it / without a context."""
     rng = np.random.default_rng(3)
-    mesh = compat_make_mesh((1, 8), ("data", "model"))
+    mesh = _mesh((1, 8))
     a = jnp.asarray(rng.normal(size=(32, 64)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(64, 48)), jnp.float32)
     want = np.asarray(ref.ref_gemm(a, b))
@@ -164,7 +169,7 @@ def check_overlap_resolution():
     from repro.tuning import dispatch
     from repro.tuning.cache import cache_key
 
-    mesh = compat_make_mesh((1, 8), ("data", "model"))
+    mesh = _mesh((1, 8))
     rng = np.random.default_rng(6)
     a = jnp.asarray(rng.normal(size=(16, 64)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(64, 24)), jnp.float32)
@@ -204,7 +209,7 @@ def check_engine_pack():
                       n_kv_heads=2, d_head=16, d_ff=128, vocab_size=256,
                       compute_dtype="float32", cache_dtype="float32")
     params = init_params(jax.random.PRNGKey(0), cfg)
-    mesh = compat_make_mesh((1, 8), ("data", "model"))
+    mesh = _mesh((1, 8))
     rng = np.random.default_rng(4)
     prompts = rng.integers(0, 256, size=(2, 16)).astype(np.int32)
     batch = {"tokens": jnp.asarray(prompts)}

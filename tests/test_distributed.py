@@ -7,11 +7,9 @@ import subprocess
 import sys
 
 import jax
-from repro.launch.mesh import compat_make_mesh
 import jax.numpy as jnp
 import pytest
-
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def test_multidevice_suite():
@@ -32,7 +30,8 @@ def test_multidevice_suite():
 class TestPolicyUnits:
     def _policy(self):
         from repro.distributed.sharding import ShardingPolicy
-        mesh = compat_make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         return ShardingPolicy(mesh=mesh, data_axes=("data",))
 
     def test_param_spec_rules(self):
@@ -52,7 +51,8 @@ class TestPolicyUnits:
 
     def test_sanitize_indivisible(self):
         from repro.distributed.sharding import ShardingPolicy
-        mesh = compat_make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         pol = ShardingPolicy(mesh=mesh, data_axes=("data",))
         # mesh axes are size 1 -> everything divides; simulate via spec
         spec = pol._sanitize(P("model", None), (7, 3))

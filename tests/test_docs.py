@@ -28,6 +28,11 @@ DOCTEST_MODULES = [
     "repro.serving.scheduler",
     "repro.serving.engine",
     "repro.serving.kvpool",
+    "repro.core.hw",
+    "repro.obs.efficiency",
+    "repro.obs.profile",
+    "repro.kernels.decode_attention",
+    "repro.launch.compile_cache",
 ]
 
 

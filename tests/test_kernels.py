@@ -245,6 +245,41 @@ def test_paged_decode_double_buffer_bit_identical(quantized):
     np.testing.assert_array_equal(np.asarray(one), np.asarray(two))
 
 
+@pytest.mark.parametrize("buffers", [1, 2])
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_paged_decode_lane_padded_rows(quantized, buffers):
+    """Serving pools store head-dim-64 rows zero-padded to 128 lanes
+    (``page_row_dim``) so the explicit-DMA gather compiles for the chip.
+    A padded pool must decode to the unpadded pool's result — exactly
+    on the oracle, to float tolerance on the kernel."""
+    from repro.kernels.decode_attention import page_row_dim
+    b, hq, hkv, d, ps, n_pool = 3, 8, 2, 64, 16, 24
+    lengths = np.asarray([3 * ps + 5, ps, 1])
+    q = randf((b, hq, d))
+    kp, vp = randf((n_pool + 1, hkv, ps, d)), randf((n_pool + 1, hkv, ps, d))
+    scales = {}
+    if quantized:
+        (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
+        scales = {"k_scale": ks, "v_scale": vs}
+    rd = page_row_dim(d)
+    assert rd == 128
+    kw, vw = ops.to_page_rows(kp, rd), ops.to_page_rows(vp, rd)
+    bt = _rand_block_tables(b, 4, n_pool, lengths, ps, seed=5)
+    ln = jnp.asarray(lengths, jnp.int32)
+    narrow = ops.decode_paged(q, kp, vp, block_tables=bt, length=ln,
+                              buffers=buffers, mode="kernel", **scales)
+    wide = ops.decode_paged(q, kw, vw, block_tables=bt, length=ln,
+                            buffers=buffers, mode="kernel", **scales)
+    assert wide.shape == (b, hq, d)
+    np.testing.assert_allclose(np.asarray(wide), np.asarray(narrow),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(
+        np.asarray(ref.ref_paged_decode_attention(q, kw, vw, bt, length=ln,
+                                                  **scales)),
+        np.asarray(ref.ref_paged_decode_attention(q, kp, vp, bt, length=ln,
+                                                  **scales)))
+
+
 def test_paged_decode_scale_validation():
     """int8 pools without scale rows would be silently wrong (raw
     quantized integers attended as values); float pools with scale rows

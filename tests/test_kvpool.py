@@ -321,25 +321,63 @@ def _staggered_trace(n=6, plen=12, seed=1):
 
 
 def test_int8_kv_greedy_outputs_unchanged():
-    """Integration: a staggered 6-request trace decodes to the SAME
-    greedy tokens under int8 KV pages as under the dense f32 engine.
+    """Integration: a staggered 6-request trace decodes under int8 KV
+    pages to the dense f32 engine's greedy tokens wherever the choice
+    is not a near-tie, and its logits stay within the int8 noise bound.
 
-    Greedy argmax only survives quantization when the top-2 logit gap
-    exceeds the ~1% quantization noise; the params/prompt seeds here
-    are pinned to a combination verified to decode identically (the
-    pinned params' top-2 logit gaps comfortably exceed the noise), so
-    this is a stable regression test of the quantized pipeline, not a
-    coin flip on near-ties."""
+    Every logits row the two engines compute from the same inputs (the
+    prompt's last position, then each decode step until their greedy
+    tokens part) must agree to 0.1 logit standard deviations: per-row
+    symmetric int8 moves a KV row by at most scale/2 = max|row|/254,
+    which perturbs a logit by ~1% of the logits' spread (4.2% measured
+    at worst here), while a wrong scale, page or row is an O(1) error.
+    Greedy argmax survives that perturbation only when the dense top-2
+    gap exceeds twice the bound, so token equality is asserted on
+    exactly those rows.  (These seeds contain one near-tie: trace id 5,
+    step 2, top-2 gap 6e-4 against a 4e-3 perturbation.)"""
     params = init_params(jax.random.PRNGKey(5), CFG)
     trace = _staggered_trace(seed=0)
-    dense, _ = _run_trace_outputs(CFG, params, trace, kv="dense")
-    paged, eng = _run_trace_outputs(CFG, params, trace, kv="paged",
-                                    page_size=16, kv_dtype="int8")
-    assert eng.kv_mode == "paged"
-    for tid in dense:
-        np.testing.assert_array_equal(
-            dense[tid], paged[tid],
-            err_msg=f"trace id {tid} diverged under int8 KV pages")
+    dense, dense_rows = _run_trace_logits(CFG, params, trace, kv="dense")
+    paged, paged_rows = _run_trace_logits(CFG, params, trace, kv="paged",
+                                          page_size=16, kv_dtype="int8")
+    compared = decided = 0
+    for t in trace:
+        tid, plen = t["id"], len(t["prompt"])
+        a, b = dense[tid], paged[tid]
+        split = int(np.argmax(a != b)) if (a != b).any() else len(a)
+        ref = dense_rows[tid]
+        for pos, row in sorted(paged_rows[tid].items()):
+            if pos > plen - 1 + split:
+                break           # inputs differ past the first parted token
+            bound = 0.1 * float(ref[pos].std())
+            err = float(np.abs(row - ref[pos]).max())
+            assert err <= bound, (tid, pos, err, bound)
+            compared += 1
+            top2 = np.sort(ref[pos])[-2:]
+            if top2[1] - top2[0] > 2 * bound:
+                decided += 1
+                assert int(np.argmax(row)) == int(np.argmax(ref[pos])), \
+                    f"trace id {tid} diverged at a decided step (pos {pos})"
+    # The bound is doing real work: most rows are compared and decided.
+    assert compared >= sum(t["max_new"] for t in trace) // 2
+    assert decided >= compared // 2
+
+
+def _run_trace_logits(cfg, params, trace, **scfg_kw):
+    """Like :func:`_run_trace_outputs`, plus every logits row the engine
+    sampled from: rows[trace id][pos] (rid == trace id on a fresh
+    engine replaying a step-indexed trace in order)."""
+    from repro.launch.serve import run_trace
+    eng = ServeEngine(cfg, params, ServeConfig(batch_slots=3, max_len=64,
+                                               **scfg_kw))
+    rows: dict = {}
+    eng.logits_tap = lambda rid, pos, row: rows.setdefault(rid, {}).update(
+        {pos: np.asarray(row)})
+    try:
+        rep = run_trace(eng, trace, log=None)
+    finally:
+        eng.close()
+    return rep["results"], rows
 
 
 def test_int8_kv_halves_kv_high_water():

@@ -301,10 +301,10 @@ def test_efficiency_sanity_on_smoke_gemm():
     t0 = time.perf_counter()
     np.asarray(a @ b)
     us = (time.perf_counter() - t0) * 1e6
-    eff = gemm_efficiency(64, 64, 64, us, "float32", backend="cpu")
+    eff = gemm_efficiency(64, 64, 64, us, "float32", device_kind="TPU v5 lite")
     assert 0.0 < eff <= 1.0
-    assert peak_flops("int8", backend="cpu") > peak_flops(
-        "bfloat16", backend="cpu")
+    assert peak_flops("int8", device_kind="TPU v5 lite") > peak_flops(
+        "bfloat16", device_kind="TPU v5 lite")
     with pytest.raises(ValueError):
         gemm_efficiency(8, 8, 8, 0.0)
 
@@ -321,7 +321,7 @@ def test_serve_efficiency_uses_model_flops():
                  + 2 * cfg.d_model * cfg.d_ff + cfg.d_ff * cfg.d_model)
     assert f == 2.0 * (cfg.n_layers * per_layer
                        + cfg.d_model * cfg.vocab_size)
-    eff = serve_efficiency(cfg, tok_s=100.0, backend="cpu")
+    eff = serve_efficiency(cfg, tok_s=100.0, device_kind="TPU v5 lite")
     assert 0.0 < eff <= 1.0
 
 

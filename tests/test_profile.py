@@ -122,7 +122,7 @@ def test_metadata_and_flow_noop_when_disabled_and_validator_rules():
 
 
 def test_record_step_decomposition_sums_to_wall():
-    prof = StepProfiler(Registry(), backend="cpu")
+    prof = StepProfiler(Registry(), device_kind="TPU v5 lite")
     r = prof.record_step(10.0, {"admit": 1.0, "prefill": 2.0,
                                 "decode": 4.0})
     assert r["device_ms"] + r["bubble_ms"] == r["wall_ms"] == 10.0
@@ -138,21 +138,22 @@ def test_record_step_decomposition_sums_to_wall():
 
 
 def test_stall_classification_agrees_with_analytic_roofline():
-    ridge = ridge_intensity("bfloat16", backend="cpu")
-    bw = peak_bandwidth("cpu")
+    v5e = "TPU v5 lite"
+    ridge = ridge_intensity("bfloat16", device_kind=v5e)
+    bw = peak_bandwidth(v5e)
     nbytes = 1e6
     hi = classify_kernel("gemm", flops=nbytes * ridge * 4.0,
-                         nbytes=nbytes, measured_us=100.0, backend="cpu")
+                         nbytes=nbytes, measured_us=100.0, device_kind=v5e)
     assert hi.stall_class == COMPUTE_BOUND
     assert hi.bound_us == pytest.approx(
         hi.flops / (ridge * bw) * 1e6)   # peak_flops = ridge * bw
     lo = classify_kernel("scatter", flops=nbytes * ridge * 0.25,
-                         nbytes=nbytes, measured_us=100.0, backend="cpu")
+                         nbytes=nbytes, measured_us=100.0, device_kind=v5e)
     assert lo.stall_class == MEMORY_BOUND
     assert lo.bound_us == pytest.approx(nbytes / bw * 1e6)
     # At the ridge point exactly, the two bounds coincide: compute.
     at = classify_kernel("ridge", flops=nbytes * ridge, nbytes=nbytes,
-                         measured_us=100.0, backend="cpu")
+                         measured_us=100.0, device_kind=v5e)
     assert at.stall_class == COMPUTE_BOUND
     for p in (hi, lo, at):
         assert 0.0 < p.bound_ratio <= 1.0
@@ -162,7 +163,7 @@ def test_stall_classification_agrees_with_analytic_roofline():
 
 def test_profiler_kernel_table_exports_gauges():
     reg = Registry()
-    prof = StepProfiler(reg, backend="cpu")
+    prof = StepProfiler(reg, device_kind="TPU v5 lite")
     prof.record_kernel("flash_decode", flops=1e3, nbytes=1e9,
                        measured_us=100.0)
     prof.record_kernel("matmul", flops=1e12, nbytes=1e3,

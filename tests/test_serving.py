@@ -604,3 +604,65 @@ def test_latency_policy_engine_run_completes_bit_identical():
     assert stats["finished"] == len(_CHUNK_LENGTHS)
     for L, w, g in zip(_CHUNK_LENGTHS, want, got):
         np.testing.assert_array_equal(w, g, err_msg=f"prompt_len={L}")
+
+
+def test_launcher_smoke_switch_selects_preset_or_full():
+    """``--smoke`` is a real switch: the default serves the preset,
+    ``--no-smoke`` the published widths, and the default arch is one
+    that fits a single 16 GB chip."""
+    from repro.launch.serve import build_parser
+    ap = build_parser()
+    assert ap.parse_args([]).smoke is True
+    assert ap.parse_args([]).arch == "smollm_360m"
+    args = ap.parse_args(["--arch", "smollm_360m", "--no-smoke"])
+    assert args.smoke is False
+    assert ap.parse_args(["--smoke"]).smoke is True
+
+
+def test_launcher_platform_request_fails_off_that_platform(capsys):
+    """Asked for the chip on a host without one, the launcher exits
+    before building anything — it never carries on on the CPU."""
+    import jax
+    from repro.launch.serve import main
+    other = "cpu" if jax.default_backend() == "tpu" else "tpu"
+    with pytest.raises(SystemExit, match=f"--platform {other}"):
+        main(["--platform", other])
+    assert "[serve] device platform=" in capsys.readouterr().out
+
+
+def test_launcher_verify_holds_logits_to_a_bound(capsys):
+    """``--verify`` taps both runs' logits rows: the continuous run
+    passes against its one-shot reference; a row moved by a fraction of
+    a logit standard deviation, or a token that parts from the
+    reference where the reference has no near-tie, fails it."""
+    from repro.launch.serve import VERIFY_TOL, _verify, run_trace
+    prompts = _prompts(RAGGED)
+    trace = [{"id": i, "arrival": i, "max_new": 6, "prompt": prompts[L]}
+             for i, L in enumerate(RAGGED)]
+    scfg = ServeConfig(batch_slots=3, max_len=64, kv="paged", page_size=8)
+    eng = ServeEngine(CFG, PARAMS, scfg)
+    taps: dict = {}
+    eng.logits_tap = lambda rid, pos, row: taps.setdefault(rid, {}).update(
+        {pos: np.asarray(row)})
+    try:
+        rep = run_trace(eng, trace, log=None)
+    finally:
+        eng.close()
+    rows = {tid: taps[rid] for tid, rid in rep["rids"].items()}
+    results = rep["results"]
+    _verify(CFG, PARAMS, trace, results, scfg, rows)
+    assert "verify OK: 3 requests" in capsys.readouterr().out
+
+    pos = len(prompts[RAGGED[1]]) - 1 + 2        # request 1, step 2
+    moved = dict(rows[1])
+    row = moved[pos].copy()
+    row[0] += 3 * VERIFY_TOL * np.std(row)
+    moved[pos] = row
+    with pytest.raises(SystemExit, match=r"id=1 max\|d\|"):
+        _verify(CFG, PARAMS, trace, results, scfg, {**rows, 1: moved})
+
+    flipped = {**results, 2: results[2].copy()}
+    r = rows[2][len(prompts[RAGGED[2]]) - 1 + 5]
+    flipped[2][5] = int(np.argmin(r))            # far from any tie
+    with pytest.raises(SystemExit, match="id=2 parted at step 5"):
+        _verify(CFG, PARAMS, trace, flipped, scfg, rows)

@@ -125,8 +125,8 @@ def roofline(snap: Dict[str, Any]) -> List[str]:
     out += _table(["kernel", "stall class", "achieved/bound"], rows)
     eff = gauges.get("serve.efficiency", {})
     if isinstance(eff, dict) and "value" in eff:
-        out += ["", f"serve efficiency: {_fmt(eff['value'])} of "
-                    f"analytic peak"]
+        out += ["", f"serve efficiency: {_fmt(eff['value'])} of the "
+                    f"chip's published peak"]
     return out + [""]
 
 
