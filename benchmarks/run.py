@@ -446,25 +446,11 @@ def bench_serve_trace() -> None:
         run_trace(engine, trace, log=None)          # compile warmup
         rep = run_trace(engine, trace, log=None)
         kv_kib = engine.kv_bytes_reserved() / 1024
-        # Step-time attribution for the warm replay: bubble is the share
-        # of step wall time outside the device-synced section probes;
-        # stall is the worst hot kernel's roofline class.  The class is
-        # deterministic (analytic shapes vs hw peaks); the fraction is
-        # timing-derived, so its --metrics gate gets a wide tolerance.
-        ktab = engine.profiler.kernel_table()
-        stall = (f"{ktab[0].name}:{ktab[0].stall_class or 'not_measured'}"
-                 if ktab else "n/a")
         emit("serve.continuous.s4", rep["wall_s"] * 1e6 / rep["tokens"],
              f"tok_s={rep['tok_s']:.1f} p50={rep['p50_ms']:.2f}ms "
              f"p99={rep['p99_ms']:.2f}ms shared_steps={rep['shared_steps']} "
              f"decode_steps={rep['decode_steps']} kv_kib={kv_kib:.0f} "
-             f"bubble={rep['bubble_fraction']:.2f} stall={stall} "
              f"{_serve_eff(cfg, rep['tok_s'])}")
-        emit_gauge("serve.bubble_fraction", rep["bubble_fraction"])
-        if ktab and ktab[0].stall_class is not None:
-            # No gauge where no device peak places the kernel (the CPU).
-            emit_gauge("serve.stall.memory_bound",
-                       1.0 if ktab[0].stall_class == "memory" else 0.0)
         # Serialized baseline: same engine, same requests, grouped into
         # uniform one-shot batches (arrivals ignored — the baseline gets
         # every benefit of the doubt); each batch decodes to its longest
@@ -557,7 +543,6 @@ def bench_serve_trace() -> None:
              f"p99={crep['p99_ms']:.2f}ms chunk=16 "
              f"budget={slots + 16} chunks={crep['prefill_chunks']} "
              f"mono_p99={prep['p99_ms']:.2f}ms "
-             f"bubble={crep['bubble_fraction']:.2f} "
              f"{_serve_eff(cfg, crep['tok_s'])}")
     finally:
         chunked.close()

@@ -377,51 +377,3 @@ def wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
     out = _on_each_device(functools.partial(
         wkv6, chunk=chunk, interpret=_interpret()))(r2, k2, v2, w2, u)
     return out[:, :, :t]
-
-
-def op_cost_model(op: str, *, m: int = 0, k: int = 0, n: int = 0,
-                  batch: int = 0, heads: int = 0, kv_heads: int = 0,
-                  seq: int = 0, d_head: int = 0,
-                  dtype_bytes: float = 2.0,
-                  kv_bytes: float = 2.0,
-                  weight_flops: float = 0.0,
-                  weight_bytes: float = 0.0,
-                  chunk_tokens: int = 0,
-                  layers: int = 1) -> tuple[float, float]:
-    """Analytic (flops, bytes_moved) for the hot ops' roofline placement.
-
-    Compiled ``cost_analysis()`` is the preferred source (the profiler
-    asks it first), but interpret-mode Pallas calls and older jax
-    versions report nothing useful — this closed-form model is the
-    deterministic fallback, counting the dominant terms only:
-
-    * ``matmul``: 2mkn FLOPs; A + B + C once each through the memory
-      system;
-    * ``flash_decode`` / ``flash_paged_decode``: one query token per
-      lane — 4·B·H·T·d FLOPs (QK^T + PV), traffic dominated by the KV
-      read (T rows per kv head) plus the per-step weight stream
-      (``weight_flops``/``weight_bytes``, from
-      ``efficiency.model_flops_per_token``-style accounting, since the
-      engine's decode step runs the whole model);
-    * ``prefill_chunk``: the chunk forward (``weight_flops``/
-      ``weight_bytes``, caller-scaled to the chunk's tokens) plus the
-      chunk's KV page scatter — read scratch + write pool, zero MACs,
-      which is what drags short chunks memory-bound and is exactly why
-      the engine overlaps the scatter with the next chunk's compute.
-    """
-    if op == "matmul":
-        flops = 2.0 * m * k * n
-        nbytes = (m * k + k * n) * dtype_bytes + m * n * dtype_bytes
-        return flops, nbytes
-    if op in ("flash_decode", "flash_paged_decode"):
-        kvh = kv_heads or heads
-        flops = layers * 4.0 * batch * heads * seq * d_head + weight_flops
-        kv_read = layers * 2.0 * batch * kvh * seq * d_head * kv_bytes
-        io = layers * 2.0 * batch * heads * d_head * dtype_bytes  # q/o
-        return flops, kv_read + io + weight_bytes
-    if op == "prefill_chunk":
-        kvh = kv_heads or heads
-        moved = (layers * 2.0 * 2.0 * chunk_tokens
-                 * kvh * d_head * kv_bytes)
-        return weight_flops, weight_bytes + moved
-    raise ValueError(f"op_cost_model: unknown op {op!r}")

@@ -357,8 +357,6 @@ def run_trace(engine, trace: List[dict],
     phit_base = engine.stats["prefix_hit_tokens"]
     ptot_base = engine.stats["prefix_prompt_tokens"]
     cow_base = engine.stats["cow_copies"]
-    bubble_base = engine.profiler.bubble_ms_total
-    pwall_base = engine.profiler.wall_ms_total
     slo_base = engine.slo.breaches()
     t0 = time.monotonic()
     while pending or not engine.sched.done():
@@ -423,12 +421,6 @@ def run_trace(engine, trace: List[dict],
         "kv_bytes_hwm": engine.kv_bytes_high_water(),
         "kv_bytes_reserved": engine.kv_bytes_reserved(),
     }
-    # Step-time attribution, as per-replay deltas (the profiler's
-    # totals are lifetime-cumulative and benches replay warm engines).
-    pwall = engine.profiler.wall_ms_total - pwall_base
-    pbubble = engine.profiler.bubble_ms_total - bubble_base
-    rep["bubble_ms_total"] = pbubble
-    rep["bubble_fraction"] = pbubble / pwall if pwall > 0 else 0.0
     rep["slo_breaches"] = engine.slo.breaches() - slo_base
     if paged:
         rep["pages_hwm"] = engine.pool.high_water
@@ -493,9 +485,11 @@ def build_parser() -> argparse.ArgumentParser:
                          "continue — how benchmarks/traces/*.jsonl are "
                          "(re)generated")
     ap.add_argument("--trace-out", type=str, default=None,
-                    help="write a Chrome-trace-event JSON of the run "
-                         "(open in chrome://tracing or ui.perfetto.dev); "
-                         "enables span recording for this run")
+                    help="write a Chrome-trace-event JSON of the run's "
+                         "host spans and per-request async spans, on the "
+                         "host clock (open in chrome://tracing or "
+                         "ui.perfetto.dev); device-aligned spans come "
+                         "from a jax.profiler trace instead")
     ap.add_argument("--metrics-out", type=str, default=None,
                     help="write the schema-1 metrics snapshot JSON "
                          "(TTFT/inter-token histograms, kvpool gauges, "
@@ -664,7 +658,6 @@ def main(argv: Optional[List[str]] = None) -> None:
             run_trace(engine, trace, log=None)
             engine.drain()
             bundle.registry.reset_values()
-            engine.profiler.reset_totals()
         if args.verify:
             engine.logits_tap = lambda rid, pos, row: served_rows.setdefault(
                 rid, {}).__setitem__(pos, row)
@@ -703,14 +696,6 @@ def main(argv: Optional[List[str]] = None) -> None:
                 "achieved decode throughput / published peak").set(eff)
             print(f"[serve] efficiency={eff:.3e} of the published peak "
                   f"({obs.efficiency.device_kind()}, incl. compile)")
-        # Step-time attribution: the run's device/bubble split and the
-        # per-kernel roofline stall table (worst bound_ratio first).
-        stall = " ".join(
-            f"{k.name}:{k.stall_class}({k.bound_ratio:.1e})"
-            if k.stall_class is not None else f"{k.name}:not-measured"
-            for k in engine.profiler.kernel_table()) or "n/a"
-        print(f"[serve] attribution: bubble={rep['bubble_fraction']:.3f} "
-              f"(bubble_ms={rep['bubble_ms_total']:.1f}) stall={stall}")
         if (args.slo_ttft_ms is not None or args.slo_itl_ms is not None
                 or rep["slo_breaches"]):
             s = engine.slo.summary()
@@ -760,8 +745,7 @@ def main(argv: Optional[List[str]] = None) -> None:
                 extra={"run": run_section},
                 required_histograms=("serve.ttft_ms",
                                      "serve.inter_token_ms"),
-                required_gauges=("kvpool.pages_in_use", "serve.kv_tokens",
-                                 "serve.bubble_fraction"))
+                required_gauges=("kvpool.pages_in_use", "serve.kv_tokens"))
             print(f"[serve] wrote metrics snapshot -> {args.metrics_out}")
         if args.prom_out:
             obs.write_prometheus(args.prom_out, bundle.registry)

@@ -2,8 +2,9 @@
 
 One process-wide :class:`Obs` bundle pairs a :class:`~repro.obs.metrics.
 Registry` (always on — instruments are allocation-light) with a
-:class:`~repro.obs.trace.Tracer` (off by default — spans cost a clock
-read each, so tracing is opt-in via ``configure`` or ``--trace-out``).
+:class:`~repro.obs.trace.Tracer` (its Chrome sink is off by default and
+opt-in via ``configure`` or ``--trace-out``; its spans reach an active
+``jax.profiler`` session either way).
 
 Call sites grab handles through :func:`get_obs` or the :func:`count`
 convenience; entry points that own a run (``launch/serve.py``, the
@@ -22,9 +23,6 @@ from repro.obs.export import (SNAPSHOT_SCHEMA, flatten_snapshot,
                               validate_snapshot, write_metrics,
                               write_prometheus)
 from repro.obs import efficiency
-from repro.obs.profile import (KernelProfile, StepProfiler, classify_kernel,
-                               extract_costs, peak_bandwidth,
-                               ridge_intensity)
 from repro.obs.slo import SLOMonitor, window_percentile
 from repro.obs.flight import FlightRecorder
 
@@ -33,8 +31,7 @@ __all__ = [
     "SNAPSHOT_SCHEMA", "flatten_snapshot", "validate_snapshot",
     "validate_chrome_trace", "write_metrics", "write_prometheus",
     "efficiency", "Obs", "get_obs", "configure", "reset", "count",
-    "KernelProfile", "StepProfiler", "classify_kernel", "extract_costs",
-    "peak_bandwidth", "ridge_intensity", "SLOMonitor", "window_percentile",
+    "SLOMonitor", "window_percentile",
     "FlightRecorder",
 ]
 

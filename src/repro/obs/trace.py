@@ -1,22 +1,26 @@
-"""Span tracer with a Chrome-trace-event (Perfetto-loadable) exporter.
+"""Span tracer: engine phases on the profiler's clock, and an opt-in
+Chrome-trace-event (Perfetto-loadable) exporter.
 
-Three event families, matching the Trace Event Format that
-``chrome://tracing`` and https://ui.perfetto.dev consume directly:
+Sync spans (:meth:`Tracer.span`) are nested host-side phases inside one
+engine step (``engine.step`` > ``engine.admit`` > ...).  Each one also
+enters a ``jax.profiler.TraceAnnotation`` while a profiler session
+records host events, so the phase lands on the host plane of the
+session's ``.xplane.pb`` on the same clock as the device's ``XLA Ops``;
+``step_num=`` makes it a ``StepTraceAnnotation``.  With no session and
+the Chrome sink off, a span costs one check and returns one shared
+null context manager: no event object, no clock read.
 
-* **sync spans** (``ph: "X"`` complete events) — nested host-side
-  phases inside one engine step (``engine.step`` > ``admit`` >
-  ``prefill`` > ``decode``).  Opened with :meth:`Tracer.span`, which
-  enforces LIFO nesting by construction (it is a context manager).
+The Chrome sink (``Tracer(enabled=True)``, ``--trace-out``) records, on
+the host clock only, three event families of the Trace Event Format
+that ``chrome://tracing`` and https://ui.perfetto.dev consume:
+
+* **sync spans** (``ph: "X"`` complete events), LIFO-nested by
+  construction (a span is a context manager);
 * **async spans** (``ph: "b"``/``"e"`` pairs keyed by ``(cat, id)``) —
-  per-request lifecycle phases (``queued`` → ``prefill`` → ``decode``)
-  that overlap arbitrarily across requests and engine steps.
-* **instants and counters** (``ph: "i"`` / ``"C"``) — point events
-  (preemption, EOS) and time series (pages in use over the trace).
-
-``Tracer(enabled=False)`` — the process default — is a zero-cost no-op:
-``span()`` returns one shared null context manager and every other
-method returns immediately, so uninstrumented serving pays a single
-attribute check per call site.
+  per-request lifecycle phases (``queued`` → ``decode``) that overlap
+  arbitrarily across requests and engine steps; Chrome-only;
+* **instants, counters and flows** (``ph: "i"`` / ``"C"`` / ``"s"``…);
+  Chrome-only.
 
 >>> tr = Tracer()
 >>> with tr.span("step", step=0):
@@ -27,6 +31,8 @@ attribute check per call site.
 >>> evs = tr.chrome_trace()["traceEvents"]
 >>> sorted({e["ph"] for e in evs})
 ['X', 'b', 'e']
+>>> Tracer(enabled=False).span("step") is _NULL_SPAN   # no session open
+True
 """
 
 from __future__ import annotations
@@ -51,23 +57,50 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """One open sync span; records an ``X`` event when it closes."""
+def _profiling() -> bool:
+    """True while a profiler session records host events.  The first
+    call imports jax and rebinds this name to jax's own check, so later
+    calls cost one C call."""
+    global _profiling
+    from jax.profiler import TraceAnnotation
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
 
-    __slots__ = ("tracer", "name", "cat", "args", "t0")
+
+def _annotation(name: str, step_num: Optional[int],
+                args: Dict[str, Any]):
+    """The profiler's host event for one span."""
+    from jax.profiler import StepTraceAnnotation, TraceAnnotation
+    if step_num is None:
+        return TraceAnnotation(name, **args)
+    return StepTraceAnnotation(name, step_num=step_num, **args)
+
+
+class _Span:
+    """One open sync span; records an ``X`` event when it closes (and
+    a profiler host event, while a session records)."""
+
+    __slots__ = ("tracer", "name", "cat", "args", "step_num", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Dict[str, Any]):
+                 args: Dict[str, Any], step_num: Optional[int]):
         self.tracer, self.name, self.cat, self.args = tracer, name, cat, args
+        self.step_num = step_num
         self.t0 = 0.0
+        self.ann = None
 
     def __enter__(self) -> "_Span":
+        if _profiling():
+            self.ann = _annotation(self.name, self.step_num, self.args)
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         self.tracer._stack.append(self)
         return self
 
     def __exit__(self, *exc) -> None:
         now = time.perf_counter()
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         top = self.tracer._stack.pop()
         assert top is self, (
             f"span {self.name!r} closed while {top.name!r} is open — "
@@ -79,8 +112,11 @@ class _Span:
         }
         if self.cat:
             ev["cat"] = self.cat
-        if self.args:
-            ev["args"] = self.args
+        args = self.args
+        if self.step_num is not None:
+            args = {**args, "step_num": self.step_num}
+        if args:
+            ev["args"] = args
         self.tracer._events.append(ev)
 
 
@@ -102,11 +138,18 @@ class Tracer:
 
     # -- recording ----------------------------------------------------------
 
-    def span(self, name: str, cat: str = "", **args):
-        """Context manager timing one nested host-side phase."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, args)
+    def span(self, name: str, cat: str = "", *,
+             step_num: Optional[int] = None, **args):
+        """Context manager around one nested host-side phase: a Chrome
+        ``X`` event when this tracer is enabled, a profiler host event
+        (a step marker with ``step_num``) while a session records, and
+        nothing otherwise.  ``args`` ride along on both; keep them small
+        ints."""
+        if self.enabled:
+            return _Span(self, name, cat, args, step_num)
+        if _profiling():
+            return _annotation(name, step_num, args)
+        return _NULL_SPAN
 
     def instant(self, name: str, cat: str = "", **args) -> None:
         if not self.enabled:

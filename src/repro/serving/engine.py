@@ -70,8 +70,7 @@ from repro.kernels import ops as kops
 from repro.models import (decode_step, forward, init_cache,
                           init_paged_cache, paged_eligible, prefill)
 from repro.models.config import ModelConfig
-from repro.obs import FlightRecorder, SLOMonitor, StepProfiler, get_obs
-from repro.obs.efficiency import device_kind
+from repro.obs import FlightRecorder, SLOMonitor, get_obs
 from repro.serving.kvpool import (BlockTables, PagePool, PrefixCache,
                                   pages_for)
 from repro.serving.scheduler import (DECODE, PREFILLING, Request,
@@ -189,6 +188,14 @@ def prefill_buckets(max_len: int, lo: int = 8) -> List[int]:
         b *= 2
     out.append(max_len)
     return out
+
+
+def _first_token(logits: jax.Array, last: jax.Array) -> tuple:
+    """The greedy token at row ``last`` (traced) of a ``(1, S, V)``
+    logits block, and that row (what ``logits_tap`` observes)."""
+    row = jax.lax.dynamic_index_in_dim(logits[0], last, axis=0,
+                                       keepdims=False)
+    return jnp.argmax(row).astype(jnp.int32), row
 
 
 def _bucket_for(plen: int, max_len: int) -> int:
@@ -364,42 +371,53 @@ class ServeEngine:
             from repro.tuning import dispatch
             self.tuned_gemm_hits = dispatch.warm_gemm_shapes(
                 self._all_gemm_shapes(), cfg.cdtype)
-        self._prefill = jax.jit(
-            lambda p, b, c: prefill(p, b, cfg, c))
+        # -- observability (repro.obs) ------------------------------------
+        # The engine instruments against the process bundle: metrics are
+        # always on (allocation-light); spans reach an active profiler
+        # session, and the Chrome sink when the entry point enabled the
+        # tracer (--trace-out).
+        obs = get_obs()
+        self._obs = obs
+        # -- the engine's jitted programs (jit_engine_<name> on a trace) --
+        program = self._program
+        self._prefill = program(
+            "prefill", lambda p, b, c: prefill(p, b, cfg, c))
         # Full-logits prefill for per-slot admission: a ragged prompt is
         # padded to its bucket, so the next-token logits live at
         # position plen-1, not at the padded end.
-        self._prefill_full = jax.jit(
+        self._prefill_full = program(
+            "prefill_full",
             lambda p, b, c: forward(p, b, cfg, caches=c,
                                     cache_pos=jnp.zeros((), jnp.int32))[:2])
         # Chunk-offset prefill: the same full-logits forward, but the
         # KV write offset / RoPE base is the slot's prompt cursor
         # (traced, so one compiled program covers every cursor value).
-        self._prefill_chunk_fn = jax.jit(
+        self._prefill_chunk_fn = program(
+            "prefill_chunk",
             lambda p, b, c, pos: forward(p, b, cfg, caches=c,
                                          cache_pos=pos)[:2])
         if self.kv_mode == "paged":
-            self._decode = jax.jit(
+            self._decode = program(
+                "decode",
                 lambda p, t, pos, bt, c: decode_step(p, t, pos, cfg, c,
                                                      block_tables=bt))
-            self._insert = jax.jit(self._insert_slot_pages)
-            self._insert_chunk = jax.jit(self._insert_chunk_pages)
+            self._insert = program("insert", self._insert_slot_pages)
+            self._insert_chunk = program("insert_chunk",
+                                         self._insert_chunk_pages)
             # COW page copy: duplicate pool row src -> dst across every
             # layer's pools (page axis 1, after the layer-group dim).
-            self._copy_page = jax.jit(
+            self._copy_page = program(
+                "copy_page",
                 lambda c, src, dst: jax.tree.map(
                     lambda a: a.at[:, dst].set(a[:, src]), c))
         else:
-            self._decode = jax.jit(
-                lambda p, t, pos, c: decode_step(p, t, pos, cfg, c))
-            self._insert = jax.jit(self._insert_slot)
-        self._sample_slots = jax.jit(self._make_sampler())
-        # -- observability (repro.obs) ------------------------------------
-        # The engine instruments against the process bundle: metrics are
-        # always on (allocation-light), spans are live only when the
-        # entry point enabled the tracer (--trace-out).
-        obs = get_obs()
-        self._obs = obs
+            self._decode = program(
+                "decode", lambda p, t, pos, c: decode_step(p, t, pos, cfg, c))
+            self._insert = program("insert", self._insert_slot)
+        self._sample_slots = program("sample", self._make_sampler())
+        # A prompt's first token: greedy from the logits row at a traced
+        # position, so no program compiles per prompt length.
+        self._first_token = program("first_token", _first_token)
         self._h_ttft = obs.registry.histogram(
             "serve.ttft_ms", "runnable -> first token, per request")
         self._h_itl = obs.registry.histogram(
@@ -441,20 +459,7 @@ class ServeEngine:
         self._prefix_prompt_tokens = 0
         if self.pool is not None:
             self.pool.bind_metrics(obs.registry)
-        # -- attribution layer (PR 10): profiler + SLO + flight ------------
-        # Step-time decomposition (device estimate vs host bubble) and
-        # the per-kernel roofline table.
-        try:
-            dtype_name = jnp.dtype(getattr(cfg, "cdtype", "bfloat16")).name
-        except TypeError:
-            dtype_name = "bfloat16"
-        self._dtype_bytes = float(jnp.dtype(dtype_name).itemsize)
-        # Roofline rows are placed against the published peaks of the
-        # chip serving (an unknown chip fails here, not mid-serve); on
-        # the CPU they are recorded with the roofline not measured.
-        self.profiler = StepProfiler(obs.registry,
-                                     device_kind=device_kind(),
-                                     dtype_name=dtype_name)
+        # -- SLO monitor + flight recorder ---------------------------------
         # Rolling-window tail-latency monitor.  Targets default to off;
         # the launcher arms them (--slo-ttft-ms / --slo-itl-ms).
         self.slo = SLOMonitor(obs.registry, tracer=obs.tracer)
@@ -465,7 +470,6 @@ class ServeEngine:
             lambda series, q, target: self.flight.trip(
                 "slo_breach", series=series, window_ms=q,
                 target_ms=target))
-        self._kernel_costs: Dict[str, tuple] = {}  # op -> (flops, bytes)
         if obs.tracer.enabled:
             # Name the pid/tid lanes so Perfetto shows "engine" instead
             # of bare zeros (idempotent — duplicates are harmless).
@@ -792,6 +796,23 @@ class ServeEngine:
                                  token_idx).astype(jnp.int32)
         return sample
 
+    def _program(self, name: str, fn: Callable) -> Callable:
+        """``jax.jit(fn)`` as the program ``jit_engine_<name>``.  JAX
+        runs the Python body only when it traces (a jit cache miss), so
+        the body counts ``engine.retraces.<name>`` and opens an
+        ``engine.retrace`` span around the trace."""
+        retraces = self._obs.registry.counter(
+            f"engine.retraces.{name}",
+            f"times JAX traced jit_engine_{name} (a jit cache miss)")
+
+        def body(*args):
+            retraces.inc()
+            with self._obs.tracer.span("engine.retrace", cat="engine",
+                                       program=name):
+                return fn(*args)
+        body.__name__ = body.__qualname__ = f"engine_{name}"
+        return jax.jit(body)
+
     # -- continuous-batching API --------------------------------------------
 
     def submit(self, prompt: np.ndarray, max_new: int, *,
@@ -890,7 +911,8 @@ class ServeEngine:
                                   "cancelled": list(self._cancel_log),
                                   "ttft_ms": {}, "itl_ms": {}}
         self._cancel_log.clear()
-        with tr.span("engine.step", cat="engine", step=self.step_count):
+        with tr.span("engine.step", cat="engine",
+                     step_num=self.step_count):
             self._admission_pass(events, "arrival")
             admit_ms = (time.perf_counter() - t_step) * 1e3
             t_pf = time.perf_counter()
@@ -903,33 +925,32 @@ class ServeEngine:
             active = self.sched.active_slots()
             decode_ms = 0.0
             if active:
-                pos = np.zeros((self.scfg.batch_slots,), np.int32)
-                pos_cap = (self._fresh_len if self.kv_mode == "paged"
-                           else self.scfg.max_len) - 1
-                for s in self.sched.slots:
-                    # Inactive slots decode garbage into their own dead
-                    # rows (dense: replaced wholesale on re-admission;
-                    # paged: the null sink page); the clamp only guards
-                    # the bound.
-                    pos[s.index] = min(s.length, pos_cap)
-                token_idx = np.zeros((self.scfg.batch_slots,), np.int32)
-                for s in active:
-                    token_idx[s.index] = s.generated
                 t_dec = time.perf_counter()
-                with tr.span("decode", cat="engine", batch=len(active)):
+                with tr.span("engine.decode.prepare", cat="engine",
+                             batch=len(active)):
+                    pos = np.zeros((self.scfg.batch_slots,), np.int32)
+                    pos_cap = (self._fresh_len if self.kv_mode == "paged"
+                               else self.scfg.max_len) - 1
+                    for s in self.sched.slots:
+                        # Inactive slots decode garbage into their own
+                        # dead rows (dense: replaced wholesale on
+                        # re-admission; paged: the null sink page); the
+                        # clamp only guards the bound.
+                        pos[s.index] = min(s.length, pos_cap)
+                    token_idx = np.zeros((self.scfg.batch_slots,), np.int32)
+                    for s in active:
+                        token_idx[s.index] = s.generated
+                    inputs = [jnp.asarray(self._tok), jnp.asarray(pos)]
                     if self.kv_mode == "paged":
-                        logits, self.caches = self._decode(
-                            self.params, jnp.asarray(self._tok),
-                            jnp.asarray(pos),
-                            jnp.asarray(self._decode_table()), self.caches)
-                    else:
-                        logits, self.caches = self._decode(
-                            self.params, jnp.asarray(self._tok),
-                            jnp.asarray(pos), self.caches)
-                    toks = np.asarray(
-                        self._sample_slots(logits, jnp.asarray(token_idx)))
+                        inputs.append(jnp.asarray(self._decode_table()))
+                    token_idx = jnp.asarray(token_idx)
+                with tr.span("engine.decode.dispatch", cat="engine"):
+                    logits, self.caches = self._decode(
+                        self.params, *inputs, self.caches)
+                    toks = self._sample_slots(logits, token_idx)
+                with tr.span("engine.decode.wait", cat="engine"):
+                    toks = np.asarray(toks)
                 decode_ms = (time.perf_counter() - t_dec) * 1e3
-                self._profile_decode(decode_ms, pos)
                 self.stats["decode_steps"] += 1
                 if events["admitted"] and holdover:
                     # A mid-stream admission shared this decode step
@@ -939,15 +960,16 @@ class ServeEngine:
                 # Peak resident KV this step: every active slot just
                 # wrote a row at position `length` (pre-increment).
                 self._note_kv_tokens(sum(s.length + 1 for s in active))
-                for s in active:
-                    if s.state != DECODE:
-                        continue    # cancelled mid-step by a callback
-                    if self.logits_tap is not None:
-                        self.logits_tap(s.rid, s.length, logits[s.index])
-                    s.length += 1
-                    self._tok[s.index] = toks[s.index]
-                    events["decoded"].append(s.rid)
-                    self._emit(s, int(toks[s.index]), events)
+                with tr.span("engine.emit", cat="engine"):
+                    for s in active:
+                        if s.state != DECODE:
+                            continue    # cancelled mid-step by a callback
+                        if self.logits_tap is not None:
+                            self.logits_tap(s.rid, s.length, logits[s.index])
+                        s.length += 1
+                        self._tok[s.index] = toks[s.index]
+                        events["decoded"].append(s.rid)
+                        self._emit(s, int(toks[s.index]), events)
             if self._cancel_log:
                 # Mid-step cancels: a stream callback fired during this
                 # decode's emit loop and called cancel().
@@ -973,20 +995,8 @@ class ServeEngine:
             "admit_ms": admit_ms, "prefill_ms": prefill_ms,
             "decode_ms": decode_ms, "step_ms": step_ms,
         }
-        # Attribution: the three phase probes are the device-attributed
-        # estimate (decode ends host-synced, admit syncs on first-token
-        # readback, chunked prefill pipelines behind decode); whatever
-        # wall time they don't cover is the host/dispatch bubble.
-        prof = self.profiler.record_step(
-            step_ms, {"admit": admit_ms, "prefill": prefill_ms,
-                      "decode": decode_ms})
-        events["profile"] = prof
-        tr.counter("step.attribution", bubble_ms=prof["bubble_ms"],
-                   device_ms=prof["device_ms"])
         self.flight.record_step(
             self.step_count - 1, wall_ms=round(step_ms, 3),
-            device_ms=round(prof["device_ms"], 3),
-            bubble_ms=round(prof["bubble_ms"], 3),
             admitted=len(events["admitted"]),
             decoded=len(events["decoded"]),
             finished=len(events["finished"]),
@@ -1013,7 +1023,8 @@ class ServeEngine:
         top of :meth:`step` and the post-reclaim pass after decode both
         funnel through here (``phase`` tags the trace span), so there
         is exactly one place admissions happen."""
-        with self._obs.tracer.span("admit", cat="engine", phase=phase):
+        with self._obs.tracer.span("engine.admit", cat="engine",
+                                   phase=phase):
             self._admit(events)
 
     def _admission_signals(self) -> Dict[str, Any]:
@@ -1040,69 +1051,6 @@ class ServeEngine:
         # can back off admissions while a breach is in progress.
         sig.update(self.slo.signals())
         return sig
-
-    # -- kernel roofline capture -------------------------------------------
-
-    def _profile_decode(self, decode_ms: float, pos: np.ndarray) -> None:
-        """Roofline-place the step's batched decode.  Costs come from
-        the compiled executable's ``cost_analysis()`` when the backend
-        reports them (captured once — the lowering is jit-cache-hot),
-        else the analytic :func:`~repro.kernels.ops.op_cost_model`;
-        the timing is this step's host-synced decode probe, so the
-        table tracks warm steady-state performance (last-wins)."""
-        op = ("flash_paged_decode" if self.kv_mode == "paged"
-              else "flash_decode")
-        costs = self._kernel_costs.get(op)
-        if costs is None:
-            from repro.obs.profile import extract_costs
-            # The executable is the one the step just ran (jit's compile
-            # cache hit); a failure here is a real compile failure and
-            # propagates.  Only missing cost figures fall back below.
-            if self.kv_mode == "paged":
-                lowered = self._decode.lower(
-                    self.params, jnp.asarray(self._tok), jnp.asarray(pos),
-                    jnp.asarray(self._decode_table()), self.caches)
-            else:
-                lowered = self._decode.lower(
-                    self.params, jnp.asarray(self._tok), jnp.asarray(pos),
-                    self.caches)
-            costs = extract_costs(lowered.compile())
-            if costs is None:
-                costs = self._analytic_decode_costs(op)
-            self._kernel_costs[op] = costs
-        if decode_ms > 0:
-            self.profiler.record_kernel(op, costs[0], costs[1],
-                                        measured_us=decode_ms * 1e3)
-
-    def _analytic_decode_costs(self, op: str) -> tuple:
-        from repro.kernels.ops import op_cost_model
-        from repro.obs.efficiency import model_flops_per_token
-        cfg = self.cfg
-        mfpt = model_flops_per_token(cfg)
-        return op_cost_model(
-            op, batch=self.scfg.batch_slots, heads=cfg.n_heads,
-            kv_heads=cfg.n_kv_heads, seq=self.scfg.max_len,
-            d_head=cfg.d_head, dtype_bytes=self._dtype_bytes,
-            kv_bytes=self._dtype_bytes, layers=cfg.n_layers,
-            weight_flops=mfpt * self.scfg.batch_slots,
-            weight_bytes=mfpt / 2.0 * self._dtype_bytes)
-
-    def _profile_prefill_chunk(self, take: int, chunk_ms: float) -> None:
-        """Roofline-place one prompt chunk (forward + page scatter)."""
-        if chunk_ms <= 0 or take <= 0:
-            return
-        from repro.kernels.ops import op_cost_model
-        from repro.obs.efficiency import model_flops_per_token
-        cfg = self.cfg
-        mfpt = model_flops_per_token(cfg)
-        flops, nbytes = op_cost_model(
-            "prefill_chunk", chunk_tokens=take, heads=cfg.n_heads,
-            kv_heads=cfg.n_kv_heads, d_head=cfg.d_head,
-            kv_bytes=self._dtype_bytes, layers=cfg.n_layers,
-            weight_flops=mfpt * take,
-            weight_bytes=mfpt / 2.0 * self._dtype_bytes)
-        self.profiler.record_kernel("prefill_chunk", flops, nbytes,
-                                    measured_us=chunk_ms * 1e3)
 
     def _admit(self, events: Dict[str, Any]) -> None:
         """Admission pass: free slots AND (paged) enough free pages for
@@ -1211,9 +1159,11 @@ class ServeEngine:
         for slot, req, tok0_dev in inflight:
             # First host sync of the pass: every later admission's
             # prefill + scatter is already in the device queue.
-            tok0 = int(np.asarray(tok0_dev))
+            with tr.span("engine.first_token", cat="engine", rid=req.rid):
+                tok0 = int(np.asarray(tok0_dev))
             self._tok[slot.index] = tok0
-            self._emit(slot, tok0, events)
+            with tr.span("engine.emit", cat="engine"):
+                self._emit(slot, tok0, events)
         self._note_kv_tokens(
             sum(s.length for s in self.sched.active_slots()))
 
@@ -1268,9 +1218,9 @@ class ServeEngine:
             buf = pages_for(take, ps) * ps
         else:
             buf = min(chunk, self._fresh_len - c0)
-        t_chunk = time.perf_counter()
-        with self._obs.tracer.span("prefill_chunk", cat="engine",
-                                   rid=req.rid, lo=c0, take=take):
+        tr = self._obs.tracer
+        with tr.span("engine.prefill_chunk", cat="engine", rid=req.rid,
+                     lo=c0, take=take):
             toks = np.zeros((1, buf), np.int32)
             toks[0, :take] = req.prompt[c0:c0 + take]
             batch: Dict[str, jax.Array] = {"tokens": jnp.asarray(toks)}
@@ -1298,8 +1248,6 @@ class ServeEngine:
                     jnp.asarray(ids), jnp.asarray(src))
             self.stats["prefill_chunks"] += 1
             self._c_chunks.inc()
-        self._profile_prefill_chunk(
-            take, (time.perf_counter() - t_chunk) * 1e3)
         slot.prefill_pos = c0 + take
         if slot.prefill_pos < plen:
             return
@@ -1315,14 +1263,18 @@ class ServeEngine:
             # while the scratch still holds their full-precision rows.
             self._cache_prefix(slot, req)
         self._scratch.pop(slot.index, None)
-        if self.logits_tap is not None:
-            self.logits_tap(req.rid, plen - 1, logits[0, take - 1])
-        tok0 = int(np.asarray(jnp.argmax(logits[0, take - 1])))
+        with tr.span("engine.first_token", cat="engine", rid=req.rid):
+            tok0, row = self._first_token(
+                logits, jnp.asarray(take - 1, jnp.int32))
+            if self.logits_tap is not None:
+                self.logits_tap(req.rid, plen - 1, row)
+            tok0 = int(np.asarray(tok0))
         slot.state = DECODE
         slot.length = plen
         self.stats["prefills"] += 1
         self._tok[slot.index] = tok0
-        self._emit(slot, tok0, events)
+        with tr.span("engine.emit", cat="engine"):
+            self._emit(slot, tok0, events)
 
     def cancel(self, rid: int) -> bool:
         """Drop a request wherever it is — queued, mid chunked-prefill,
@@ -1376,18 +1328,20 @@ class ServeEngine:
         the head — until the append succeeds; oldest slots grow first,
         so the policy is deterministic and FIFO-fair (a victim can
         never be older than the slot it yields to)."""
-        for s in sorted(self.sched.active_slots(),
-                        key=lambda s: s.admit_seq):
-            if s.state != DECODE:
-                continue            # preempted by an earlier iteration
-            if self.prefix is not None and not self._cow_guard(s, events):
-                continue            # s preempted itself finding a copy
-            while not self.blocks.extend_to(s.index, s.length + 1):
-                victim = max(self.sched.active_slots(),
-                             key=lambda v: v.admit_seq)
-                self._preempt(victim, events)
-                if victim is s:
-                    break           # s yielded its own pages; skip it
+        with self._obs.tracer.span("engine.grow_pages", cat="engine"):
+            for s in sorted(self.sched.active_slots(),
+                            key=lambda s: s.admit_seq):
+                if s.state != DECODE:
+                    continue        # preempted by an earlier iteration
+                if self.prefix is not None \
+                        and not self._cow_guard(s, events):
+                    continue        # s preempted itself finding a copy
+                while not self.blocks.extend_to(s.index, s.length + 1):
+                    victim = max(self.sched.active_slots(),
+                                 key=lambda v: v.admit_seq)
+                    self._preempt(victim, events)
+                    if victim is s:
+                        break       # s yielded its own pages; skip it
 
     def _cow_guard(self, s: Slot, events: Dict[str, Any]) -> bool:
         """Copy-on-write before a decode write lands in a shared page:
@@ -1529,8 +1483,8 @@ class ServeEngine:
         plen = req.prompt_len
         bucket = (plen if self._exact_prefill
                   else _bucket_for(plen, self.scfg.max_len))
-        with self._obs.tracer.span("prefill", cat="engine", rid=req.rid,
-                                   plen=plen, bucket=bucket):
+        with self._obs.tracer.span("engine.prefill", cat="engine",
+                                   rid=req.rid, plen=plen, bucket=bucket):
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :plen] = req.prompt
             batch: Dict[str, jax.Array] = {"tokens": jnp.asarray(toks)}
@@ -1553,9 +1507,11 @@ class ServeEngine:
                     self.caches, one, jnp.asarray(slot.index, jnp.int32))
             self.stats["prefills"] += 1
             slot.length = plen
+            tok0, row = self._first_token(logits,
+                                          jnp.asarray(plen - 1, jnp.int32))
             if self.logits_tap is not None:
-                self.logits_tap(req.rid, plen - 1, logits[0, plen - 1])
-            return jnp.argmax(logits[0, plen - 1])
+                self.logits_tap(req.rid, plen - 1, row)
+            return tok0
 
     # -- legacy one-shot API (reimplemented on the continuous loop) ---------
 

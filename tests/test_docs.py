@@ -30,7 +30,8 @@ DOCTEST_MODULES = [
     "repro.serving.kvpool",
     "repro.core.hw",
     "repro.obs.efficiency",
-    "repro.obs.profile",
+    "repro.obs.trace",
+    "repro.obs.devtrace",
     "repro.kernels.decode_attention",
     "repro.launch.compile_cache",
 ]

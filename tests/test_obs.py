@@ -390,5 +390,5 @@ def test_engine_trace_is_balanced_and_perfetto_valid(smoke_engine_run):
     phases = {e["ph"] for e in doc["traceEvents"]}
     assert {"X", "b", "e"} <= phases
     names = {e["name"] for e in doc["traceEvents"]}
-    assert {"engine.step", "prefill", "decode",
-            "request", "queued"} <= names
+    assert {"engine.step", "engine.prefill", "engine.decode.dispatch",
+            "decode", "request", "queued"} <= names

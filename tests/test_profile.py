@@ -1,9 +1,8 @@
-"""repro.obs.profile / slo / flight: step-time decomposition sums to
-wall time, stall classification agrees with the analytic roofline,
-SLO breaches fire exactly at the threshold (rolling-window property vs
-a reference model), flight rings never exceed their bounds and dumps
-round-trip through JSON — plus the histogram reservoir cap and the
-Chrome-trace metadata/flow extensions they ride on."""
+"""repro.obs slo / flight: SLO breaches fire exactly at the threshold
+(rolling-window property vs a reference model), flight rings never
+exceed their bounds and dumps round-trip through JSON — plus the
+histogram reservoir cap and the Chrome-trace metadata/flow extensions
+they ride on."""
 
 import json
 
@@ -20,9 +19,6 @@ from collections import deque
 from repro import obs
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import Histogram, Registry
-from repro.obs.profile import (COMPUTE_BOUND, MEMORY_BOUND, StepProfiler,
-                               classify_kernel, extract_costs,
-                               peak_bandwidth, ridge_intensity)
 from repro.obs.slo import SLOMonitor, window_percentile
 from repro.obs.trace import Tracer, validate_chrome_trace
 
@@ -114,115 +110,6 @@ def test_metadata_and_flow_noop_when_disabled_and_validator_rules():
             {"name": "f", "ph": "s", "ts": 0.0}]})
     with pytest.raises(ValueError):      # metadata events need a name
         validate_chrome_trace({"traceEvents": [{"ph": "M"}]})
-
-
-# ---------------------------------------------------------------------------
-# Step profiler: decomposition identity + roofline classification
-# ---------------------------------------------------------------------------
-
-
-def test_record_step_decomposition_sums_to_wall():
-    prof = StepProfiler(Registry(), device_kind="TPU v5 lite")
-    r = prof.record_step(10.0, {"admit": 1.0, "prefill": 2.0,
-                                "decode": 4.0})
-    assert r["device_ms"] + r["bubble_ms"] == r["wall_ms"] == 10.0
-    assert r["bubble_ms"] == pytest.approx(3.0)
-    # Probes can over-cover wall by clock granularity: clamp, never a
-    # negative bubble, identity still holds.
-    r = prof.record_step(5.0, {"decode": 7.0})
-    assert (r["device_ms"], r["bubble_ms"]) == (5.0, 0.0)
-    assert prof.bubble_fraction() == pytest.approx(3.0 / 15.0)
-    assert prof.wall_ms_total == 15.0
-    prof.reset_totals()                  # the warmup seam
-    assert prof.bubble_fraction() == 0.0
-
-
-def test_stall_classification_agrees_with_analytic_roofline():
-    v5e = "TPU v5 lite"
-    ridge = ridge_intensity("bfloat16", device_kind=v5e)
-    bw = peak_bandwidth(v5e)
-    nbytes = 1e6
-    hi = classify_kernel("gemm", flops=nbytes * ridge * 4.0,
-                         nbytes=nbytes, measured_us=100.0, device_kind=v5e)
-    assert hi.stall_class == COMPUTE_BOUND
-    assert hi.bound_us == pytest.approx(
-        hi.flops / (ridge * bw) * 1e6)   # peak_flops = ridge * bw
-    lo = classify_kernel("scatter", flops=nbytes * ridge * 0.25,
-                         nbytes=nbytes, measured_us=100.0, device_kind=v5e)
-    assert lo.stall_class == MEMORY_BOUND
-    assert lo.bound_us == pytest.approx(nbytes / bw * 1e6)
-    # At the ridge point exactly, the two bounds coincide: compute.
-    at = classify_kernel("ridge", flops=nbytes * ridge, nbytes=nbytes,
-                         measured_us=100.0, device_kind=v5e)
-    assert at.stall_class == COMPUTE_BOUND
-    for p in (hi, lo, at):
-        assert 0.0 < p.bound_ratio <= 1.0
-    with pytest.raises(ValueError):
-        classify_kernel("k", flops=1.0, nbytes=1.0, measured_us=0.0)
-
-
-def test_profiler_kernel_table_exports_gauges():
-    reg = Registry()
-    prof = StepProfiler(reg, device_kind="TPU v5 lite")
-    prof.record_kernel("flash_decode", flops=1e3, nbytes=1e9,
-                       measured_us=100.0)
-    prof.record_kernel("matmul", flops=1e12, nbytes=1e3,
-                       measured_us=100.0)
-    table = prof.kernel_table()
-    assert [p.name for p in table] == \
-        sorted((p.name for p in table),
-               key=lambda n: next(x.bound_ratio for x in table
-                                  if x.name == n))
-    g = reg.snapshot()["gauges"]
-    assert g["profile.flash_decode.memory_bound"]["value"] == 1.0
-    assert g["profile.matmul.memory_bound"]["value"] == 0.0
-    assert 0.0 < g["profile.matmul.bound_ratio"]["value"] <= 1.0
-    # Last-wins: re-recording replaces the row, not appends.
-    prof.record_kernel("matmul", flops=1e12, nbytes=1e3,
-                       measured_us=200.0)
-    assert len(prof.kernel_table()) == 2
-
-
-def test_extract_costs_defensive():
-    class Raises:
-        def cost_analysis(self):
-            raise NotImplementedError
-
-    class AsDict:
-        def cost_analysis(self):
-            return {"flops": 10.0, "bytes accessed": 20.0}
-
-    class AsList:
-        def cost_analysis(self):
-            return [{"flops": 5.0, "bytes accessed": 6.0}]
-
-    class Zeros:
-        def cost_analysis(self):
-            return {"flops": 0.0}
-
-    assert extract_costs(Raises()) is None
-    assert extract_costs(AsDict()) == (10.0, 20.0)
-    assert extract_costs(AsList()) == (5.0, 6.0)
-    assert extract_costs(Zeros()) is None
-
-
-def test_op_cost_model_formulas():
-    from repro.kernels.ops import op_cost_model
-    f, b = op_cost_model("matmul", m=64, k=64, n=64, dtype_bytes=2.0)
-    assert f == 2 * 64 ** 3
-    assert b == (64 * 64 + 64 * 64) * 2.0 + 64 * 64 * 2.0
-    f, b = op_cost_model("flash_decode", batch=2, heads=8, kv_heads=4,
-                         seq=128, d_head=64, kv_bytes=2.0,
-                         dtype_bytes=2.0)
-    assert f == 4 * 2 * 8 * 128 * 64          # QK^T + PV, 1 query token
-    assert b == (2 * 2 * 4 * 128 * 64 * 2.0   # KV read
-                 + 2 * 2 * 8 * 64 * 2.0)      # q + out
-    f, b = op_cost_model("prefill_chunk", chunk_tokens=16, kv_heads=4,
-                         d_head=64, kv_bytes=2.0, layers=4)
-    assert f == 0.0
-    assert b == 4 * 2 * 2 * 16 * 4 * 64 * 2.0
-    with pytest.raises(ValueError):
-        op_cost_model("warp_drive")
 
 
 # ---------------------------------------------------------------------------
@@ -364,9 +251,10 @@ def test_flight_preemption_storm_trips():
 
 @pytest.mark.serving
 def test_engine_attribution_slo_and_flight(tmp_path):
-    """End-to-end over a real engine: per-step decomposition sums to
-    wall time, default targets see zero breaches on the smoke trace, a
-    deliberately tight target fires and trips the flight recorder."""
+    """End-to-end over a real engine: every step leaves a flight record
+    of its wall time, default targets see zero breaches on the smoke
+    trace, a deliberately tight target fires and trips the flight
+    recorder."""
     import jax
 
     from repro import configs as C
@@ -385,17 +273,14 @@ def test_engine_attribution_slo_and_flight(tmp_path):
         # Acceptance: zero breaches at default (unarmed) targets.
         assert engine.slo.breaches() == 0
         assert rep["slo_breaches"] == 0
-        assert 0.0 <= rep["bubble_fraction"] < 1.0
-        # Decomposition identity on every retained step record (flight
-        # rounds to 3 decimals, hence the 2e-3 slack).
+        # Every retained step record carries the step's host wall time
+        # and its request counts.
         dump = engine.flight.dump("test")
         assert dump["steps"]
         for s in dump["steps"]:
-            assert s["device_ms"] + s["bubble_ms"] == \
-                pytest.approx(s["wall_ms"], abs=2e-3)
-        # The decode hot op got classified onto the roofline.
-        assert any(k.name in ("flash_decode", "flash_paged_decode")
-                   for k in engine.profiler.kernel_table())
+            assert s["wall_ms"] > 0.0
+            assert {"admitted", "decoded", "finished",
+                    "preempted"} <= set(s)
         # Every request has a full flight timeline.
         for t in trace:
             evs = [e["event"] for e in dump["requests"][str(t["id"])]]

@@ -5,13 +5,15 @@ Consumes the JSON artifacts a serving run leaves behind and turns them
 into the tables a human actually reads during triage:
 
 * ``--metrics`` (required) — the schema-1 snapshot from
-  ``--metrics-out`` / ``benchmarks.run --json``: run summary, step-time
-  **attribution table** (device vs bubble), per-kernel **roofline
-  table** (stall class + achieved-vs-bound ratio), SLO window state;
+  ``--metrics-out`` / ``benchmarks.run --json``: run summary and SLO
+  window state;
 * ``--trace`` (optional) — the Chrome trace from ``--trace-out``: span
   aggregates per name and the **breach log** (``slo.breach`` instants);
 * ``--flight`` (optional) — the ``--flight-out`` flight record: trip
   log and the last recorded steps.
+
+Where the device's time goes is read from a ``jax.profiler`` trace
+instead, on the device's clock: ``python -m repro.obs.devtrace``.
 
 Markdown-shaped output (pipe tables) renders in a terminal and pastes
 straight into an issue.  Exit codes: 0 ok, 2 malformed input.
@@ -74,60 +76,15 @@ def run_summary(snap: Dict[str, Any]) -> List[str]:
         return []
     keys = ("arch", "kv_mode", "prefill_chunk", "tokens", "tok_s",
             "p50_ms", "p99_ms", "ttft_p50_ms", "ttft_p99_ms",
-            "bubble_fraction", "slo_breaches")
+            "slo_breaches")
     rows = [[k, _fmt(run[k])] for k in keys if k in run]
+    eff = snap.get("gauges", {}).get("serve.efficiency", {})
+    if isinstance(eff, dict) and "value" in eff:
+        rows.append(["efficiency (of the published peak)",
+                     _fmt(eff["value"])])
     if not rows:
         return []
     return ["## Run", ""] + _table(["key", "value"], rows) + [""]
-
-
-def attribution(snap: Dict[str, Any]) -> List[str]:
-    hists = snap.get("histograms", {})
-    gauges = snap.get("gauges", {})
-    rows = []
-    for name in ("step.device_ms", "step.bubble_ms"):
-        h = hists.get(name)
-        if isinstance(h, dict):
-            rows.append(_hist_row(name, h))
-    if not rows:
-        return []
-    out = ["## Step-time attribution", ""]
-    out += _table(["series", "count", "p50", "p90", "p99", "max",
-                   "sum"], rows)
-    bf = gauges.get("serve.bubble_fraction", {})
-    if isinstance(bf, dict) and "value" in bf:
-        out += ["", f"bubble fraction: **{_fmt(bf['value'])}** "
-                    f"(high water {_fmt(bf.get('high_water'))}) — "
-                    f"share of step wall time not covered by the "
-                    f"device-attributed section probes"]
-    return out + [""]
-
-
-def roofline(snap: Dict[str, Any]) -> List[str]:
-    gauges = snap.get("gauges", {})
-    kernels: Dict[str, Dict[str, Any]] = {}
-    for name, g in gauges.items():
-        if not (name.startswith("profile.") and isinstance(g, dict)):
-            continue
-        parts = name.split(".")
-        if len(parts) != 3:
-            continue
-        kernels.setdefault(parts[1], {})[parts[2]] = g.get("value")
-    rows = []
-    for op in sorted(kernels,
-                     key=lambda o: kernels[o].get("bound_ratio") or 0.0):
-        k = kernels[op]
-        cls = ("memory" if k.get("memory_bound") else "compute")
-        rows.append([op, cls, _fmt(k.get("bound_ratio"))])
-    if not rows:
-        return []
-    out = ["## Kernel roofline (stall classification)", ""]
-    out += _table(["kernel", "stall class", "achieved/bound"], rows)
-    eff = gauges.get("serve.efficiency", {})
-    if isinstance(eff, dict) and "value" in eff:
-        out += ["", f"serve efficiency: {_fmt(eff['value'])} of the "
-                    f"chip's published peak"]
-    return out + [""]
 
 
 def slo_section(snap: Dict[str, Any],
@@ -207,12 +164,10 @@ def flight_section(flight: Dict[str, Any], last: int = 8) -> List[str]:
            f"timelines, {len(flight.get('trips', []))} trips"]
     if steps:
         rows = [[_fmt(s.get("step"), 0), _fmt(s.get("wall_ms")),
-                 _fmt(s.get("device_ms")), _fmt(s.get("bubble_ms")),
                  _fmt(s.get("decoded"), 0), _fmt(s.get("finished"), 0),
                  _fmt(s.get("preempted"), 0)]
                 for s in steps[-last:]]
-        out += [""] + _table(["step", "wall ms", "device ms",
-                              "bubble ms", "decoded", "finished",
+        out += [""] + _table(["step", "wall ms", "decoded", "finished",
                               "preempted"], rows)
     return out + [""]
 
@@ -221,8 +176,6 @@ def render(snap: Dict[str, Any], trace: Optional[Dict[str, Any]],
            flight: Optional[Dict[str, Any]]) -> str:
     lines = ["# repro.obs report", ""]
     lines += run_summary(snap)
-    lines += attribution(snap)
-    lines += roofline(snap)
     lines += slo_section(snap, trace, flight)
     if trace is not None:
         lines += trace_section(trace)
