@@ -33,11 +33,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
-from repro.kernels.decode_attention import flash_decode, flash_paged_decode
+from repro.kernels.decode_attention import (flash_decode, flash_paged_decode,
+                                           paged_pages_per_block)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gemm import gama_gemm
 from repro.kernels.wkv import wkv6
-from repro.obs import count as _obs_count
+from repro.obs import count as _obs_count, get_obs as _get_obs
 
 Mode = str  # "auto" | "kernel" | "ref"
 
@@ -294,11 +295,14 @@ def decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array, *,
     per-slot valid rows.
 
     The kernel path gathers each slot's pages via the block table
-    inside the split-K loop (one page per step, the last partial page
-    masked by ``length``); ``buffers=2`` (default) double-buffers that
-    gather with explicit DMA copy slots so page i+1's loads overlap page
-    i's softmax/matmul, and ``buffers=1`` keeps the serial BlockSpec
-    gather — both bit-identical.
+    inside the split-K loop (the last partial page masked by
+    ``length``).  ``buffers=2`` (default) takes a block of pages of all
+    KV heads per step, copies only the live pages with explicit DMAs
+    into two ping-pong slots (block j+1's loads overlap block j's
+    softmax/matmul), and sizes the block from the shapes
+    (``paged_pages_per_block``, kept in the
+    ``ops.decode_paged.pages_per_block`` gauge at trace time);
+    ``buffers=1`` keeps the serial one-page-one-head BlockSpec gather.
     int8 pools pass per-row ``k_scale``/``v_scale`` rows
     ((P,Hkv,page_size) f32); dequant fuses into the split-K loop.  The
     ref path dequantizes + materializes the gather and runs the dense
@@ -333,6 +337,13 @@ def decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array, *,
             q, k_pages, v_pages, block_tables, length=length, scale=scale,
             k_scale=k_scale, v_scale=v_scale)
     _obs_count("ops.decode_paged.kernel")
+    if buffers == 2:
+        _get_obs().registry.gauge(
+            "ops.decode_paged.pages_per_block",
+            "table pages per grid step of the paged decode kernel").set(
+            paged_pages_per_block(
+                block_tables.shape[1], hkv, page_size, k_pages.shape[-1],
+                k_pages.dtype.itemsize, quantized))
     group = hq // hkv
     gp = max(8, group)                  # sublane-pad the GQA group
     if gp != group:

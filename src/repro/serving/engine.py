@@ -435,6 +435,14 @@ class ServeEngine:
         self._c_rejects = obs.registry.counter(
             "serve.admission_rejections",
             "arrived requests deferred by the paged fits() gate")
+        self._c_pages_live = obs.registry.counter(
+            "engine.decode.kv_pages_live",
+            "KV pages the paged decode reads: ceil((pos + 1) / page) "
+            "summed over slots, per decode step")
+        self._c_pages_table = obs.registry.counter(
+            "engine.decode.kv_pages_table",
+            "slots x max_pages: the block-table entries a decode step "
+            "holds")
         self._g_active = obs.registry.gauge(
             "serve.active_slots", "slots mid-decode")
         self._g_kv_tokens = obs.registry.gauge(
@@ -928,14 +936,17 @@ class ServeEngine:
                 t_dec = time.perf_counter()
                 with tr.span("engine.decode.prepare", cat="engine",
                              batch=len(active)):
+                    # Slots not decoding stay at position 0: they
+                    # decode garbage into their own dead row 0 (dense:
+                    # replaced wholesale on re-admission; paged: the
+                    # null sink page their table row holds), so the
+                    # kernel reads one key for them, not the length a
+                    # released slot's last request left.  The clamp
+                    # only guards the bound.
                     pos = np.zeros((self.scfg.batch_slots,), np.int32)
                     pos_cap = (self._fresh_len if self.kv_mode == "paged"
                                else self.scfg.max_len) - 1
-                    for s in self.sched.slots:
-                        # Inactive slots decode garbage into their own
-                        # dead rows (dense: replaced wholesale on
-                        # re-admission; paged: the null sink page); the
-                        # clamp only guards the bound.
+                    for s in active:
                         pos[s.index] = min(s.length, pos_cap)
                     token_idx = np.zeros((self.scfg.batch_slots,), np.int32)
                     for s in active:
@@ -943,6 +954,12 @@ class ServeEngine:
                     inputs = [jnp.asarray(self._tok), jnp.asarray(pos)]
                     if self.kv_mode == "paged":
                         inputs.append(jnp.asarray(self._decode_table()))
+                        # Pages the kernel reads (each slot attends over
+                        # pos + 1 keys) against the table it walks.
+                        ps = self.pool.page_size
+                        self._c_pages_live.inc(
+                            int((pos // ps).sum()) + len(pos))
+                        self._c_pages_table.inc(pos.size * self._max_pages)
                     token_idx = jnp.asarray(token_idx)
                 with tr.span("engine.decode.dispatch", cat="engine"):
                     logits, self.caches = self._decode(

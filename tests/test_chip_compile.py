@@ -185,15 +185,16 @@ def test_flash_decode_batched(one_chip):
 
 
 def _paged_args(sharding, row_dim, page_size, kv_dtype, n_pages=64,
-                max_pages=8):
+                max_pages=8, slots=SLOTS, hkv=CFG.n_kv_heads,
+                d_head=CFG.d_head):
     """Arguments at the served geometry: head dim 64, page rows of
-    ``row_dim`` lanes (the serving pools use ``page_row_dim(64)``)."""
-    hkv = CFG.n_kv_heads
-    q = _spec(sharding, (SLOTS, hkv * 8, CFG.d_head), jnp.bfloat16)
+    ``row_dim`` lanes (the serving pools use ``page_row_dim(64)``), the
+    query group padded to 8 rows as ``ops.decode_paged`` pads it."""
+    q = _spec(sharding, (slots, hkv * 8, d_head), jnp.bfloat16)
     pages = _spec(sharding, (n_pages, hkv, page_size, row_dim), kv_dtype)
     args = [q, pages, pages,
-            _spec(sharding, (SLOTS, max_pages), jnp.int32),
-            _spec(sharding, (SLOTS,), jnp.int32)]
+            _spec(sharding, (slots, max_pages), jnp.int32),
+            _spec(sharding, (slots,), jnp.int32)]
     if kv_dtype == jnp.int8:
         rows = _spec(sharding, (n_pages, hkv, page_size), jnp.float32)
         args += [rows, rows]
@@ -231,14 +232,26 @@ def test_flash_paged_decode_blockspec_gather(one_chip, kv_dtype, page_size,
                          ids=["bf16", "int8"])
 @pytest.mark.parametrize("page_size", [16, 32])
 def test_flash_paged_decode_dma_gather(one_chip, kv_dtype, page_size):
-    """buffers=2 (explicit DMA ping-pong) at the served geometry: head
-    dim 64 in page rows padded to 128 lanes, so each page copy is
-    lane-aligned; int8 scale rows come through the BlockSpec pipeline
-    at any page size."""
-    text = _compile(_paged_fn(2, kv_dtype == jnp.int8),
-                    *_paged_args(one_chip, SERVED_ROWS, page_size,
-                                 kv_dtype))
-    assert "gama_flash_paged_decode_b2" in text
+    """buffers=2 (explicit DMA ping-pong of blocks of whole pages) at
+    the served geometries, each page copy lane-aligned and int8 scale
+    rows coming through the BlockSpec pipeline: a short table (one
+    block), smollm_360m's 32 slots over a 2048-token table (several
+    blocks of 256 keys), and Phi-3-medium's 16 slots of 10 KV heads of
+    128 with a group of 4 padded to 8.  The kernel keeps its name,
+    which the benchmark reads its device time by."""
+    tokens = 2048 // page_size
+    geometries = [
+        {},
+        {"slots": 32, "max_pages": tokens, "n_pages": 4096},
+        {"slots": 16, "max_pages": tokens, "n_pages": 4096, "hkv": 10,
+         "d_head": 128},
+    ]
+    for geometry in geometries:
+        row_dim = page_row_dim(geometry.get("d_head", CFG.d_head))
+        text = _compile(_paged_fn(2, kv_dtype == jnp.int8),
+                        *_paged_args(one_chip, row_dim, page_size,
+                                     kv_dtype, **geometry))
+        assert "gama_flash_paged_decode_b2" in text, geometry
 
 
 def test_flash_paged_decode_dma_gather_refuses_narrow_heads(one_chip):

@@ -15,6 +15,8 @@ except ImportError:  # deterministic fallback sampler, see _hypothesis_stub
     from _hypothesis_stub import given, settings, st
 
 from repro.kernels import ops, ref
+from repro.kernels.decode_attention import (flash_paged_decode,
+                                           paged_pages_per_block)
 
 RNG = np.random.default_rng(42)
 
@@ -221,10 +223,12 @@ def test_flash_paged_decode_int8_matches_dequant_oracle(buffers):
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
 def test_paged_decode_double_buffer_bit_identical(quantized):
-    """buffers=2 (explicit-DMA pipelined page gather) and buffers=1
-    (BlockSpec gather) share one arithmetic body — their outputs must
-    be BIT-identical, not just close: any drift means the pipeline
-    reordered or re-rounded the online softmax."""
+    """buffers=2 (explicit-DMA pipelined block gather) at one page per
+    block and buffers=1 (BlockSpec gather) share one arithmetic body —
+    their outputs must be BIT-identical, not just close: any drift
+    means the pipeline reordered or re-rounded the online softmax.  At
+    its derived block size buffers=2 sums larger blocks, so it is held
+    to the oracle's float tolerance instead."""
     b, hq, hkv, d, ps, n_pool = 4, 8, 2, 64, 16, 24
     lengths = np.asarray([3 * ps + 5, 2 * ps, ps - 1, 1])
     q = randf((b, hq, d))
@@ -238,11 +242,113 @@ def test_paged_decode_double_buffer_bit_identical(quantized):
         scales = {}
     bt = _rand_block_tables(b, 4, n_pool, lengths, ps, seed=11)
     ln = jnp.asarray(lengths, jnp.int32)
-    one = ops.decode_paged(q, kp, vp, block_tables=bt, length=ln,
-                           buffers=1, mode="kernel", **scales)
-    two = ops.decode_paged(q, kp, vp, block_tables=bt, length=ln,
-                           buffers=2, mode="kernel", **scales)
+    one = flash_paged_decode(q, kp, vp, bt, length=ln, buffers=1,
+                             interpret=True, **scales)
+    two = flash_paged_decode(q, kp, vp, bt, length=ln, buffers=2,
+                             pages_per_block=1, interpret=True, **scales)
     np.testing.assert_array_equal(np.asarray(one), np.asarray(two))
+    derived = ops.decode_paged(q, kp, vp, block_tables=bt, length=ln,
+                               buffers=2, mode="kernel", **scales)
+    np.testing.assert_allclose(
+        np.asarray(derived),
+        np.asarray(ref.ref_paged_decode_attention(q, kp, vp, bt, length=ln,
+                                                  **scales)),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_paged_decode_bf16_pool_bit_identical_and_near_oracle():
+    """bf16 queries over a bf16 pool, the serving dtypes, take the
+    kernel's native-MXU body (no f32 copies of K and V, p split into two
+    bf16 terms): still one body for both pipelines, so bit-identical at
+    one page per block, and within two bf16 ulps of the f32 oracle at
+    the derived block size (the output itself is bf16)."""
+    rng = np.random.default_rng(21)
+    b, hq, hkv, d, ps, n_pool = 4, 8, 2, 64, 16, 24
+    lengths = np.asarray([3 * ps + 5, 2 * ps, ps - 1, 1])
+
+    def bf16(shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+
+    q = bf16((b, hq, d))
+    kp, vp = bf16((n_pool + 1, hkv, ps, d)), bf16((n_pool + 1, hkv, ps, d))
+    bt = _rand_block_tables(b, 4, n_pool, lengths, ps, seed=13)
+    ln = jnp.asarray(lengths, jnp.int32)
+    one = flash_paged_decode(q, kp, vp, bt, length=ln, buffers=1,
+                             interpret=True)
+    two = flash_paged_decode(q, kp, vp, bt, length=ln, buffers=2,
+                             pages_per_block=1, interpret=True)
+    np.testing.assert_array_equal(np.asarray(one, np.float32),
+                                  np.asarray(two, np.float32))
+    derived = ops.decode_paged(q, kp, vp, block_tables=bt, length=ln,
+                               mode="kernel")
+    exp = ref.ref_paged_decode_attention(
+        q.astype(jnp.float32), kp.astype(jnp.float32),
+        vp.astype(jnp.float32), bt, length=ln)
+    np.testing.assert_allclose(np.asarray(derived, np.float32),
+                               np.asarray(exp), rtol=8e-3, atol=8e-3)
+
+
+# Slot lengths against blocks of 8 pages of 256 tokens over a 20-page
+# table (3 blocks, the last padded): ends mid-block, exactly at a block
+# edge, one token (two blocks of sink entries only), the full table.
+_BLOCK_LENGTHS = {"mid_block": 5 * 256 + 7, "block_edge": 8 * 256,
+                  "one_token": 1, "full_table": 20 * 256}
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("pattern", sorted(_BLOCK_LENGTHS))
+def test_paged_decode_blocks_match_ref(pattern, quantized):
+    """The block kernel at its derived block size (8 pages at page 256)
+    against the oracle, with the slot under test beside a full-table
+    slot: the block that holds the length's end, the blocks wholly past
+    it (no DMA, no compute) and the padded last block must all leave
+    the output the oracle's."""
+    rng = np.random.default_rng(17)
+    b, hq, hkv, d, ps, max_pages = 2, 8, 2, 64, 256, 20
+    lengths = np.asarray([_BLOCK_LENGTHS[pattern], max_pages * ps])
+    n_pool = int(sum(-(-n // ps) for n in lengths))
+
+    def pool():
+        x = rng.normal(size=(n_pool + 1, hkv, ps, d)).astype(np.float32)
+        x[n_pool] = 1e4                   # sink garbage, never a key
+        return jnp.asarray(x)
+
+    q = jnp.asarray(rng.normal(size=(b, hq, d)), jnp.float32)
+    kp, vp = pool(), pool()
+    scales = {}
+    if quantized:
+        (kp, ks), (vp, vs) = _quantize_pool(kp), _quantize_pool(vp)
+        scales = {"k_scale": ks, "v_scale": vs}
+    assert paged_pages_per_block(max_pages, hkv, ps, d, kp.dtype.itemsize,
+                                 quantized) == 8
+    bt = _rand_block_tables(b, max_pages, n_pool, lengths, ps, seed=2)
+    ln = jnp.asarray(lengths, jnp.int32)
+    out = ops.decode_paged(q, kp, vp, block_tables=bt, length=ln,
+                           buffers=2, mode="kernel", **scales)
+    exp = ref.ref_paged_decode_attention(q, kp, vp, bt, length=ln, **scales)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_paged_decode_pages_per_block_gauge():
+    """The block size the kernel derived is kept in the registry at
+    trace time: 32 pages of 64 tokens (2048 keys) here."""
+    from repro import obs
+    obs.reset()
+    try:
+        rng = np.random.default_rng(3)
+        b, hkv, d, ps = 2, 2, 64, 64
+        bt = _rand_block_tables(b, 40, 20, [70, 300], ps)
+        pool = jnp.asarray(rng.normal(size=(21, hkv, ps, d)), jnp.float32)
+        q = jnp.asarray(rng.normal(size=(b, 8, d)), jnp.float32)
+        ops.decode_paged(q, pool, pool, block_tables=bt,
+                         length=jnp.asarray([70, 300], jnp.int32),
+                         mode="kernel")
+        gauge = obs.get_obs().registry.gauges[
+            "ops.decode_paged.pages_per_block"]
+        assert gauge.value == 32
+    finally:
+        obs.reset()
 
 
 @pytest.mark.parametrize("buffers", [1, 2])

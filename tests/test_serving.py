@@ -215,6 +215,47 @@ def test_midstream_admission_shares_decode_step():
         eng.close()
 
 
+def test_decode_page_counters_and_released_slot_length():
+    """Each paged decode step counts the KV pages its kernel reads
+    (``ceil((pos + 1) / page)`` summed over slots) and the table it
+    holds (slots x max_pages).  Only decoding slots carry their length:
+    a released slot and a never-used one decode at position 0, so the
+    kernel reads one key for them, not a finished request's length."""
+    from repro import obs
+    obs.reset()
+    prompts = _prompts((20, 7), seed=9)
+    eng = ServeEngine(CFG, PARAMS, ServeConfig(
+        batch_slots=3, max_len=64, kv="paged", page_size=16))
+    seen = []
+    decode = eng._decode
+
+    def spy(params, tok, pos, table, caches):
+        seen.append(np.asarray(pos).copy())
+        return decode(params, tok, pos, table, caches)
+
+    eng._decode = spy
+    try:
+        long_rid = eng.submit(prompts[20], 8)
+        short_rid = eng.submit(prompts[7], 2)
+        res = eng.drain()
+        counters = obs.get_obs().registry.counters
+        live = counters["engine.decode.kv_pages_live"].value
+        table = counters["engine.decode.kv_pages_table"].value
+    finally:
+        eng.close()
+        obs.reset()
+    assert len(res[long_rid]) == 8 and len(res[short_rid]) == 2
+    pos = np.stack(seen)                      # (decode steps, slots)
+    # The long request decodes from its prompt length on, one a step.
+    np.testing.assert_array_equal(pos[:, 0], 20 + np.arange(len(pos)))
+    # The short one decodes at 7, then its released slot sits at 0
+    # while the long one runs on; the third slot never held a request.
+    assert pos[0, 1] == 7 and (pos[1:, 1] == 0).all() and len(pos) > 1
+    assert (pos[:, 2] == 0).all()
+    assert live == sum(int(np.ceil((p + 1) / 16).sum()) for p in pos)
+    assert live == sum(-(-(20 + i + 1) // 16) + 2 for i in range(len(pos)))
+    assert table == len(pos) * 3 * (64 // 16)
+
 # ---------------------------------------------------------------------------
 # Property: outputs are invariant to arrival order/spacing
 # ---------------------------------------------------------------------------
