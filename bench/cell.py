@@ -3,10 +3,11 @@
     python3 -m bench.cell --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell's entry in ``BENCHMARK.json`` names a configuration
-(``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<mix>.json``); its output-check limits are in
-``bench/limits/<workload>.json`` and each per-layer metric is read by
-``bench/metrics/<metric>.py``.  The run draws the weights and the
+(``bench/configs/<config>.json``, whose ``reference`` key names its
+architecture: ``bench/arch/<name>.py`` and ``bench/reference/<name>.py``)
+and a traffic mix (``bench/traffic/<mix>.json``); its output-check
+limits are in ``bench/limits/<workload>.json`` and each per-layer metric
+is read by ``bench/metrics/<metric>.py``.  The run draws the weights and the
 requests from ``--seed``, builds the program's ``ServeEngine`` with the
 mix's settings, warms up every shape the mix uses, replays the mix for
 ``--seconds`` through ``submit()``/``step()``, checks the served tokens
@@ -199,7 +200,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
 
     from bench.lib import check, model, traffic
     from bench.lib.replay import Record, Replay, no_annotation
-    shape = model.load_shape(spec["config_file"], cell["config"])
+    shape = model.load_shape(root, spec["config_file"], cell["config"])
     weights = model.make_weights(shape, seed)
     engine = program.build_engine(shape, weights, mix["engine"], seed,
                                   **(overrides or {}))

@@ -20,23 +20,19 @@ from.
 
 from __future__ import annotations
 
-import importlib
 import json
 from pathlib import Path
 from typing import Dict, List
 
 import numpy as np
 
+from bench.lib.model import reference_of
 from bench.lib.traffic import rng_for
 
 
 def load_limits(root: Path, workload: str) -> Dict[str, dict]:
     return json.loads((root / "bench" / "limits" / f"{workload}.json")
                       .read_text())
-
-
-def reference_module(name: str):
-    return importlib.import_module(f"bench.reference.{name}")
 
 
 def sample(run, k: int, seed: int) -> List:
@@ -87,7 +83,7 @@ def gaps(shape, weights, rows, pad_to: int, control: bool = False
     of the token it ranks first."""
     import jax
     import jax.numpy as jnp
-    ref = reference_module(shape.reference)
+    ref = reference_of(shape)
     row_gaps = jax.jit(_row_gaps)
     out = []
     for _, seq, pos, served in rows:

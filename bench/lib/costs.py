@@ -2,11 +2,11 @@
 
 The GEMM terms follow the program's ``obs/efficiency.model_flops_per_token``
 and ``kernels/ops.op_cost_model`` (2·M·K·N operations; A, B and C each
-through memory once), with attention added: a query at position ``p``
-attends over ``p + 1`` keys, 4·heads·d_head operations per key (QKᵀ and
-PV).  Only the work a step needs is counted: the rows of tokens it
-serves, not the rows or lanes the program pads to, and the lm head of a
-prompt chunk only for the row whose logits are used.
+through memory once).  Which GEMMs a forward runs, and what attention
+costs, are the architecture's terms (:mod:`bench.lib.model`), reached
+through the shape.  Only the work a step needs is counted: the rows of
+tokens it serves, not the rows or lanes the program pads to, and the lm
+head of a prompt chunk only for the row whose logits are used.
 
 :func:`window_work` rebuilds, from a run's records, every forward call
 the window made: each decode step with its live contexts, and each
@@ -18,24 +18,11 @@ leading up to its first token.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Tuple
+from typing import List
 
-from bench.lib.model import Shape
+from bench.lib.model import arch_of
 
 BF16 = 2
-
-
-def forward_gemms(s: Shape, rows: int, head_rows: int
-                  ) -> List[Tuple[int, int, int]]:
-    """(M, K, N) of every GEMM of one forward over ``rows`` token rows,
-    ``head_rows`` of which need logits."""
-    d, hd, kvd = s.d_model, s.heads * s.d_head, s.kv_heads * s.d_head
-    layer = [(rows, d, hd), (rows, d, kvd), (rows, d, kvd), (rows, hd, d),
-             (rows, d, s.d_ff), (rows, d, s.d_ff), (rows, s.d_ff, d)]
-    out = layer * s.layers
-    if head_rows:
-        out.append((head_rows, d, s.vocab))
-    return out
 
 
 def gemm_flops(shapes) -> float:
@@ -51,26 +38,9 @@ def gemm_least_s(shapes, peak_flops: float, bw: float,
                for m, k, n in shapes)
 
 
-def attention_flops(s: Shape, keys: int) -> float:
-    """QKᵀ and PV of queries attending over ``keys`` keys in total."""
-    return 4.0 * s.layers * s.heads * s.d_head * keys
-
-
 def causal_keys(lo: int, take: int) -> int:
     """Keys attended by the queries at positions lo .. lo+take-1."""
     return take * lo + take * (take + 1) // 2
-
-
-def paged_decode_cost(s: Shape, contexts: List[int],
-                      nbytes: int = BF16) -> Tuple[float, float]:
-    """(operations, bytes) of one decode step's paged attention over
-    every layer: each live slot reads its context's K and V rows at
-    ``d_head`` lanes, and its query and output."""
-    keys = sum(contexts)
-    flops = attention_flops(s, keys)
-    kv = s.layers * 2 * s.kv_heads * s.d_head * keys * nbytes
-    qo = s.layers * 2 * len(contexts) * s.heads * s.d_head * nbytes
-    return flops, float(kv + qo)
 
 
 @dataclasses.dataclass
@@ -129,6 +99,7 @@ def window_work(run, chunk: int) -> List[Call]:
     return calls
 
 
-def model_flops(s: Shape, calls: List[Call]) -> float:
-    return sum(gemm_flops(forward_gemms(s, c.rows, c.head_rows))
-               + attention_flops(s, c.keys) for c in calls)
+def model_flops(s, calls: List[Call]) -> float:
+    arch = arch_of(s)
+    return sum(gemm_flops(arch.forward_gemms(s, c.rows, c.head_rows))
+               + arch.attention_flops(s, c.keys) for c in calls)

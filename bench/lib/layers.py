@@ -11,11 +11,11 @@ reported as 0 for want of a reading.
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 from pathlib import Path
 from typing import List, Optional
 
 from bench.lib import costs
+from bench.lib.model import arch_of, module
 from bench.lib.peaks import peaks
 from bench.lib.replay import percentile
 
@@ -23,7 +23,7 @@ from bench.lib.replay import percentile
 @dataclasses.dataclass
 class Context:
     run: object            # replay.Run of the window
-    shape: object          # model.Shape
+    shape: object          # the architecture's shape (bench.lib.model)
     mix: dict
     trace: object          # xtrace.Reduced, the window's device trace
     device_kind: str
@@ -31,6 +31,11 @@ class Context:
     @property
     def peaks(self) -> dict:
         return peaks(self.device_kind)
+
+    @property
+    def arch(self):
+        """The architecture module, whose cost terms take the shape."""
+        return arch_of(self.shape)
 
     def window_steps(self):
         return [st for st in self.run.steps if st.t0 < self.run.seconds]
@@ -40,12 +45,7 @@ class Context:
 
 
 def load_reader(root: Path, name: str):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return module(root, "metrics", name).read
 
 
 def read_all(root: Path, metrics: list, ctx: Context) -> dict:
@@ -87,7 +87,7 @@ def kernel_roofline_pct(ctx: Context, kernel: str, least_s: float
 def gemm_roofline_pct(ctx: Context) -> Optional[float]:
     p = ctx.peaks
     least = sum(costs.gemm_least_s(
-        costs.forward_gemms(ctx.shape, c.rows, c.head_rows),
+        ctx.arch.forward_gemms(ctx.shape, c.rows, c.head_rows),
         p["bf16_flops"], p["hbm_bytes_per_s"]) for c in ctx.calls())
     return kernel_roofline_pct(ctx, "gama_gemm", least)
 
@@ -97,7 +97,8 @@ def paged_decode_roofline_pct(ctx: Context) -> Optional[float]:
     least = 0.0
     for c in ctx.calls():
         if c.kind == "decode":
-            flops, nbytes = costs.paged_decode_cost(ctx.shape, c.contexts)
+            flops, nbytes = ctx.arch.paged_decode_cost(ctx.shape,
+                                                       c.contexts)
             least += max(flops / p["bf16_flops"],
                          nbytes / p["hbm_bytes_per_s"])
     return kernel_roofline_pct(ctx, "gama_flash_paged_decode", least)

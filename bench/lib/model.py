@@ -1,59 +1,100 @@
-"""A configuration file's sizes, and its weights drawn from a seed.
+"""A configuration's architecture, its sizes, and its weights drawn from a
+seed.
+
+An architecture is a unit of files, named by the configuration file's
+``reference`` key and found by path under the benchmark's root, so that
+a configuration of a new architecture is added with new files alone:
+
+``bench/arch/<name>.py``
+    ``load_shape(config, name)``: a frozen, hashable shape (the plain
+    reference is jitted with it static) that carries at least
+    :data:`SHAPE_FIELDS`, the fields the harness reads on any
+    architecture.
+    ``weight_shapes(shape)``: ``(shapes, norms)``, the canonical weight
+    names with their shapes, and the names that are norm scales.
+    ``program_config(shape)`` and ``program_params(weights)``: the
+    program's ``ModelConfig`` and its parameter tree of the canonical
+    weights; the program is imported inside them, never at the top.
+    The cost terms, which count only the work the served tokens need:
+    ``matmul_params(shape)``; ``forward_gemms(shape, rows, head_rows)``,
+    the (M, K, N) of every GEMM of one forward over ``rows`` token rows
+    of which ``head_rows`` need logits (an expert layer lists the rows
+    each expert serves); ``attention_flops(shape, keys)`` over ``keys``
+    attended keys; and ``paged_decode_cost(shape, contexts)``, the
+    (operations, bytes) of one decode step's paged attention over the
+    live ``contexts`` (latent attention counts the latent bytes a key
+    keeps).
+``bench/reference/<name>.py``
+    ``logits(weights, tokens, shape, int8=False)``: the plain reference
+    over the canonical weights, importing nothing of the program.
 
 The weights are canonical: one dict of stacked arrays that the plain
-reference reads under its own names and that :mod:`bench.lib.program`
-hands to the program in its layout.  They are drawn on the device in
-one jitted call, in the dtype the checkpoint is published in.
+reference reads under its own names and that the architecture hands to
+the program in its layout.  They are drawn on the device in one jitted
+call, in the dtype the checkpoint is published in.
 """
 
 from __future__ import annotations
 
-import dataclasses
+import hashlib
+import importlib.util
 import json
+import sys
 from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 
-
-@dataclasses.dataclass(frozen=True)
-class Shape:
-    name: str
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    d_head: int
-    d_ff: int
-    vocab: int
-    tied: bool
-    rope_theta: float
-    eps: float
-    init_std: float
-    dtype: str
-    reference: str
-
-    @property
-    def matmul_params(self) -> int:
-        """Parameters of every matrix a token's forward pass multiplies
-        by, the lm head included."""
-        attn = self.d_model * (self.heads + 2 * self.kv_heads) * self.d_head \
-            + self.heads * self.d_head * self.d_model
-        return self.layers * (attn + 3 * self.d_model * self.d_ff) \
-            + self.d_model * self.vocab
+SHAPE_FIELDS = ("name", "layers", "vocab", "dtype", "init_std", "reference")
 
 
-def load_shape(path: Path, name: str) -> Shape:
+def module(root: Path, kind: str, name: str):
+    """``bench/<kind>/<name>.py`` under ``root``, loaded once per file.
+    It is registered in ``sys.modules`` under a name of its path, so
+    that a shape's class leads back to its module."""
+    path = (Path(root) / "bench" / kind / f"{name}.py").resolve()
+    key = (f"bench_{kind}_{name.replace('.', '_')}_"
+           f"{hashlib.sha1(str(path).encode()).hexdigest()[:12]}")
+    if key in sys.modules:
+        return sys.modules[key]
+    if not path.is_file():
+        raise FileNotFoundError(f"no {kind} module {name!r}: {path} is "
+                                f"missing")
+    spec = importlib.util.spec_from_file_location(key, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[key] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[key]
+        raise
+    return mod
+
+
+def load_shape(root: Path, path: Path, name: str):
+    """The shape of the configuration file ``path``, by its
+    architecture's module under ``root``; an architecture or reference
+    that is not there fails here, before any weight is drawn."""
     c = json.loads(Path(path).read_text())
-    heads = c["num_attention_heads"]
-    return Shape(
-        name=name, layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        heads=heads, kv_heads=c["num_key_value_heads"],
-        d_head=c.get("head_dim") or c["hidden_size"] // heads,
-        d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-        tied=bool(c["tie_word_embeddings"]), rope_theta=c["rope_theta"],
-        eps=c["rms_norm_eps"], init_std=c["initializer_range"],
-        dtype=c["torch_dtype"], reference=c["reference"])
+    arch = module(root, "arch", c["reference"])
+    module(root, "reference", c["reference"])
+    s = arch.load_shape(c, name)
+    missing = [f for f in SHAPE_FIELDS if not hasattr(s, f)]
+    if missing:
+        raise TypeError(f"{arch.__file__}: its shape lacks {missing}")
+    return s
+
+
+def arch_of(s):
+    """The architecture module that made the shape ``s``."""
+    return sys.modules[type(s).__module__]
+
+
+def reference_of(s):
+    """The plain reference of ``s``, from the root its architecture
+    module was found under."""
+    return module(Path(arch_of(s).__file__).parents[2], "reference",
+                  s.reference)
 
 
 def _key(seed: int) -> jax.Array:
@@ -62,26 +103,10 @@ def _key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def weight_shapes(s: Shape) -> dict:
-    L, d, f = s.layers, s.d_model, s.d_ff
-    shapes = {
-        "embed": (s.vocab, d),
-        "wq": (L, d, s.heads * s.d_head),
-        "wk": (L, d, s.kv_heads * s.d_head),
-        "wv": (L, d, s.kv_heads * s.d_head),
-        "wo": (L, s.heads * s.d_head, d),
-        "gate": (L, d, f), "up": (L, d, f), "down": (L, f, d),
-        "ln1": (L, d), "ln2": (L, d), "final_norm": (d,),
-    }
-    if not s.tied:
-        shapes["head"] = (d, s.vocab)
-    return shapes
-
-
-def make_weights(s: Shape, seed: int) -> dict:
+def make_weights(s, seed: int) -> dict:
     """Every weight, drawn from ``seed`` on the default device in one
     jitted call: matrices N(0, init_std), norm scales 1 + 0.1 N(0, 1)."""
-    shapes = weight_shapes(s)
+    shapes, norms = arch_of(s).weight_shapes(s)
     dtype = jnp.dtype(s.dtype)
 
     def draw(key):
@@ -89,8 +114,7 @@ def make_weights(s: Shape, seed: int) -> dict:
         for i, (name, shape) in enumerate(sorted(shapes.items())):
             z = jax.random.normal(jax.random.fold_in(key, i), shape,
                                   jnp.float32)
-            scale = z * 0.1 + 1.0 if len(shape) == 1 or name.startswith(
-                ("ln", "final")) else z * s.init_std
+            scale = z * 0.1 + 1.0 if name in norms else z * s.init_std
             out[name] = scale.astype(dtype)
         return out
 

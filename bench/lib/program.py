@@ -1,10 +1,11 @@
 """The system under test, as the benchmark drives it: the repository's
 ``ServeEngine`` built for a configuration and a mix.
 
-This is the one module that imports the program (``src/repro``).  It
-turns the benchmark's sizes into the program's ``ModelConfig``, hands
-the canonical weights over in the program's parameter layout (the same
-arrays, regrouped), and builds the engine with the mix's settings.
+This module puts the program (``src/repro``) on the path and builds the
+engine with the mix's settings.  The configuration's architecture
+module (:mod:`bench.lib.model`) turns the benchmark's sizes into the
+program's ``ModelConfig`` and hands the canonical weights over in the
+program's parameter layout (the same arrays, regrouped).
 """
 
 from __future__ import annotations
@@ -12,7 +13,7 @@ from __future__ import annotations
 import sys
 from pathlib import Path
 
-from bench.lib.model import Shape
+from bench.lib.model import arch_of
 
 CHECKOUT = Path(__file__).resolve().parents[2]
 
@@ -26,32 +27,7 @@ def import_program() -> None:
         sys.path.insert(0, str(src))
 
 
-def model_config(s: Shape):
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=s.name, n_layers=s.layers, d_model=s.d_model, n_heads=s.heads,
-        n_kv_heads=s.kv_heads, d_head=s.d_head, d_ff=s.d_ff,
-        vocab_size=s.vocab, tie_embeddings=s.tied, rope_theta=s.rope_theta,
-        norm_eps=s.eps, param_dtype=s.dtype, compute_dtype=s.dtype,
-        cache_dtype=s.dtype)
-
-
-def program_params(w: dict) -> dict:
-    """The canonical weights in the program's tree (one dense block
-    pattern, layers stacked on the leading axis)."""
-    block = {
-        "ln1": {"scale": w["ln1"]}, "ln2": {"scale": w["ln2"]},
-        "attn": {n: {"w": w[n]} for n in ("wq", "wk", "wv", "wo")},
-        "mlp": {n: {"w": w[n]} for n in ("gate", "up", "down")},
-    }
-    p = {"embed": {"table": w["embed"]},
-         "final_norm": {"scale": w["final_norm"]}, "blocks": [block]}
-    if "head" in w:
-        p["head"] = {"w": w["head"]}
-    return p
-
-
-def build_engine(s: Shape, weights: dict, engine: dict, seed: int,
+def build_engine(s, weights: dict, engine: dict, seed: int,
                  **overrides):
     """A paged, chunked-prefill ``ServeEngine`` with the mix's settings;
     ``overrides`` go to ``ServeConfig`` as they are (the output check's
@@ -62,7 +38,9 @@ def build_engine(s: Shape, weights: dict, engine: dict, seed: int,
         seed=seed & 0x7FFFFFFF, kv="paged", page_size=engine["page_size"],
         prefill_chunk=engine["prefill_chunk"],
         prefix_cache=engine["prefix_cache"], **overrides)
-    return ServeEngine(model_config(s), program_params(weights), scfg)
+    arch = arch_of(s)
+    return ServeEngine(arch.program_config(s), arch.program_params(weights),
+                       scfg)
 
 
 def set_tuning_cache(path: Path) -> None:

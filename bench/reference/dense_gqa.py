@@ -51,14 +51,14 @@ def _upcast(a, int8: bool):
     return jnp.clip(jnp.round(a / scale), -127, 127) * scale
 
 
-@functools.partial(jax.jit, static_argnames=("s", "int8"))
-def _layer(x, w, s, int8=False):
+def _attention(x, w, s):
+    """The attention branch of a layer: RMSNorm, then causal GQA over
+    the rows of ``x`` with the float32 weights ``w``."""
     t = x.shape[0]
     pos = jnp.arange(t)
     mask = pos[:, None] >= pos[None, :]
     group = s.heads // s.kv_heads
     mm = functools.partial(jnp.matmul, precision="highest")
-    w = jax.tree.map(lambda a: _upcast(a, int8), w)
     h = _rmsnorm(x, w["ln1"], s.eps)
     q = mm(h, w["wq"]).reshape(t, s.heads, s.d_head)
     k = mm(h, w["wk"]).reshape(t, s.kv_heads, s.d_head)
@@ -70,7 +70,14 @@ def _layer(x, w, s, int8=False):
     att = jnp.where(mask[None], att * s.d_head ** -0.5, -jnp.inf)
     att = jax.nn.softmax(att, axis=-1)
     o = jnp.einsum("hts,shd->thd", att, v, precision="highest")
-    x = x + mm(o.reshape(t, s.heads * s.d_head), w["wo"])
+    return mm(o.reshape(t, s.heads * s.d_head), w["wo"])
+
+
+@functools.partial(jax.jit, static_argnames=("s", "int8"))
+def _layer(x, w, s, int8=False):
+    mm = functools.partial(jnp.matmul, precision="highest")
+    w = jax.tree.map(lambda a: _upcast(a, int8), w)
+    x = x + _attention(x, w, s)
     h = _rmsnorm(x, w["ln2"], s.eps)
     return x + mm(jax.nn.silu(mm(h, w["gate"])) * mm(h, w["up"]), w["down"])
 

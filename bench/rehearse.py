@@ -47,8 +47,10 @@ def main() -> None:
     from bench.cell import load_cell
     spec = load_cell(ROOT, args.workload)
     eng = spec["mix"]["engine"]
-    shape = model.load_shape(spec["config_file"], spec["cell"]["config"])
-    cfg = program.model_config(shape)
+    shape = model.load_shape(ROOT, spec["config_file"],
+                             spec["cell"]["config"])
+    arch = model.arch_of(shape)
+    cfg = arch.program_config(shape)
     dispatch.set_cache_path(ROOT / ".cache" / "bench" / "rehearse.json")
 
     topo = topologies.get_topology_desc(platform="tpu",
@@ -60,8 +62,8 @@ def main() -> None:
             a.shape, a.dtype, sharding=chip), tree)
 
     weights = {k: jax.ShapeDtypeStruct(v, jnp.dtype(shape.dtype))
-               for k, v in model.weight_shapes(shape).items()}
-    params = on_chip(program.program_params(weights))
+               for k, v in arch.weight_shapes(shape)[0].items()}
+    params = on_chip(arch.program_params(weights))
     slots, ps = args.slots or eng["slots"], eng["page_size"]
     max_pages = -(-eng["max_len"] // ps)
     pool = on_chip(jax.eval_shape(

@@ -1,5 +1,5 @@
-"""A cell, a configuration, a mix and a per-layer metric are found by
-name from new files alone."""
+"""A cell, a configuration, an architecture, a mix and a per-layer metric
+are found by name from new files alone."""
 
 import json
 import shutil
@@ -7,8 +7,9 @@ import shutil
 import pytest
 
 from bench import cell
-from bench.lib import layers
-from bench.tests.tiny import make_root
+from bench.lib import layers, model
+from bench.tests.test_faults import SEED, altered
+from bench.tests.tiny import DATA, make_root
 
 
 def test_new_files_only(tmp_path):
@@ -73,3 +74,84 @@ def test_every_named_file_exists():
         assert (root / "bench" / "limits" / f"{w['name']}.json").is_file()
     for m in bench["per_layer"]:
         assert (root / "bench" / "metrics" / f"{m['name']}.py").is_file()
+
+
+MOE_FILES = {"moe_gqa_arch.py": "arch/moe_gqa.py",
+             "moe_gqa_reference.py": "reference/moe_gqa.py",
+             "tiny_moe_config.json": "configs/tiny_moe.json",
+             "tiny_chat.json": "traffic/tiny_moe_chat.json"}
+
+
+def snapshot(root):
+    return {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+
+
+@pytest.fixture(scope="module")
+def moe_root(tmp_path_factory):
+    """A root to which a configuration of a new architecture (dense GQA
+    attention with routed experts) is added: its architecture module,
+    reference, configuration, mix and limits as new files, and new
+    entries in BENCHMARK.json."""
+    root = make_root(tmp_path_factory.mktemp("bench"))
+    before = snapshot(root)
+    for src, dst in MOE_FILES.items():
+        shutil.copy(DATA / src, root / "bench" / dst)
+    (root / "bench" / "limits" / "tiny_moe.chat.json").write_text(
+        json.dumps({"mean_gap": {"limit": 1e-3}, "min_tokens": 1}))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    old = {k: list(v) for k, v in bench.items() if isinstance(v, list)}
+    bench["configs"].append({"name": "tiny_moe", "source": "test",
+                             "file": "bench/configs/tiny_moe.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "tiny_moe.chat", "config": "tiny_moe",
+                               "traffic": "tiny_moe_chat", "chips": 1,
+                               "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    after = snapshot(root)
+    assert {p for p in before if after[p] != before[p]} == {
+        root / "BENCHMARK.json"}
+    new = json.loads((root / "BENCHMARK.json").read_text())
+    for key, entries in old.items():
+        assert new[key][:len(entries)] == entries
+    return root
+
+
+def test_new_architecture_from_files_only(moe_root):
+    res = cell.run_cell(moe_root, "tiny_moe.chat", SEED, 1.5, False,
+                        require_chip=False)
+    assert res["correct"], res["check"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+    shape = model.load_shape(moe_root, moe_root / "bench" / "configs"
+                             / "tiny_moe.json", "tiny_moe")
+    assert (shape.experts, shape.top_k) == (8, 2)
+    assert model.arch_of(shape).__file__ == str(
+        moe_root / "bench" / "arch" / "moe_gqa.py")
+
+
+def test_new_architecture_catches_altered_token(moe_root):
+    res = cell.run_cell(moe_root, "tiny_moe.chat", SEED, 1.5, False,
+                        require_chip=False, fault=altered)
+    assert not res["correct"]
+    assert res["check"]["mean_gap"]["value"] > 1e-3
+
+
+@pytest.mark.parametrize("missing", ["arch", "reference"])
+def test_unknown_architecture_fails_before_weights(tmp_path, monkeypatch,
+                                                   missing):
+    root = make_root(tmp_path)
+    for kind in ("arch", "reference"):
+        if kind != missing:
+            shutil.copy(root / "bench" / kind / "dense_gqa.py",
+                        root / "bench" / kind / "nope.py")
+    c = json.loads((root / "bench" / "configs" / "tiny.json").read_text())
+    c["reference"] = "nope"
+    (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(c))
+
+    def drawn(*_):
+        raise AssertionError("weights drawn")
+
+    monkeypatch.setattr(model, "make_weights", drawn)
+    with pytest.raises(FileNotFoundError, match=f"bench/{missing}/nope.py"):
+        cell.run_cell(root, "tiny.chat", SEED, 1.0, False,
+                      require_chip=False)

@@ -13,11 +13,14 @@ CELLS = {"tiny.chat": "tiny_chat", "tiny.backlog": "tiny_backlog"}
 
 def make_root(tmp: Path, limit: float = 1e-3) -> Path:
     """``tmp`` laid out as a checkout: BENCHMARK.json naming the tiny
-    cells, their config, mixes and limits, and the real metric readers."""
+    cells, their config, mixes and limits, and the real metric readers,
+    architecture modules and references."""
     (tmp / "bench" / "traffic").mkdir(parents=True)
     (tmp / "bench" / "limits").mkdir()
     (tmp / "bench" / "configs").mkdir()
-    shutil.copytree(BENCH / "metrics", tmp / "bench" / "metrics")
+    for kind in ("metrics", "arch", "reference"):
+        shutil.copytree(BENCH / kind, tmp / "bench" / kind,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(DATA / "tiny_config.json", tmp / "bench" / "configs"
                 / "tiny.json")
     for cell, mix in CELLS.items():
