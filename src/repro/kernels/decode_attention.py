@@ -570,3 +570,171 @@ def flash_paged_decode(
         name=f"gama_flash_paged_decode_b{buffers}",
     )(*operands)
     return out.reshape(b, hq, rd)[..., :d]
+
+
+def latent_pages_per_block(max_pages: int, page_size: int, row_dim: int,
+                           itemsize: int) -> int:
+    """Table pages per grid step of :func:`latent_paged_decode`: 2048
+    keys, halved while its two block slots would take more than 12 MiB
+    of VMEM, and never more than the table holds.
+
+    >>> latent_pages_per_block(128, 16, 576, 2)    # DeepSeek-V2-Lite
+    128
+    >>> latent_pages_per_block(3, 16, 576, 2)
+    3
+    """
+    ppb = max(1, _PAGED_BLOCK_KEYS // page_size)
+    while (ppb > 1
+           and 2 * ppb * page_size * row_dim * itemsize > _PAGED_BLOCK_VMEM):
+        ppb //= 2
+    return min(ppb, max_pages)
+
+
+def _latent_decode_kernel(bt_ref, len_ref, q_ref, pages_hbm, o_ref,
+                          m_ref, l_ref, acc_ref, buf, sem, *,
+                          batch: int, n_blocks: int, ppb: int,
+                          page_size: int, value_dim: int, scale: float):
+    """One grid step = one block of ``ppb`` table pages of one slot,
+    every query head against each latent row.
+
+    The pool stays in HBM; each live page is one DMA into one of two
+    VMEM block slots.  The slots alternate over the whole grid, not
+    per slot: the step before copies the next step's block (the next
+    block of this slot, or the first block of the next slot), so a
+    slot's one block at serving lengths still overlaps the slot before
+    it.  A page is live while ``i * page_size < length``; the start and
+    the wait of a copy share that predicate."""
+    bb = pl.program_id(0)
+    j = pl.program_id(1)
+    step = bb * n_blocks + j
+    slot = jax.lax.rem(step, 2)
+    bk = ppb * page_size
+    length = len_ref[bb]
+
+    def gather(b_, blk, buf_slot, start: bool):
+        live_pages = (len_ref[b_] + page_size - 1) // page_size
+        first = blk * ppb
+
+        def page_copy(i, carry):
+            copy = pltpu.make_async_copy(
+                pages_hbm.at[bt_ref[b_, first + i]], buf.at[buf_slot, i],
+                sem.at[buf_slot])
+            if start:
+                copy.start()
+            else:
+                copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(live_pages - first, 0, ppb),
+                          page_copy, 0)
+
+    @pl.when(step == 0)
+    def _warm_up():
+        # Rows of a skipped page meet p = 0 in the PV matmul; zeroing
+        # the never-written slots keeps them finite (0 * NaN = NaN).
+        buf[...] = jnp.zeros_like(buf)
+        gather(0, 0, 0, start=True)
+
+    last = j + 1 == n_blocks
+    nb = jnp.where(last, bb + 1, bb)
+    nj = jnp.where(last, 0, j + 1)
+
+    @pl.when(nb < batch)
+    def _prefetch():
+        gather(nb, nj, 1 - slot, start=True)
+
+    gather(bb, j, slot, start=False)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * bk < length)
+    def _body():
+        m, l, acc = _online_softmax_step(
+            q_ref[0], buf[slot], buf[slot, :, :, :, :value_dim],
+            m_ref[...][:, :1], l_ref[...][:, :1], acc_ref[...],
+            k_block_start=j * bk, length=length, scale=scale)
+        acc_ref[...] = acc
+        m_ref[...] = jnp.broadcast_to(m, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l, l_ref.shape)
+
+    @pl.when(j == n_blocks - 1)
+    def _done():
+        l = l_ref[...][:, :1]
+        safe_l = jnp.where(l > 0.0, l, 1.0)
+        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
+
+
+def latent_paged_decode(
+    q: jax.Array,
+    pages: jax.Array,
+    block_tables: jax.Array,
+    *,
+    length: jax.Array,
+    value_dim: int,
+    scale: float,
+    pages_per_block: Optional[int] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Paged decode over a latent KV cache (multi-head latent
+    attention, absorbed form).  q: (B, H, W) absorbed queries; pages:
+    (P, 1, page_size, W), one latent row per key (P includes the null
+    sink page); block_tables: (B, max_pages) int32; length: (B,) int32.
+    Returns (B, H, value_dim).
+
+    Every query head attends over the same rows: the logits are q . row
+    over all W lanes, the values are the rows' first ``value_dim``
+    lanes.  So this is a multi-query attention whose keys and values
+    share one pool and one DMA per page.  The grid is (B, n_blocks),
+    one block of ``pages_per_block`` table pages per step (default
+    :func:`latent_pages_per_block`), only the live pages copied, as in
+    :func:`flash_paged_decode` with ``buffers=2``."""
+    b, h, w = q.shape
+    _, one, page_size, rw = pages.shape
+    _, n_pages = block_tables.shape
+    if one != 1 or rw != w:
+        raise ValueError(f"latent pages must be (P, 1, page_size, {w}), "
+                         f"got {pages.shape}")
+    if not 0 < value_dim <= w:
+        raise ValueError(f"value_dim {value_dim} outside the {w}-lane rows")
+    ppb = pages_per_block or latent_pages_per_block(
+        n_pages, page_size, w, pages.dtype.itemsize)
+    n_blocks = -(-n_pages // ppb)
+    block_tables = block_tables.astype(jnp.int32)
+    if n_blocks * ppb != n_pages:
+        # Entries past the table lie past every length: never read.
+        block_tables = jnp.pad(block_tables,
+                               ((0, 0), (0, n_blocks * ppb - n_pages)))
+    length = jnp.minimum(length.reshape(b).astype(jnp.int32),
+                         n_pages * page_size)
+    kernel = functools.partial(
+        _latent_decode_kernel, batch=b, n_blocks=n_blocks, ppb=ppb,
+        page_size=page_size, value_dim=value_dim, scale=scale)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_blocks),
+            in_specs=[pl.BlockSpec((1, h, w),
+                                   lambda bb, j, bt, ln: (bb, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, h, value_dim),
+                                   lambda bb, j, bt, ln: (bb, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((h, _LANES), jnp.float32),
+                pltpu.VMEM((h, _LANES), jnp.float32),
+                pltpu.VMEM((h, value_dim), jnp.float32),
+                pltpu.VMEM((2, ppb, 1, page_size, w), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="gama_latent_paged_decode",
+    )(block_tables, length, q, pages)
+    return out

@@ -34,7 +34,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import (flash_decode, flash_paged_decode,
+                                           latent_paged_decode,
                                            paged_pages_per_block)
+from repro.kernels.expert_gmm import ROW_TILE, expert_gmm as _expert_gmm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gemm import gama_gemm
 from repro.kernels.wkv import wkv6
@@ -360,6 +362,55 @@ def decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array, *,
     if gp != group:
         out = out.reshape(b, hkv, gp, d)[:, :, :group].reshape(b, hq, d)
     return out
+
+
+def decode_latent_paged(q: jax.Array, pages: jax.Array, *,
+                        block_tables: jax.Array, length: jax.Array,
+                        value_dim: int, scale: float,
+                        mode: Mode = "auto") -> jax.Array:
+    """Single-token decode over a **latent** paged cache (multi-head
+    latent attention, absorbed): q (B, H, D) absorbed queries; pages
+    (P, 1, page_size, R) latent rows, the D lanes of a row in its first
+    D of R = ``page_row_dim(D)``; values are a row's first
+    ``value_dim`` lanes.  Returns (B, H, value_dim).  The kernel path
+    is ``gama_latent_paged_decode``."""
+    b = q.shape[0]
+    if block_tables.shape[0] != b or block_tables.ndim != 2:
+        raise ValueError(f"block_tables must be (B={b}, max_pages), got "
+                         f"{block_tables.shape}")
+    length = jnp.minimum(jnp.asarray(length, jnp.int32),
+                         block_tables.shape[1] * pages.shape[2])
+    block_tables = jnp.asarray(block_tables, jnp.int32)
+    if not _use_kernel(mode):
+        _obs_count("ops.decode_latent_paged.ref")
+        return ref.ref_latent_paged_decode(
+            q, pages, block_tables, length=length, value_dim=value_dim,
+            scale=scale)
+    _obs_count("ops.decode_latent_paged.kernel")
+    qp = to_page_rows(q, pages.shape[-1])
+    return _on_each_device(
+        lambda q_, p_, bt, ln: latent_paged_decode(
+            q_, p_, bt, length=ln, value_dim=value_dim, scale=scale,
+            interpret=_interpret()))(qp, pages, block_tables, length)
+
+
+def expert_gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
+               mode: Mode = "auto") -> jax.Array:
+    """Grouped matmul over held experts: x (M, K) rows sorted by expert,
+    w (E, K, N), group_sizes (E,) rows per expert.  Returns (M, N); rows
+    past the last group are zero on the ref path and unwritten on the
+    kernel path (``gama_expert_gmm``), so callers mask them."""
+    if not _use_kernel(mode):
+        _obs_count("ops.expert_gmm.ref")
+        return ref.ref_expert_gmm(x, w, group_sizes)
+    _obs_count("ops.expert_gmm.kernel")
+    m = x.shape[0]
+    mp = _round_up(m, ROW_TILE)
+    xp = jnp.pad(x, ((0, mp - m), (0, 0)))
+    out = _on_each_device(functools.partial(
+        _expert_gmm, interpret=_interpret()))(xp, w.astype(x.dtype),
+                                              group_sizes)
+    return out[:m]
 
 
 def wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,

@@ -221,6 +221,32 @@ def ref_paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     return ref_decode_attention(q, kc, vc, length=length, scale=scale)
 
 
+def ref_latent_paged_decode(q: jax.Array, pages: jax.Array,
+                            block_tables: jax.Array, *, length: jax.Array,
+                            value_dim: int, scale: float) -> jax.Array:
+    """Oracle for latent_paged_decode: gather the latent rows
+    contiguous, then the dense decode oracle with the rows as keys (all
+    the query's lanes) and their first ``value_dim`` lanes as values."""
+    rows = gather_pages(pages, block_tables)[..., :q.shape[-1]]
+    return ref_decode_attention(q, rows, rows[..., :value_dim],
+                                length=length, scale=scale)
+
+
+def ref_expert_gmm(x: jax.Array, w: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """Oracle for expert_gmm: rows of ``x`` (M, K) grouped in order by
+    ``group_sizes`` (E,), each group times its expert's ``w`` (E, K, N);
+    rows past the last group are zero."""
+    ends = jnp.cumsum(group_sizes)
+    row = jnp.arange(x.shape[0])
+    group = jnp.searchsorted(ends, row, side="right")
+    every = jnp.einsum("mk,ekn->emn", x.astype(jnp.float32),
+                       w.astype(jnp.float32))
+    pick = group[None, :] == jnp.arange(w.shape[0])[:, None]   # (E, M)
+    return jnp.sum(jnp.where(pick[..., None], every, 0.0),
+                   axis=0).astype(x.dtype)
+
+
 def ref_wkv(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             u: jax.Array,
             state: Optional[jax.Array] = None) -> jax.Array:

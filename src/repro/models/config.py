@@ -3,8 +3,12 @@
 A model is a stack of `n_layers` blocks cycling through `pattern` (a tuple
 of BlockSpec): dense transformers use a single ("attn","dense") entry;
 MoE models ("attn","moe"); RWKV ("rwkv","rwkv_cm"); Jamba an 8-entry
-hybrid pattern.  Encoder-decoder models add an encoder stack and give
-decoder blocks cross-attention.
+hybrid pattern.  `first_dense_layers` leading blocks (the pattern's
+mixer with a dense FFN of `d_ff`) may come before the cycled ones, as
+DeepSeek's first_k_dense_replace.  Latent attention
+(DeepSeek-V2's MLA) is the mixer "mla" with an `MLAConfig`.
+Encoder-decoder models add an encoder stack and give decoder blocks
+cross-attention.
 """
 
 from __future__ import annotations
@@ -14,14 +18,16 @@ from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
+from repro.models.layers import YarnScaling
 from repro.models.mamba import MambaConfig
+from repro.models.mla import MLAConfig
 from repro.models.moe import MoEConfig
 from repro.models.rwkv import RwkvConfig
 
 
 @dataclasses.dataclass(frozen=True)
 class BlockSpec:
-    mixer: str = "attn"      # "attn" | "mamba" | "rwkv"
+    mixer: str = "attn"      # "attn" | "mla" | "mamba" | "rwkv"
     ffn: str = "dense"       # "dense" | "moe" | "rwkv_cm" | "none"
 
 
@@ -41,10 +47,16 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     mamba: Optional[MambaConfig] = None
     rwkv: Optional[RwkvConfig] = None
+    mla: Optional[MLAConfig] = None
+
+    # Leading blocks before the cycled pattern: the pattern's first
+    # mixer with a dense FFN of d_ff.
+    first_dense_layers: int = 0
 
     # Attention details.
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None     # read by the mla mixer
     mrope_sections: Optional[Tuple[int, int, int]] = None
     causal: bool = True
 
@@ -72,10 +84,16 @@ class ModelConfig:
 
     @property
     def n_groups(self) -> int:
-        assert self.n_layers % len(self.pattern) == 0, (
-            f"{self.name}: n_layers {self.n_layers} not divisible by "
+        cycled = self.n_layers - self.first_dense_layers
+        assert cycled % len(self.pattern) == 0, (
+            f"{self.name}: {cycled} cycled layers not divisible by "
             f"pattern period {len(self.pattern)}")
-        return self.n_layers // len(self.pattern)
+        return cycled // len(self.pattern)
+
+    @property
+    def lead_spec(self) -> BlockSpec:
+        """The block of the leading dense layers."""
+        return BlockSpec(self.pattern[0].mixer, "dense")
 
     @property
     def pdtype(self):
@@ -91,9 +109,18 @@ class ModelConfig:
         total = v * d  # embedding
         if not self.tie_embeddings:
             total += v * d
-        for spec in self.pattern:
+        specs = [(spec, self.n_groups) for spec in self.pattern]
+        if self.first_dense_layers:
+            specs.append((self.lead_spec, self.first_dense_layers))
+        for spec, count in specs:
             n = 0
-            if spec.mixer == "attn":
+            if spec.mixer == "mla":
+                m = self.mla
+                n += d * (self.n_heads * m.qk_dim + m.latent_dim)
+                n += m.kv_lora_rank * self.n_heads * (m.qk_nope_dim
+                                                      + m.v_head_dim)
+                n += self.n_heads * m.v_head_dim * d
+            elif spec.mixer == "attn":
                 n += d * (self.n_heads + 2 * self.n_kv_heads) * self.d_head
                 n += self.n_heads * self.d_head * d
             elif spec.mixer == "mamba":
@@ -113,13 +140,13 @@ class ModelConfig:
             elif spec.ffn == "moe":
                 assert self.moe is not None
                 n += d * self.moe.num_experts
-                n += 3 * d * self.moe.d_ff * self.moe.num_experts
+                n += 3 * d * self.moe.d_ff * self.moe.n_held
                 if self.moe.n_shared:
                     sf = self.moe.shared_d_ff or self.moe.d_ff * self.moe.n_shared
                     n += 3 * d * sf
             elif spec.ffn == "rwkv_cm":
                 n += 2 * d * f + d * d
-            total += n * self.n_groups
+            total += n * count
         if self.encoder_decoder:
             # Encoder layers (attn+dense ffn) + decoder cross-attn.
             enc = self.n_encoder_layers * (
@@ -139,7 +166,7 @@ class ModelConfig:
         moe_layers = sum(1 for s in self.pattern if s.ffn == "moe") \
             * self.n_groups
         all_experts = 3 * self.d_model * self.moe.d_ff \
-            * self.moe.num_experts * moe_layers
+            * self.moe.n_held * moe_layers
         active_experts = 3 * self.d_model * self.moe.d_ff \
             * self.moe.top_k * moe_layers
         return full - all_experts + active_experts

@@ -9,6 +9,7 @@ smoke tests and the dry-run) — see kernels/ops.py.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -149,6 +150,72 @@ def mrope_angles(positions: jax.Array, d_head: int,
                         total_repeat_length=half)
     pos_sel = positions[..., select]            # (..., S, half)
     return pos_sel.astype(jnp.float32) * inv_freq
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling, as DeepseekV2YarnRotaryEmbedding defines it
+    (the published ``rope_scaling`` of type "yarn")."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """0.1 * mscale * ln(factor) + 1 (1 for factor <= 1).
+
+    >>> round(yarn_mscale(40, 0.707), 5)
+    1.2608
+    """
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(y: YarnScaling, dim: int,
+                          theta: float) -> Tuple[int, int]:
+    """The rotary dims between which YaRN ramps from extrapolation
+    (fast rotations) to interpolation.
+
+    >>> yarn_correction_range(YarnScaling(40, 4096), 64, 10000.0)
+    (10, 23)
+    """
+    def dim_of(rotations):
+        return (dim * math.log(y.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(dim_of(y.beta_fast)), 0),
+            min(math.ceil(dim_of(y.beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, theta: float, y: YarnScaling) -> jax.Array:
+    """(dim // 2,) inverse frequencies: the interpolated ones (divided
+    by ``factor``) below the correction range, the original ones above
+    it, a linear ramp between."""
+    base = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    extra, inter = 1.0 / base, 1.0 / (y.factor * base)
+    low, high = yarn_correction_range(y, dim, theta)
+    high = high + 0.001 if low == high else high
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return inter * (1.0 - keep) + extra * keep
+
+
+def yarn_softmax_scale(y: Optional[YarnScaling]) -> float:
+    """The factor YaRN puts on the softmax scale: mscale(factor,
+    mscale_all_dim) squared, where ``mscale_all_dim`` is set."""
+    if y is None or not y.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+
+
+def yarn_cos_scale(y: YarnScaling) -> float:
+    """The factor on cos and sin: mscale(factor, mscale) /
+    mscale(factor, mscale_all_dim)."""
+    return (yarn_mscale(y.factor, y.mscale)
+            / yarn_mscale(y.factor, y.mscale_all_dim))
 
 
 # RoPE application dtype: "float32" (default, max accuracy) or "compute"

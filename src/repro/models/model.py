@@ -40,6 +40,9 @@ def init_params(rng, cfg: ModelConfig) -> Params:
         "blocks": T.init_stack(r_blocks, cfg,
                                cross_attn=cfg.encoder_decoder),
     }
+    if cfg.first_dense_layers:
+        p["lead"] = T.init_lead(
+            jax.random.fold_in(r_blocks, len(cfg.pattern)), cfg)
     if cfg.norm == "layernorm":
         p["ln0"] = L.layernorm_init(cfg.d_model, cfg.pdtype)  # rwkv style
     if not cfg.tie_embeddings:
@@ -109,7 +112,7 @@ def _encode(params: Params, batch: Batch, cfg: ModelConfig,
     x = batch["enc_embeds"].astype(cfg.cdtype)
     pos = jnp.broadcast_to(
         jnp.arange(x.shape[1], dtype=jnp.int32)[None], x.shape[:2])
-    x, _, _ = T.apply_stack(params["encoder"]["blocks"], x, enc_cfg,
+    x, _, _, _ = T.apply_stack(params["encoder"]["blocks"], x, enc_cfg,
                             positions=pos, remat=remat)
     if cfg.norm == "layernorm":
         return L.layernorm(params["encoder"]["final_norm"], x, cfg.norm_eps)
@@ -122,24 +125,31 @@ def forward(params: Params, batch: Batch, cfg: ModelConfig, *,
             block_tables: Optional[jax.Array] = None,
             decode: bool = False,
             remat: bool = False,
-            remat_policy: str = "full"
-            ) -> Tuple[jax.Array, Optional[List], jax.Array]:
-    """Returns (logits (B,S,V) f32, new_caches, aux_loss)."""
+            remat_policy: str = "full",
+            held_counts: bool = False,
+            ) -> Tuple:
+    """Returns (logits (B,S,V) f32, new_caches, aux_loss), and with
+    ``held_counts`` a fourth element: summed over the held-expert
+    layers, the (token, expert) pairs they served and the held experts
+    that got any (int32 (2,); needs ``cfg.moe.held``)."""
     x = _input_embed(params, batch, cfg)
     s = x.shape[1]
     offset = 0 if cache_pos is None else cache_pos
     pos = _positions(batch, cfg, s, offset)
     # Decode reuses the prefill-time cross-attention cache; no re-encode.
     enc_out = None if decode else _encode(params, batch, cfg, remat)
-    x, new_caches, aux = T.apply_stack(
+    x, new_caches, aux, held = T.apply_stack(
         params["blocks"], x, cfg, positions=pos, caches=caches,
         cache_pos=cache_pos, block_tables=block_tables, enc_out=enc_out,
-        decode=decode, remat=remat, remat_policy=remat_policy)
+        decode=decode, remat=remat, remat_policy=remat_policy,
+        lead=params.get("lead"))
     if cfg.norm == "layernorm":
         x = L.layernorm(params["final_norm"], x, cfg.norm_eps)
     else:
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     lg = L.logits(params["embed"], x, params.get("head"))
+    if held_counts:
+        return lg, new_caches, aux, held
     return lg, new_caches, aux
 
 
@@ -167,17 +177,20 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 
 def paged_eligible(cfg: ModelConfig) -> bool:
     """True when the arch can decode through the paged KV pool: every
-    mixer is attention (recurrent mamba/rwkv state is fixed-size per
-    slot — nothing to page) and there is no enc-dec cross cache."""
+    mixer is attention, GQA or latent (recurrent mamba/rwkv state is
+    fixed-size per slot — nothing to page) and there is no enc-dec
+    cross cache."""
     return (not cfg.encoder_decoder
-            and all(spec.mixer == "attn" for spec in cfg.pattern))
+            and all(spec.mixer in ("attn", "mla") for spec in cfg.pattern))
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int,
                      kv_dtype: Optional[str] = None) -> List:
     """Paged-KV cache stack (``repro.serving.kvpool``): per attention
     layer, a (num_pages + 1, Hkv, page_size, D) page pool — the extra
-    row is the null sink unallocated block-table entries point at.
+    row is the null sink unallocated block-table entries point at.  A
+    latent-attention layer holds one (num_pages + 1, 1, page_size, R)
+    pool of latent rows instead (``models/mla.py``).
     ``kv_dtype`` overrides the page dtype (``"int8"`` adds per-row
     scale-row arrays; see ``attention.init_paged_kv_cache``)."""
     if not paged_eligible(cfg):
@@ -199,8 +212,8 @@ def prefill(params: Params, batch: Batch, cfg: ModelConfig,
 def decode_step(params: Params, token: jax.Array, pos: jax.Array,
                 cfg: ModelConfig, caches: List,
                 embeds: Optional[jax.Array] = None,
-                block_tables: Optional[jax.Array] = None
-                ) -> Tuple[jax.Array, List]:
+                block_tables: Optional[jax.Array] = None,
+                held_counts: bool = False) -> Tuple:
     """One token (B,) at position `pos`; returns (logits, caches).
 
     ``pos`` is either a scalar (uniform batch — every sequence sits at
@@ -209,14 +222,15 @@ def decode_step(params: Params, token: jax.Array, pos: jax.Array,
     writes its KV at its own offset and attends only to its own valid
     prefix).  With a paged cache (``init_paged_cache``),
     ``block_tables`` (B, max_pages) maps each slot's positions onto
-    pool pages; ``pos`` must then be the per-slot vector form.
+    pool pages; ``pos`` must then be the per-slot vector form.  With
+    ``held_counts`` a third element is returned, as :func:`forward`'s.
     """
     batch: Batch = {}
     if embeds is not None:
         batch["embeds"] = embeds[:, None]
     else:
         batch["tokens"] = token[:, None]
-    lg, new_caches, _ = forward(params, batch, cfg, caches=caches,
-                                cache_pos=pos, block_tables=block_tables,
-                                decode=True)
-    return lg[:, 0], new_caches
+    out = forward(params, batch, cfg, caches=caches, cache_pos=pos,
+                  block_tables=block_tables, decode=True,
+                  held_counts=held_counts)
+    return (out[0][:, 0], out[1]) + tuple(out[3:])

@@ -10,6 +10,13 @@ Supports top-1 (llama4-maverick / Switch) through top-8 (kimi-k2), a
 shared-expert branch (DeepSeek/Kimi style), and a load-balancing auxiliary
 loss.  Dropped tokens (over capacity) fall through via the residual
 connection, as in GShard.
+
+:func:`moe_ffn_held` is the serving layer of one chip's share of the
+experts (``MoEConfig.held``): it routes every token over all the
+experts, drops nothing, and computes the part of the routed sum that the
+held experts give, with one grouped matmul per projection over them
+(``gama_expert_gmm``).  What the experts on other chips would add is
+left out; on one chip there is no exchange.
 """
 
 from __future__ import annotations
@@ -20,6 +27,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.serving.quant import maybe_dequant
 
@@ -48,14 +56,27 @@ class MoEConfig:
     # the dispatch scatter group-local — the §Perf hillclimb lever for the
     # MoE architectures.  1 = the paper-faithful global dispatch.
     dispatch_groups: int = 1
+    # The experts this chip holds, (first, count) of num_experts: the
+    # dropless share layer (moe_ffn_held).  None: every expert, through
+    # the capacity layer (moe_ffn).
+    held: Optional[Tuple[int, int]] = None
+    # Renormalise the top-k gates to sum to 1 (Mixtral, the capacity
+    # layer's historical behaviour); DeepSeek-V2 keeps the raw softmax.
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.num_experts if self.held is None else self.held[1]
 
 
 def init_moe(rng, d_model: int, cfg: MoEConfig, dtype=jnp.float32) -> Params:
     rr, rg, ru, rd, rs = jax.random.split(rng, 5)
-    e, f = cfg.num_experts, cfg.d_ff
+    e, f = cfg.n_held, cfg.d_ff
     scale = (1.0 / d_model) ** 0.5
     p = {
-        "router": L.dense_init(rr, d_model, e, jnp.float32),
+        "router": L.dense_init(rr, d_model, cfg.num_experts, jnp.float32),
         "gate": jax.random.normal(rg, (e, d_model, f), dtype) * scale,
         "up": jax.random.normal(ru, (e, d_model, f), dtype) * scale,
         "down": jax.random.normal(rd, (e, f, d_model), dtype)
@@ -90,8 +111,11 @@ def moe_ffn(p: Params, x: jax.Array, cfg: MoEConfig
                             xg.astype(jnp.float32))          # (G, Tg, E)
     probs = jax.nn.softmax(router_logits, axis=-1)
     gate_vals, idx = jax.lax.top_k(probs, k)                 # (G, Tg, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling_factor != 1.0:
+        gate_vals = gate_vals * cfg.routed_scaling_factor
 
     # Position of each (token, slot) within its expert's (per-group)
     # capacity buffer: cumsum over the group's token axis.
@@ -160,3 +184,61 @@ def moe_ffn(p: Params, x: jax.Array, cfg: MoEConfig
     aux = cfg.aux_loss_weight * e * jnp.sum(me * pe)
 
     return out.reshape(b, s, d), aux
+
+
+def route(p: Params, xt: jax.Array, cfg: MoEConfig
+          ) -> Tuple[jax.Array, jax.Array]:
+    """Gates and experts of each token row of ``xt`` (T, d): the f32
+    softmax over every expert, its ``top_k`` largest (greedy), those
+    renormalised where ``norm_topk_prob``, times the routed scaling
+    factor.  Returns ((T, k) f32 gates, (T, k) int32 expert ids)."""
+    probs = jax.nn.softmax(L.dense(p["router"], xt.astype(jnp.float32)),
+                           axis=-1)
+    gates, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    return gates * cfg.routed_scaling_factor, idx.astype(jnp.int32)
+
+
+def moe_ffn_held(p: Params, x: jax.Array, cfg: MoEConfig
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the routed sum, plus the shared
+    experts.  x: (B, S, d) -> (out, counts): ``counts`` is an int32
+    pair, the (token, expert) pairs served here and the held experts
+    that got any (the grouped matmul reads those experts' weights).
+
+    Every (token, expert) pair whose expert is held is served: the
+    pairs are sorted by expert (the others after them), their rows
+    gathered, and each held expert's SwiGLU runs over its own rows as
+    one grouped matmul per projection; the gate-weighted rows are added
+    back to their tokens.  There is no capacity, so none is dropped."""
+    b, s, d = x.shape
+    t, k = b * s, cfg.top_k
+    first, n_held = cfg.held
+    xt = x.reshape(t, d)
+    with jax.named_scope("moe.route"):
+        gates, idx = route(p, xt, cfg)
+    with jax.named_scope("moe.dispatch"):
+        local = idx.reshape(t * k) - first
+        held = (local >= 0) & (local < n_held)
+        key = jnp.where(held, local, n_held)
+        order = jnp.argsort(key, stable=True)
+        token = (jnp.arange(t * k, dtype=jnp.int32) // k)[order]
+        sizes = jnp.zeros((n_held,), jnp.int32).at[key].add(1, mode="drop")
+        rows = xt[token]
+    dt = x.dtype
+    g = kops.expert_gmm(rows, maybe_dequant(p["gate"], dt), sizes)
+    u = kops.expert_gmm(rows, maybe_dequant(p["up"], dt), sizes)
+    y = kops.expert_gmm(jax.nn.silu(g) * u, maybe_dequant(p["down"], dt),
+                        sizes)
+    with jax.named_scope("moe.combine"):
+        # Rows past the held pairs hold nothing a kernel wrote: select,
+        # never multiply, them away.
+        part = jnp.where(held[order][:, None],
+                         y.astype(jnp.float32)
+                         * gates.reshape(t * k)[order][:, None], 0.0)
+        out = jnp.zeros((t, d), jnp.float32).at[token].add(part).astype(dt)
+    if cfg.n_shared:
+        out = out + L.mlp(p["shared"], xt, "swiglu")
+    return out.reshape(b, s, d), jnp.stack([jnp.sum(sizes),
+                                            jnp.sum(sizes > 0)])

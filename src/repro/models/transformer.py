@@ -3,7 +3,9 @@
 Layers are stacked per pattern position and iterated with ``lax.scan``
 (one compiled block group regardless of depth — essential for compiling
 80-layer configs in the dry-run).  Caches are stacked the same way and
-threaded through the scan.
+threaded through the scan.  ``cfg.first_dense_layers`` leading blocks
+are a stack of their own (``params["lead"]``, the first cache entry),
+scanned before the pattern.
 """
 
 from __future__ import annotations
@@ -18,6 +20,7 @@ from jax.ad_checkpoint import checkpoint_name
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import mamba as M
+from repro.models import mla as MLA
 from repro.models import moe as MOE
 from repro.models import rwkv as R
 from repro.models.config import BlockSpec, ModelConfig
@@ -47,7 +50,10 @@ def init_block(rng, cfg: ModelConfig, spec: BlockSpec,
                cross_attn: bool = False) -> Params:
     keys = jax.random.split(rng, 6)
     p: Params = {"ln1": _norm_init(cfg)}
-    if spec.mixer == "attn":
+    if spec.mixer == "mla":
+        p["mla"] = MLA.init_mla(keys[0], cfg.d_model, cfg.n_heads, cfg.mla,
+                                cfg.pdtype)
+    elif spec.mixer == "attn":
         p["attn"] = A.init_attention(
             keys[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head,
             cfg.qk_norm, cfg.pdtype)
@@ -91,7 +97,17 @@ def init_block_cache(cfg: ModelConfig, spec: BlockSpec, batch: int,
     is fixed-size per slot and has no paged form; enc-dec cross caches
     are likewise dense-only."""
     c: Cache = {}
-    if spec.mixer == "attn":
+    if spec.mixer == "mla":
+        if cross_len:
+            raise NotImplementedError("latent attention has no cross cache")
+        dt = jnp.dtype(cfg.cache_dtype)
+        if paged is not None:
+            c["mla"] = MLA.init_paged_mla_cache(
+                paged[0], paged[1], cfg.mla, dt,
+                kv_dtype=paged[2] if len(paged) > 2 else None)
+        else:
+            c["mla"] = MLA.init_mla_cache(batch, max_len, cfg.mla, dt)
+    elif spec.mixer == "attn":
         if paged is not None:
             if cross_len:
                 raise NotImplementedError(
@@ -130,13 +146,24 @@ def apply_block(
     block_tables: Optional[jax.Array] = None,
     enc_out: Optional[jax.Array] = None,
     decode: bool = False,
-) -> Tuple[jax.Array, Optional[Cache], jax.Array]:
-    """Returns (x, new_cache, aux_loss)."""
+) -> Tuple[jax.Array, Optional[Cache], jax.Array, Optional[jax.Array]]:
+    """Returns (x, new_cache, aux_loss, held counts): the last is a
+    held-expert layer's (pairs served, experts reached), as
+    :func:`~repro.models.moe.moe_ffn_held` gives them (None for any
+    other block)."""
     aux = jnp.zeros((), jnp.float32)
+    held = None
     new_cache: Cache = {} if cache is not None else None
 
     h = _norm(cfg, p["ln1"], x)
-    if spec.mixer == "attn":
+    if spec.mixer == "mla":
+        out, nc = MLA.mla_attention(
+            p["mla"], h, cfg, positions=positions,
+            cache=None if cache is None else cache.get("mla"),
+            cache_pos=cache_pos, block_tables=block_tables)
+        if new_cache is not None and nc is not None:
+            new_cache["mla"] = nc
+    elif spec.mixer == "attn":
         out, nc = A.attention(
             p["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
             d_head=cfg.d_head, positions=positions,
@@ -181,6 +208,9 @@ def apply_block(
         if spec.ffn == "dense":
             x = x + checkpoint_name(L.mlp(p["mlp"], h, cfg.ffn_kind),
                                     "tp_out")
+        elif spec.ffn == "moe" and cfg.moe.held is not None:
+            out, held = MOE.moe_ffn_held(p["moe"], h, cfg.moe)
+            x = x + checkpoint_name(out, "tp_out")
         elif spec.ffn == "moe":
             out, aux = MOE.moe_ffn(p["moe"], h, cfg.moe)
             x = x + checkpoint_name(out, "tp_out")
@@ -195,7 +225,7 @@ def apply_block(
                 new_cache["rwkv"] = merged
             x = x + out
     x = L.shard_hint(x, "residual")
-    return x, new_cache, aux
+    return x, new_cache, aux, held
 
 
 # ---------------------------------------------------------------------------
@@ -214,15 +244,25 @@ def init_stack(rng, cfg: ModelConfig, cross_attn: bool = False
     return stacks
 
 
+def init_lead(rng, cfg: ModelConfig) -> List[Params]:
+    """The leading dense blocks, stacked over ``first_dense_layers``."""
+    rngs = jax.random.split(rng, cfg.first_dense_layers)
+    return [jax.vmap(lambda r: init_block(r, cfg, cfg.lead_spec))(rngs)]
+
+
 def init_stack_cache(cfg: ModelConfig, batch: int, max_len: int,
                      cross_len: int = 0,
                      paged: Optional[Tuple] = None) -> List[Cache]:
+    """Per pattern position, stacked as the params; the leading dense
+    blocks' stack, where there are any, comes first."""
     caches = []
-    for spec in cfg.pattern:
+    lead = [(cfg.lead_spec, cfg.first_dense_layers)] \
+        if cfg.first_dense_layers else []
+    for spec, n in lead + [(spec, cfg.n_groups) for spec in cfg.pattern]:
         one = init_block_cache(cfg, spec, batch, max_len, cross_len,
                                paged=paged)
         caches.append(jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (cfg.n_groups,) + x.shape), one))
+            lambda x, n=n: jnp.broadcast_to(x, (n,) + x.shape), one))
     return caches
 
 
@@ -239,8 +279,14 @@ def apply_stack(
     decode: bool = False,
     remat: bool = False,
     remat_policy: str = "full",
-) -> Tuple[jax.Array, Optional[List[Cache]], jax.Array]:
-    """Scan the group over n_groups.  Returns (x, new_caches, aux_sum).
+    lead: Optional[List[Params]] = None,
+) -> Tuple[jax.Array, Optional[List[Cache]], jax.Array,
+           Optional[jax.Array]]:
+    """Scan the group over n_groups, after the ``lead`` stack of leading
+    dense blocks where there is one (its cache is ``caches[0]``).
+    Returns (x, new_caches, aux_sum, held): ``held`` sums the
+    held-expert layers' (pairs served, experts reached) (None without
+    them).
 
     remat_policy: "full" saves nothing (max recompute — the backward
     re-executes the forward *including its partial-sum all-reduces*);
@@ -248,42 +294,63 @@ def apply_stack(
     so the collective results survive to the backward — the §Perf lever
     that removes the remat-duplicated collectives.
     """
+    track = cfg.moe is not None and cfg.moe.held is not None
 
-    def group_fn(carry, xs):
-        x, aux = carry
-        params_g = xs["params"]
-        caches_g = xs.get("cache")
-        new_caches_g = [] if caches_g is not None else None
-        for i, spec in enumerate(cfg.pattern):
-            c = None if caches_g is None else caches_g[i]
-            x, nc, a = apply_block(
-                params_g[i], x, cfg, spec, positions=positions, cache=c,
-                cache_pos=cache_pos, block_tables=block_tables,
-                enc_out=enc_out, decode=decode)
-            aux = aux + a
-            if new_caches_g is not None:
-                new_caches_g.append(nc if nc else c)
-        out = {"cache": new_caches_g} if new_caches_g is not None else {}
-        return (x, aux), out
+    def scan(stacks_, specs, caches_, carry):
+        def group_fn(carry, xs):
+            x, aux = carry[:2]
+            held = carry[2:]
+            params_g = xs["params"]
+            caches_g = xs.get("cache")
+            new_caches_g = [] if caches_g is not None else None
+            for i, spec in enumerate(specs):
+                c = None if caches_g is None else caches_g[i]
+                x, nc, a, h = apply_block(
+                    params_g[i], x, cfg, spec, positions=positions,
+                    cache=c, cache_pos=cache_pos,
+                    block_tables=block_tables, enc_out=enc_out,
+                    decode=decode)
+                aux = aux + a
+                if h is not None:
+                    held = (held[0] + h,)
+                if new_caches_g is not None:
+                    new_caches_g.append(nc if nc else c)
+            out = {"cache": new_caches_g} if new_caches_g is not None \
+                else {}
+            return (x, aux) + held, out
 
-    if remat:
-        if remat_policy == "dots":
-            policy = jax.checkpoint_policies.checkpoint_dots
-        elif remat_policy == "tp_outs":
-            # Save only the named post-collective block outputs: the
-            # backward re-runs elementwise/attention work but never the
-            # partial-sum combines — minimal memory for maximal
-            # collective savings.
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "tp_out")
+        if remat:
+            if remat_policy == "dots":
+                policy = jax.checkpoint_policies.checkpoint_dots
+            elif remat_policy == "tp_outs":
+                # Save only the named post-collective block outputs: the
+                # backward re-runs elementwise/attention work but never
+                # the partial-sum combines — minimal memory for maximal
+                # collective savings.
+                policy = jax.checkpoint_policies.save_only_these_names(
+                    "tp_out")
+            else:
+                policy = None
+            fn = jax.checkpoint(group_fn, policy=policy)
         else:
-            policy = None
-        fn = jax.checkpoint(group_fn, policy=policy)
-    else:
-        fn = group_fn
-    xs = {"params": stacks}
-    if caches is not None:
-        xs["cache"] = caches
-    (x, aux), ys = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)), xs)
-    new_caches = ys.get("cache") if caches is not None else None
-    return x, new_caches, aux
+            fn = group_fn
+        xs = {"params": stacks_}
+        if caches_ is not None:
+            xs["cache"] = caches_
+        carry, ys = jax.lax.scan(fn, carry, xs)
+        return carry, (ys.get("cache") if caches_ is not None else None)
+
+    carry = (x, jnp.zeros((), jnp.float32))
+    if track:
+        carry += (jnp.zeros((2,), jnp.int32),)
+    new_caches = None
+    if lead is not None:
+        carry, new_caches = scan(lead, [cfg.lead_spec],
+                                 None if caches is None else caches[:1],
+                                 carry)
+        caches = None if caches is None else caches[1:]
+    carry, ys = scan(stacks, cfg.pattern, caches, carry)
+    if ys is not None:
+        new_caches = ys if new_caches is None else new_caches + ys
+    x, aux = carry[:2]
+    return x, new_caches, aux, (carry[2] if track else None)

@@ -198,6 +198,15 @@ def _first_token(logits: jax.Array, last: jax.Array) -> tuple:
     return jnp.argmax(row).astype(jnp.int32), row
 
 
+def _to_pools(full, one, scat):
+    """Each layer's page pools, ``scat(pool, dense)``-updated from the
+    matching dense scratch arrays: scratch array ``n`` of a layer goes
+    to its pool ``n + "_pages"`` (K and V; or the latent rows)."""
+    return [{m: {f"{n}_pages": scat(fc[m][f"{n}_pages"], a)
+                 for n, a in oc[m].items()} for m in oc}
+            for fc, oc in zip(full, one)]
+
+
 def _bucket_for(plen: int, max_len: int) -> int:
     for b in prefill_buckets(max_len):
         if plen <= b:
@@ -241,6 +250,11 @@ class ServeEngine:
                     f"ServeConfig.kv_dtype={scfg.kv_dtype!r} requires "
                     f"kv='paged' — the dense layout has no page pool to "
                     f"retype (got kv={scfg.kv!r})")
+            if scfg.kv_dtype == "int8" and any(
+                    spec.mixer == "mla" for spec in cfg.pattern):
+                raise ValueError(
+                    f"arch {cfg.name!r} keeps a latent KV pool, which has "
+                    f"no int8 pages (kv_dtype='int8')")
             if not paged_eligible(cfg):
                 raise ValueError(
                     f"arch {cfg.name!r} cannot honor "
@@ -317,7 +331,7 @@ class ServeEngine:
         # compiled program per distinct length); causal attention is
         # immune, so attention-only archs keep the pow2 buckets.
         self._exact_prefill = any(
-            spec.mixer != "attn" or spec.ffn == "rwkv_cm"
+            spec.mixer not in ("attn", "mla") or spec.ffn == "rwkv_cm"
             for spec in cfg.pattern)
         # Chunked prefill shares the page pool's eligibility predicate:
         # only archs whose whole per-token state is attention KV can
@@ -382,25 +396,32 @@ class ServeEngine:
         program = self._program
         self._prefill = program(
             "prefill", lambda p, b, c: prefill(p, b, cfg, c))
+        # A held-expert model's programs also return the (token, expert)
+        # pairs its layers served and the held experts they reached,
+        # read with the decode's tokens.
+        held = cfg.moe is not None and cfg.moe.held is not None
+
+        def fwd(p, b, c, pos):
+            out = forward(p, b, cfg, caches=c, cache_pos=pos,
+                          held_counts=held)
+            return out[:2] + out[3:]
+
         # Full-logits prefill for per-slot admission: a ragged prompt is
         # padded to its bucket, so the next-token logits live at
         # position plen-1, not at the padded end.
         self._prefill_full = program(
             "prefill_full",
-            lambda p, b, c: forward(p, b, cfg, caches=c,
-                                    cache_pos=jnp.zeros((), jnp.int32))[:2])
+            lambda p, b, c: fwd(p, b, c, jnp.zeros((), jnp.int32)))
         # Chunk-offset prefill: the same full-logits forward, but the
         # KV write offset / RoPE base is the slot's prompt cursor
         # (traced, so one compiled program covers every cursor value).
-        self._prefill_chunk_fn = program(
-            "prefill_chunk",
-            lambda p, b, c, pos: forward(p, b, cfg, caches=c,
-                                         cache_pos=pos)[:2])
+        self._prefill_chunk_fn = program("prefill_chunk", fwd)
         if self.kv_mode == "paged":
             self._decode = program(
                 "decode",
                 lambda p, t, pos, bt, c: decode_step(p, t, pos, cfg, c,
-                                                     block_tables=bt))
+                                                     block_tables=bt,
+                                                     held_counts=held))
             self._insert = program("insert", self._insert_slot_pages)
             self._insert_chunk = program("insert_chunk",
                                          self._insert_chunk_pages)
@@ -412,7 +433,8 @@ class ServeEngine:
                     lambda a: a.at[:, dst].set(a[:, src]), c))
         else:
             self._decode = program(
-                "decode", lambda p, t, pos, c: decode_step(p, t, pos, cfg, c))
+                "decode", lambda p, t, pos, c: decode_step(
+                    p, t, pos, cfg, c, held_counts=held))
             self._insert = program("insert", self._insert_slot)
         self._sample_slots = program("sample", self._make_sampler())
         # A prompt's first token: greedy from the logits row at a traced
@@ -443,6 +465,35 @@ class ServeEngine:
             "engine.decode.kv_pages_table",
             "slots x max_pages: the block-table entries a decode step "
             "holds")
+        # Routed-expert telemetry (0 for a model without held experts):
+        # pairs are counted over every expert layer and every row the
+        # programs route, padding rows included.
+        moe_layers = (sum(spec.ffn == "moe" for spec in cfg.pattern)
+                      * cfg.n_groups) if held else 0
+        self._moe_layers = moe_layers
+        self._pairs_per_row = moe_layers * (cfg.moe.top_k if held else 0)
+        self._held_dev: List[jax.Array] = []    # counts not yet read
+        self._c_routed = obs.registry.counter(
+            "engine.moe.routed_rows",
+            "(token, expert) pairs routed: rows x top_k x expert layers")
+        self._c_held = obs.registry.counter(
+            "engine.moe.held_rows",
+            "of those, the pairs whose expert this chip holds (served)")
+        self._c_reached = obs.registry.counter(
+            "engine.moe.held_experts_reached",
+            "held experts that got any pair, per expert layer and "
+            "program call: each has its weights read once")
+        self._c_layer_calls = obs.registry.counter(
+            "engine.moe.layer_calls",
+            "expert-layer calls: program calls x expert layers")
+        obs.registry.gauge(
+            "engine.moe.held_experts",
+            "experts of each expert layer held on this chip").set(
+            cfg.moe.n_held if held else 0)
+        obs.registry.gauge(
+            "engine.cache.bytes_per_token",
+            "KV bytes one token holds over every layer "
+            "(token_kv_bytes)").set(self.token_kv_bytes())
         self._g_active = obs.registry.gauge(
             "serve.active_slots", "slots mid-decode")
         self._g_kv_tokens = obs.registry.gauge(
@@ -573,7 +624,8 @@ class ServeEngine:
 
     def token_kv_bytes(self) -> int:
         """Bytes of attention KV one token occupies across the stack
-        (k + v, every attention layer).  Paged pools with a kv_dtype
+        (k + v, every attention layer; one latent row of ``latent_dim``
+        lanes per latent-attention layer).  Paged pools with a kv_dtype
         override are counted at the page dtype; int8 pages additionally
         carry one f32 scale per token row per KV head (the per-row
         scale-row layout), so the int8 figure is D + 4 bytes per head
@@ -582,14 +634,19 @@ class ServeEngine:
         ``page_row_dim(D)`` lanes, as the TPU's tiled HBM layout
         allocates a row narrower than 128 lanes anyway."""
         cfg = self.cfg
-        n_attn = sum(1 for spec in cfg.pattern if spec.mixer == "attn")
+        layers = [(spec, cfg.n_groups) for spec in cfg.pattern]
+        if cfg.first_dense_layers:
+            layers.append((cfg.lead_spec, cfg.first_dense_layers))
+        n_attn = sum(n for spec, n in layers if spec.mixer == "attn")
+        n_mla = sum(n for spec, n in layers if spec.mixer == "mla")
         kv_dtype = (self.scfg.kv_dtype if self.kv_mode == "paged"
                     else None)
         itemsize = jnp.dtype(kv_dtype or cfg.cache_dtype).itemsize
         row_bytes = cfg.d_head * itemsize
         if kv_dtype == "int8":
             row_bytes += 4                       # the row's f32 scale
-        return 2 * n_attn * cfg.n_groups * cfg.n_kv_heads * row_bytes
+        latent = n_mla * cfg.mla.latent_dim * itemsize if n_mla else 0
+        return 2 * n_attn * cfg.n_kv_heads * row_bytes + latent
 
     def kv_bytes_reserved(self) -> int:
         """Attention-KV bytes held for the engine's lifetime: the page
@@ -617,6 +674,16 @@ class ServeEngine:
         if live > self._kv_tokens_hwm:
             self._kv_tokens_hwm = live
         self._g_kv_tokens.set(live)
+
+    def _note_routed(self, rows: int, held: List[jax.Array]) -> None:
+        """Count the pairs a program call routed over ``rows`` token
+        rows, and keep its device counts of held pairs and reached
+        experts (``held``: empty for a model without held experts) to
+        read with the next decode's tokens."""
+        if held:
+            self._c_routed.inc(rows * self._pairs_per_row)
+            self._c_layer_calls.inc(self._moe_layers)
+            self._held_dev += held
 
     def _insert_slot(self, full, one, slot):
         """Overwrite slot ``slot`` of the persistent cache with a
@@ -668,10 +735,7 @@ class ServeEngine:
                                      "k_scale": ks, "v_scale": vs}})
             return out
 
-        return [{"attn": {
-            "k_pages": scat(fc["attn"]["k_pages"], oc["attn"]["k"]),
-            "v_pages": scat(fc["attn"]["v_pages"], oc["attn"]["v"]),
-        }} for fc, oc in zip(full, one)]
+        return _to_pools(full, one, scat)
 
     def _insert_chunk_pages(self, full, one, page_ids, src_idx):
         """Scatter one prefill *chunk*'s pages from the dense scratch
@@ -715,10 +779,7 @@ class ServeEngine:
             return pool.at[:, page_ids].set(
                 pick(dense, pool.shape[-1]).astype(pool.dtype))
 
-        return [{"attn": {
-            "k_pages": scat(fc["attn"]["k_pages"], oc["attn"]["k"]),
-            "v_pages": scat(fc["attn"]["v_pages"], oc["attn"]["v"]),
-        }} for fc, oc in zip(full, one)]
+        return _to_pools(full, one, scat)
 
     # -- prefix cache (kvpool.PrefixCache) ----------------------------------
 
@@ -749,25 +810,24 @@ class ServeEngine:
         exactly the rows this prefill computed)."""
         ps = self.pool.page_size
         lo = page_idx * ps
-        return [{"attn": {
-            "k": lc["attn"]["k"][:, :, :, lo:lo + ps, :],
-            "v": lc["attn"]["v"][:, :, :, lo:lo + ps, :],
-        }} for lc in scratch]
+        return [{m: {n: a[:, :, :, lo:lo + ps, :] for n, a in rows.items()}
+                 for m, rows in lc.items()} for lc in scratch]
 
     def _restore_prefix(self, scratch, payloads):
         """Write a hit's sidecar pages into a fresh scratch's leading
-        rows; leaves beyond attention K/V (none on paged-eligible
-        archs) pass through untouched."""
+        rows (every array of every layer's cache: K and V, or the
+        latent rows)."""
         out = []
         for li, lc in enumerate(scratch):
-            upd = {}
-            for k in ("k", "v"):
-                rows = jnp.concatenate(
-                    [p[li]["attn"][k] for p in payloads], axis=3)
-                upd[k] = jax.lax.dynamic_update_slice_in_dim(
-                    lc["attn"][k], rows.astype(lc["attn"][k].dtype),
-                    0, axis=3)
-            out.append({**lc, "attn": {**lc["attn"], **upd}})
+            new = {}
+            for m, arrays in lc.items():
+                new[m] = {}
+                for n, a in arrays.items():
+                    rows = jnp.concatenate(
+                        [p[li][m][n] for p in payloads], axis=3)
+                    new[m][n] = jax.lax.dynamic_update_slice_in_dim(
+                        a, rows.astype(a.dtype), 0, axis=3)
+            out.append(new)
         return out
 
     def _cache_prefix(self, slot: Slot, req: Request) -> None:
@@ -962,11 +1022,23 @@ class ServeEngine:
                         self._c_pages_table.inc(pos.size * self._max_pages)
                     token_idx = jnp.asarray(token_idx)
                 with tr.span("engine.decode.dispatch", cat="engine"):
-                    logits, self.caches = self._decode(
+                    logits, self.caches, *held = self._decode(
                         self.params, *inputs, self.caches)
                     toks = self._sample_slots(logits, token_idx)
+                    self._note_routed(self.scfg.batch_slots, held)
                 with tr.span("engine.decode.wait", cat="engine"):
-                    toks = np.asarray(toks)
+                    if self._held_dev:
+                        # The held counts of this decode and of the
+                        # chunks since the last one come back with the
+                        # tokens, in the same transfer.
+                        toks, counts = jax.device_get(
+                            (toks, self._held_dev))
+                        self._held_dev = []
+                        pairs, reached = np.sum(counts, axis=0)
+                        self._c_held.inc(int(pairs))
+                        self._c_reached.inc(int(reached))
+                    else:
+                        toks = np.asarray(toks)
                 decode_ms = (time.perf_counter() - t_dec) * 1e3
                 self.stats["decode_steps"] += 1
                 if events["admitted"] and holdover:
@@ -1243,9 +1315,11 @@ class ServeEngine:
             batch: Dict[str, jax.Array] = {"tokens": jnp.asarray(toks)}
             if req.enc_embeds is not None:
                 batch["enc_embeds"] = jnp.asarray(req.enc_embeds)
-            logits, self._scratch[slot.index] = self._prefill_chunk_fn(
-                self.params, batch, self._scratch[slot.index],
-                jnp.asarray(c0, jnp.int32))
+            logits, self._scratch[slot.index], *held = \
+                self._prefill_chunk_fn(self.params, batch,
+                                       self._scratch[slot.index],
+                                       jnp.asarray(c0, jnp.int32))
+            self._note_routed(buf, held)
             if self.kv_mode == "paged":
                 # Incremental page scatter: only this chunk's pages
                 # move.  Chunks are page-aligned, so the cursor sits on
@@ -1509,7 +1583,9 @@ class ServeEngine:
                 batch["enc_embeds"] = jnp.asarray(req.enc_embeds)
             fresh = init_cache(self.cfg, 1, self._fresh_len,
                                enc_len=self.scfg.enc_len)
-            logits, one = self._prefill_full(self.params, batch, fresh)
+            logits, one, *held = self._prefill_full(self.params, batch,
+                                                    fresh)
+            self._note_routed(bucket, held)
             if self.kv_mode == "paged":
                 # Scatter the dense scratch into the pool along this
                 # slot's block-table row (prompt pages; the tail lands

@@ -23,7 +23,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs as C
 from repro.kernels.decode_attention import (flash_decode, flash_paged_decode,
-                                           page_row_dim)
+                                           latent_paged_decode, page_row_dim)
+from repro.kernels.expert_gmm import expert_gmm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gemm import gama_gemm
 from repro.kernels.ops import _round_up
@@ -260,3 +261,33 @@ def test_flash_paged_decode_dma_gather_refuses_narrow_heads(one_chip):
     with pytest.raises(ValueError, match="page_row_dim"):
         _compile(_paged_fn(2, False),
                  *_paged_args(one_chip, CFG.d_head, 16, jnp.bfloat16))
+
+
+def test_latent_paged_decode_at_deepseek_v2_lite(one_chip):
+    """The latent decode at DeepSeek-V2-Lite's served geometry: 48
+    slots, 16 heads over one latent row a key, 576 lanes stored in
+    ``page_row_dim(576)`` = 640 (the compiler refuses a DMA of a
+    576-lane row), 128 pages of 16 a table.  The kernel keeps its name,
+    which the benchmark reads its device time by."""
+    w = page_row_dim(576)
+    text = _compile(
+        lambda q, p, bt, ln: latent_paged_decode(
+            q, p, bt, length=ln, value_dim=512, scale=0.114721),
+        _spec(one_chip, (48, 16, w), jnp.bfloat16),
+        _spec(one_chip, (48 * 128 + 1, 1, 16, w), jnp.bfloat16),
+        _spec(one_chip, (48, 128), jnp.int32),
+        _spec(one_chip, (48,), jnp.int32))
+    assert "gama_latent_paged_decode" in text
+
+
+@pytest.mark.parametrize("m,k,n", [(384, 2048, 1408), (1536, 1408, 2048)],
+                         ids=["decode_gate", "chunk_down"])
+def test_expert_gmm_at_deepseek_v2_lite(one_chip, m, k, n):
+    """The held experts' grouped matmul over 8 experts of 1408 at
+    DeepSeek-V2-Lite's widths: a 48-slot decode's 288 pairs (tiles of
+    128 rows) and a 256-row chunk's 1536; each expert's whole (K, N)
+    weight block is one VMEM block."""
+    text = _compile(expert_gmm, _spec(one_chip, (m, k), jnp.bfloat16),
+                    _spec(one_chip, (8, k, n), jnp.bfloat16),
+                    _spec(one_chip, (8,), jnp.int32))
+    assert "gama_expert_gmm" in text

@@ -33,6 +33,7 @@ DOCTEST_MODULES = [
     "repro.obs.trace",
     "repro.obs.devtrace",
     "repro.kernels.decode_attention",
+    "repro.models.layers",
     "repro.launch.compile_cache",
 ]
 
